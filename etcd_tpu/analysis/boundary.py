@@ -16,7 +16,7 @@ the same loop.  Jit roots are resolved in the module itself
 (``@jax.jit`` / ``functools.partial(jax.jit, ...)`` decorators,
 ``f = jax.jit(g)`` bindings) and across ``from X import y`` edges
 when X lives in this repo, so the common split (kernels in ``ops/``,
-loops in ``server/``/``bench.py``) is covered.  Method calls on
+loops in ``server/``/``scripts/``) is covered.  Method calls on
 engine objects (``mr.propose(...)``) are NOT resolved — that tier is
 instrumented by the devledger at runtime instead.
 
@@ -71,7 +71,7 @@ def _jit_roots_of(tree: ast.AST) -> set[str]:
 
 class DeviceBoundaryChecker(Checker):
     name = "device-boundary"
-    targets = ("etcd_tpu/", "scripts/", "bench.py")
+    targets = ("etcd_tpu/", "scripts/")
 
     def __init__(self):
         self._module_roots: dict[str, set[str]] = {}

@@ -27,7 +27,7 @@ _RECEIVERS = {"faults", "_faults", "FAULTS"}
 
 class FaultVocabularyChecker(Checker):
     name = "fault-vocabulary"
-    targets = ("etcd_tpu/", "scripts/", "bench.py")
+    targets = ("etcd_tpu/", "scripts/")
 
     def _catalog(self) -> set[str] | None:
         try:

@@ -28,7 +28,7 @@ _RECEIVERS = {"registry", "obs_registry", "reg", "_reg", "_obs"}
 
 class MetricsVocabularyChecker(Checker):
     name = "metrics-vocabulary"
-    targets = ("etcd_tpu/", "scripts/", "bench.py")
+    targets = ("etcd_tpu/", "scripts/")
 
     def _catalog(self) -> set[str] | None:
         try:

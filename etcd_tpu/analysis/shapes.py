@@ -6,8 +6,8 @@ shapes are baked into the trace.  A Python branch on ``x.shape`` (or
 legal — the purity checker de-taints those reads — but it turns every
 NEW caller shape into a full re-trace + re-compile.  With tens of
 thousands of co-hosted groups batched through a handful of kernels,
-one shape-churning call site is a compile storm (PALLAS_NOTES'
-re-jit-churn class).
+one shape-churning call site is a compile storm (the re-jit-churn
+class).
 
 This checker joins both halves statically, which needs the
 whole-program call graph:

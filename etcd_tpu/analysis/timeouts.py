@@ -96,7 +96,7 @@ _HEARTBEAT_CTORS = {
 
 class TimeoutBandChecker(Checker):
     name = "timeout-bands"
-    targets = ("etcd_tpu/", "scripts/", "bench.py")
+    targets = ("etcd_tpu/", "scripts/")
 
     def check(self, relpath, tree, source, root=None, ctx=None):
         findings: list[Finding] = []

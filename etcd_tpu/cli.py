@@ -196,15 +196,6 @@ def main(argv: list[str] | None = None) -> int:
     logging.basicConfig(
         level=logging.INFO,
         format="%(asctime)s %(name)s: %(message)s")
-    # Operator override for the device-replay JAX platform (e.g.
-    # ETCD_JAX_PLATFORMS=cpu on hosts whose PJRT plugin hijacks
-    # env-var platform selection); applied via jax.config, which wins
-    # over import-time plugin hooks.
-    plat = os.environ.get("ETCD_JAX_PLATFORMS")
-    if plat:
-        import jax
-
-        jax.config.update("jax_platforms", plat)
     argv = argv if argv is not None else sys.argv[1:]
     parser = build_parser()
     args = parser.parse_args(argv)
@@ -237,6 +228,11 @@ def main(argv: list[str] | None = None) -> int:
 
     if args.proxy != PROXY_VALUE_OFF:
         return start_proxy(args, cluster, explicit)
+    # every serving mode below may compile (JAX_PLATFORMS picks the
+    # platform; one chip belongs to one process)
+    from .utils.jaxenv import configure_compile_cache
+
+    configure_compile_cache()
     if args.dist_slot >= 0:
         return start_dist(args, explicit)
     if args.cohosted_groups > 0:
@@ -250,6 +246,7 @@ def start_dist(args, explicit: set[str]) -> int:
     other slots (server/distserver.py).  The standard /v2 client API
     serves from the local replica; writes route to group leaders."""
     from .server.distserver import DistServer
+    from .utils.jaxenv import log_devices
 
     peers = [u.strip() for u in args.dist_peers.split(",") if u.strip()]
     if len(peers) < 2 or not (0 <= args.dist_slot < len(peers)):
@@ -291,6 +288,7 @@ def start_dist(args, explicit: set[str]) -> int:
     client_tls = TLSInfo(args.cert_file, args.key_file, args.ca_file)
     acurls = urls_from_flags(args, "advertise_client_urls", "addr",
                              explicit, client_tls.empty())
+    log_devices()
     # member identity folds the slot in: hosts commonly share a
     # --name (the default!), and identical names would collapse to
     # one sha1 id whose registry entries overwrite each other
@@ -407,7 +405,9 @@ def start_multigroup(args, explicit: set[str]) -> int:
     (server/multigroup.py — no reference counterpart; the reference
     is one group per process)."""
     from .server.multigroup import MultiGroupServer
+    from .utils.jaxenv import log_devices
 
+    log_devices()
     data_dir = args.data_dir or f"{args.name}_multigroup_data"
     os.makedirs(data_dir, mode=0o700, exist_ok=True)
     client_tls = TLSInfo(args.cert_file, args.key_file, args.ca_file)
@@ -542,12 +542,12 @@ def _serve_client(args, s, cors, host: str, port: int, ssl_context):
 
 
 def _local_mesh(n: int, groups: int):
-    """Build a local device mesh over the first ``n`` devices, or
-    None when ``n`` is 0.  Fails fast (ValueError) on every flag
-    misconfiguration — negative/oversized counts (group_mesh would
-    silently truncate) and a group count that does not split over
-    the mesh — so the servers' own pre-disk guards never fire from
-    the CLI path."""
+    """Build a ``g``-only serving mesh over the first ``n`` local
+    devices, or None when ``n`` is 0.  Fails fast (ValueError) on
+    every flag misconfiguration — negative/oversized counts
+    (serving_mesh would silently truncate) and a group count that
+    does not split over the mesh — so the servers' own pre-disk
+    guards never fire from the CLI path."""
     if not n:
         return None
     if n < 0:
@@ -555,13 +555,13 @@ def _local_mesh(n: int, groups: int):
                          f"got {n}")
     import jax
 
-    from .parallel.mesh import check_group_divisible, group_mesh
+    from .parallel.mesh import check_group_divisible, serving_mesh
 
     avail = len(jax.devices())
     if n > avail:
         raise ValueError(f"{n} mesh devices requested but only "
                          f"{avail} available")
-    mesh = group_mesh(n)
+    mesh = serving_mesh(n)
     check_group_divisible(mesh, groups)
     return mesh
 

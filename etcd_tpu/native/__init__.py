@@ -1,9 +1,9 @@
 """ctypes bindings for the native WAL data-loader tier (native/walscan.cc).
 
 Builds the shared library on demand with ``make`` (g++ is in the
-image; the .so is not committed).  All functions fall back gracefully:
-``available()`` is False when no compiler/toolchain is present, and
-callers (wal.replay_device, bench.py) keep a pure-Python path.
+image; the .so is not committed).  ``available()`` is False — with a
+logged warning — when no compiler/toolchain is present, and callers
+(wal.replay_device) keep a pure-Python path.
 
 The native tier owns the byte-granular, branchy work the reference
 does in Go — framing (wal/decoder.go:30-35), proto field walks,
@@ -14,6 +14,7 @@ batched checksum/commit math runs on device (ops/).
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -23,6 +24,8 @@ import numpy as np
 _DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
 _SO = os.path.join(_DIR, "libwalscan.so")
+
+log = logging.getLogger(__name__)
 
 _lock = threading.Lock()
 _lib = None
@@ -68,7 +71,12 @@ def _build() -> bool:
         subprocess.run(["make", "-C", _DIR, "libwalscan.so"],
                        check=True, capture_output=True, timeout=120)
         return True
-    except (subprocess.SubprocessError, OSError):
+    except (subprocess.SubprocessError, OSError) as e:
+        # callers keep a pure-Python path, but never silently: a
+        # host that cannot build the scanner must say so once
+        log.warning("native: building %s failed (%r); %s", _SO, e,
+                    (getattr(e, "stderr", b"") or b"")
+                    .decode(errors="replace")[-500:])
         return False
 
 

@@ -147,8 +147,8 @@ def _planes_tile_for(length: int, tile: int) -> int:
 
 
 def _pallas_planes_kernel(perturb_ref, buf_ref, ck_ref, out_ref):
-    # perturb: scalar XORed into every byte IN VMEM — bench.py's
-    # sustained loop uses it to defeat loop-invariant hoisting
+    # perturb: scalar XORed into every byte IN VMEM — a sustained
+    # measurement loop uses it to defeat loop-invariant hoisting
     # without materializing a perturbed [N, L] copy in HBM each
     # iteration (the outer `rows ^ i` costs a full extra HBM
     # read+write pass per iteration).  0 = unperturbed (the
@@ -298,7 +298,7 @@ def raw_crc_planes4(buf) -> jnp.ndarray:
     return _planes4_jit(buf, ck)
 
 
-#: name -> callable, for the bench sweep and the bench.py variant knob
+#: name -> callable, for the race script (scripts/crc_variants_bench.py)
 VARIANTS = {
     "planes": raw_crc_planes,
     "transposed": raw_crc_transposed,
@@ -318,8 +318,7 @@ TPU_RACE_VARIANTS = {
 
 def parse_variant(name: str) -> tuple[str, int | None]:
     """Validate and split a variant name of the ``base`` or
-    ``base@tile`` grammar shared by BENCH_CRC_VARIANT (bench.py) and
-    the race script.  Returns (base, tile-or-None); raises
+    ``base@tile`` grammar the race script takes.  Returns (base, tile-or-None); raises
     ValueError on an unknown base or a non-numeric tile — a typo
     must fail loudly, not run some other kernel under the wrong
     label in a bench artifact."""
@@ -339,8 +338,8 @@ def parse_variant(name: str) -> tuple[str, int | None]:
 def pallas_planes_perturbed(name: str = "pallas_planes",
                             tile: int | None = None):
     """``(buf, i) -> raw CRCs of buf ^ uint8(i)`` with the
-    perturbation applied inside the kernel (VMEM), for bench.py's
-    sustained loop: the outer ``rows ^ i`` form costs a full extra
+    perturbation applied inside the kernel (VMEM), for a sustained
+    measurement loop: the outer ``rows ^ i`` form costs a full extra
     HBM read+write pass of the batch per iteration purely to defeat
     loop-invariant hoisting; a scalar SMEM operand defeats it for
     free.  ``i == 0`` is the unperturbed, correctness-gated pass."""

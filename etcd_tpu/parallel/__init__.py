@@ -15,6 +15,7 @@ from .mesh import (
     make_sharded_step,
     place_step_inputs,
     replay_commit_local,
+    serving_mesh,
     shard_leading,
 )
 
@@ -26,5 +27,6 @@ __all__ = [
     "make_sharded_step",
     "place_step_inputs",
     "replay_commit_local",
+    "serving_mesh",
     "shard_leading",
 ]

@@ -39,21 +39,6 @@ from ..ops.quorum import maybe_commit_batch
 from ..raft.batched import GroupState, replication_round
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across the jax version band: the public
-    ``jax.shard_map`` (with ``check_vma``) landed after 0.4.x, where
-    the same transform lives at ``jax.experimental.shard_map`` and
-    spells the replication check ``check_rep``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as esm
-
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def group_mesh(n_devices: int | None = None) -> Mesh:
     """Build a 2D ``(g, s)`` mesh over the first ``n_devices`` devices.
 
@@ -68,6 +53,19 @@ def group_mesh(n_devices: int | None = None) -> Mesh:
     g = n // s
     arr = np.asarray(devs[: g * s]).reshape(g, s)
     return Mesh(arr, ("g", "s"))
+
+
+def serving_mesh(n_devices: int | None = None) -> Mesh:
+    """A ``g``-only mesh over the first ``n_devices`` devices, for the
+    serving tiers: ``MultiRaft.shard`` / ``DistMember.shard`` place
+    [G]-leading state over ``g`` alone, so on :func:`group_mesh`'s
+    ``(g, s)`` layout every ``s`` column would hold a full COPY of
+    its row's groups (four chips, two of them replicas).  The fused
+    replay+commit step keeps the ``s`` axis (make_sharded_step)."""
+    devs = jax.devices()
+    if n_devices is not None:
+        devs = devs[:n_devices]
+    return Mesh(np.asarray(devs), ("g",))
 
 
 def check_group_divisible(mesh: Mesh, g: int) -> None:
@@ -201,7 +199,7 @@ def make_sharded_step(mesh: Mesh):
         return links_ok, state, err, ncomm, commit_all
 
     gspec = GroupState(*([P("g")] * len(GroupState._fields)))
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(P("g", "s"), P("g"), P("g"), P(), gspec, P("g"),
                   P("g"), P("g", None), P("g", None), P("g", None),
@@ -271,7 +269,7 @@ def make_replay_commit_step(mesh: Mesh):
             new_committed, "g", tiled=True)
         return links_ok, committed_all
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(P("g", "s"), P("g"), P("g"), P(), P("g"), P("g"),
                   P("g"), P("g"), P("g", None), P("g"), P("s", None)),

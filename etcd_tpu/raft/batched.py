@@ -54,14 +54,17 @@ def _append_write_mode() -> str:
             raise ValueError(
                 f"ETCD_APPEND_WRITE={mode!r}: want scatter|dense")
         return mode
-    # default dense everywhere: the scatter form MEASURED 2x slower
-    # for the whole serving round on the XLA-CPU virtual mesh
-    # (config5 @100k groups: 89 -> 177 ms/round — XLA lowers the
-    # .at[].set to a non-aliased copy+scatter), and arithmetic says
-    # the dense [G, cap] write (~26 MB/exchange, ~2.6 ms at host
-    # bandwidth) was never the 23 ms/exchange bottleneck.  The knob
-    # and both forms stay for on-hardware racing.
-    return "dense"
+    # Chosen from the platform the program is traced for.  On the
+    # v5e the dense form's [G, cap] take_along_axis is a 10M-element
+    # gather per follower exchange at config-4 size (G=10k, cap=1024):
+    # 85 ms each, a 0.53 s fused round against 0.12 s for scatter —
+    # past the 0.5 s client request timeout, so no HTTP write could
+    # be acknowledged (chip run, PR 21).  On XLA-CPU the scatter form
+    # MEASURED 2x slower for the whole serving round (config5 @100k
+    # groups: 89 -> 177 ms/round — XLA lowers the .at[].set to a
+    # non-aliased copy+scatter).
+    return "scatter" if jax.default_backend() == "tpu" else "dense"
+
 
 FOLLOWER, CANDIDATE, LEADER = 0, 1, 2
 
@@ -225,8 +228,8 @@ def _maybe_append_jit(state, prev_idx, prev_term, ent_terms, n_ents,
     # - "dense": one masked full-window where() — contiguous and
     #   layout-friendly where gathers/scatters are expensive.
     #
-    # Default: dense (measured faster end-to-end on the XLA-CPU
-    # virtual mesh — see _append_write_mode);
+    # Default: by platform — scatter on the TPU, dense on XLA-CPU
+    # (see _append_write_mode for both measurements);
     # ETCD_APPEND_WRITE={scatter,dense} overrides for racing.
     if write_mode == "scatter":
         rel = e_idx - state.offset[:, None]    # cap slot of entry j

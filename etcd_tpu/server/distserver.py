@@ -580,7 +580,11 @@ class DistServer:
         # arrays get placed too)
         self.mesh = mesh
         if mesh is not None:
+            from ..utils.jaxenv import log_placement
+
             self.mr.shard(mesh)
+            log_placement(f"dist[{slot}] log_term",
+                          self.mr.state.log_term)
         self._refresh_member_cache()
 
     def _refresh_member_cache(self) -> None:

@@ -637,8 +637,10 @@ def run_worker(args) -> None:
 
 
 def run_shard(args) -> None:
+    from ..utils.jaxenv import configure_compile_cache
     from .distserver import DistServer
 
+    configure_compile_cache()
     _arm_parent_death()
     done = _arm_signals()
     _start_role_obs()
@@ -919,7 +921,10 @@ class Supervisor:
 
     def spawn(self, role: str) -> None:
         argv = self._child_argv(role)
-        self.children[role] = subprocess.Popen(argv)
+        # a role family is a CPU layout, said in the children's own
+        # environment: a chip belongs to one process (start() warns)
+        self.children[role] = subprocess.Popen(
+            argv, env=dict(os.environ, JAX_PLATFORMS="cpu"))
         self.ports[role] = self._port_of(role)
         self._spawned_at[role] = time.monotonic()
         self._write_roles_file()
@@ -939,6 +944,11 @@ class Supervisor:
         os.replace(tmp, path)
 
     def start(self) -> None:
+        log.warning(
+            "roles: %d shard processes cannot share one chip — the "
+            "role family runs with JAX_PLATFORMS=cpu (ROADMAP S6/D3 "
+            "decides its future); the on-chip cluster is one process",
+            self.args.shards)
         os.makedirs(self.args.data_dir, exist_ok=True)
         for s in range(self.args.shards):
             name = ring_name(self.args.client_port, s)

@@ -603,37 +603,35 @@ def _replay_wal_raw(waldir: str, index: int, backend: str,
                     or size >= _DEVICE_REPLAY_MIN_BYTES
                     or (route == "host" and native.available()))
         if use_fast:
-            try:
-                from ..wal.replay_device import open_replay_device
+            from ..wal.replay_device import open_replay_device
 
+            try:
                 with tracer.span("replay.device"):
                     w, md, hard_state, block = open_replay_device(
                         waldir, index, route=route)
-                log.info("etcdserver: %s-route replay of %d entries "
-                         "(%d bytes)", route, len(block), size)
-                return w, md, hard_state, block
-            except Exception as e:
+            except TornTailError:
                 # a crash-torn tail must heal on EVERY backend — the
-                # torn bytes were never acked — so even strict tpu
-                # mode falls through to the host path's repair for
-                # that case; all three scanners raise the same typed
-                # TornTailError (wal/errors.py), so this matches on
-                # type, never on message text
-                if backend == "tpu" and not isinstance(
-                        e, TornTailError):
-                    raise
-                log.warning("etcdserver: %s-route replay failed; "
-                            "falling back to host path", route,
-                            exc_info=True)
+                # torn bytes were never acked — and only the host
+                # path repairs; all three scanners raise the same
+                # typed TornTailError (wal/errors.py).  Nothing else
+                # is caught: a device fault stops the restart on
+                # every backend instead of hiding behind the host.
+                log.warning("etcdserver: %s-route replay met a torn "
+                            "tail; falling back to host path", route)
                 # the decision artifact must name the lane that RAN
                 pol.note(stage, "host",
-                         f"{route} lane failed "
-                         f"({type(e).__name__}); host repair path")
+                         f"{route} lane met a torn tail; host "
+                         f"repair path")
+            else:
+                log.info("etcdserver: %s-route replay of %d entries "
+                         "(%d bytes; %s)", route, len(block), size,
+                         pol.decisions[stage]["why"])
+                return w, md, hard_state, block
     with tracer.span("replay.host"):
         w = WAL.open_at_index(waldir, index)
         # server restarts tolerate a crash-torn tail (unacked by
-        # construction — acks only follow fsync); the device lane
-        # above raises on one, and auto mode then lands here
+        # construction — acks only follow fsync); the fast lanes
+        # above raise on one and land here
         md, hard_state, ents = w.read_all(repair=True)
     return w, md, hard_state, ents
 

@@ -6,7 +6,7 @@
 // The reference achieves its replay throughput with Go's stdlib
 // hash/crc32 (SSE4.2-accelerated) in a strictly sequential loop; this
 // file reproduces that loop in C++ as the *baseline* the device path
-// is measured against (bench.py), and provides the framing pass the
+// is measured against, and provides the framing pass the
 // device path runs on host (record offsets/lengths/stored CRCs) —
 // everything byte-level and branchy, i.e. the wrong shape for a TPU,
 // stays here; everything batchable goes to the device.
@@ -561,8 +561,8 @@ int64_t etcd_wal_scan_chunk(const uint8_t* buf, uint64_t n, uint64_t pos,
 
 // The reference's sequential hot loop, natively: frame, proto-parse,
 // rolling-chain CRC verify per record (decoder.go:28-47), entry
-// index/term extraction. This is the single-core baseline bench.py
-// measures the device path against. Returns entry count.
+// index/term extraction. This is the single-core baseline the
+// device path is measured against. Returns entry count.
 int64_t etcd_replay_verify(const uint8_t* buf, uint64_t n, uint32_t seed,
                            uint64_t* last_index, uint64_t* last_term) {
   uint64_t pos = 0;

@@ -26,6 +26,10 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
+# one process, so it may hold the chip: JAX_PLATFORMS is honoured,
+# and the default says CPU out loud (read before jax is imported)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
 from etcd_tpu.utils.diskstat import wal_snap_usage as disk_sample  # noqa: E402
 
 
@@ -57,10 +61,9 @@ def main() -> int:
     # it many times, so the bounded-disk gate actually bites
     snap_count = int(sys.argv[3]) if len(sys.argv) > 3 else 2000
 
-    import jax
+    from etcd_tpu.utils.jaxenv import configure_compile_cache
 
-    jax.config.update("jax_platforms", "cpu")
-
+    configure_compile_cache()
     from etcd_tpu.server.multigroup import MultiGroupServer
     from etcd_tpu.wire.requests import Request
 

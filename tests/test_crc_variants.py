@@ -38,7 +38,7 @@ def test_variant_matches_production_and_host(name, n, length):
 @pytest.mark.parametrize("name", sorted(VARIANTS))
 def test_variant_composes_with_seed_injection(name):
     """The variants slot into the seed-injected chain verify exactly
-    like the production path (bench.py's sustained loop contract)."""
+    like the production path (the sustained-loop contract)."""
     from etcd_tpu.ops.crc_device import chain_links_injected, inject_seeds
 
     rng = np.random.default_rng(5)
@@ -61,7 +61,7 @@ def test_variant_composes_with_seed_injection(name):
 
 @pytest.mark.parametrize("name", ["pallas_planes", "pallas_planes_t"])
 def test_perturbed_kernel_matches_outer_xor(name):
-    """The SMEM perturb operand (bench.py's sustained-loop LICM
+    """The SMEM perturb operand (the sustained-loop LICM
     defeat) must compute exactly raw(buf ^ uint8(i)) — the headline
     TPU number depends on it, and the bench gate only checks i=0."""
     from etcd_tpu.ops.crc_variants import pallas_planes_perturbed
