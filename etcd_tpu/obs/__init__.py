@@ -1,14 +1,11 @@
 """Process-wide observability subsystem (PR 2 tentpole).
 
-Three pillars:
+Two pillars:
 
 - :mod:`.metrics` — typed catalog-checked registry (counters,
   gauges, log-bucketed histograms with exact snapshot-time
   percentiles); the ``metrics-vocabulary`` lint checker enforces the
   catalog.
-- :mod:`.roofline` — the single source of truth for FLOP/byte
-  accounting, ceiling probes and MFU derivation; refuses to emit a
-  silent >100%-of-ceiling row (``ceiling_suspect`` tagging).
 - :mod:`.exporter` + :mod:`.devledger` — Prometheus text exposition
   for ``GET /metrics`` and the per-stage device/host transfer
   ledger wrapping the jitted-dispatch seams.

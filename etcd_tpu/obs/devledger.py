@@ -23,9 +23,14 @@ tracer-purity checker's domain): callers wrap the *dispatch call
 site*, never the traced body.
 
 Stage attribution (PR 8): when a ledger seam runs inside an active
-``tracer.stage(...)`` context, its device wall seconds are charged
-ONCE to that stage's ``etcd_stage_seconds{kind="device"}`` column
-(via utils/trace.note_device_seconds).  A ``dispatch`` seam charges
+``tracer.stage(...)`` context, its host seconds blocked at the seam
+are charged ONCE to the innermost stage's
+``etcd_stage_seconds{kind="device"}`` column (via
+utils/trace.note_device_seconds), and from there into each enclosing
+stage's, as wall and cpu are.  They are seconds the HOST spent at the
+seam, not seconds the device worked: every read-back of a round goes
+through ``fetch`` so that the wait for the device is billed here and
+not lost between two seams.  A ``dispatch`` seam charges
 its whole window at exit; ``block``/``fetch`` charge only when no
 dispatch seam is active on the thread — a block inside a dispatch is
 already inside the dispatch's window, and charging both would

@@ -233,20 +233,17 @@ _DEFS = (
         "pay the piggybacked confirmation round).", window=4096),
     MetricDef(
         "etcd_stage_seconds", "histogram",
-        "Per-stage attribution of the serving loops (PR 8 stage() "
+        "Per-stage attribution of the serving loops (the stage() "
         "facade): one sample per pass through a labeled stage, "
-        "split by kind — wall (perf_counter span), cpu "
-        "(time.thread_time delta: CPU this thread actually burned "
-        "inside the stage) and device (devledger-attributed "
-        "dispatch/block seconds inside the stage, charged here "
-        "ONCE so wall/cpu/device columns sum honestly instead of "
-        "the ledger and the span double-counting the window).",
+        "split by kind — wall (perf_counter span; its count is the "
+        "number of passes; a request's waits filed by record_wait "
+        "land here too), cpu (time.thread_time delta: CPU this "
+        "thread actually burned inside the stage; not taken by the "
+        "children that tile a hot pass) and device (host "
+        "seconds blocked at a device seam inside the stage: the "
+        "devledger's dispatch and read-back windows, charged here "
+        "ONCE; not time the device worked).",
         labels=("stage", "kind"), window=512),
-    MetricDef(
-        "etcd_trace_spans_total", "counter",
-        "Stage passes recorded by the stage() facade, per stage "
-        "(the denominator for the etcd_stage_seconds sums).",
-        labels=("stage",)),
     MetricDef(
         "etcd_flight_events_total", "counter",
         "Flight-recorder events recorded, by event class: span "

@@ -142,14 +142,20 @@ def _default_use_pallas() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def raw_crc_batch(buf, use_pallas: bool | None = None) -> jnp.ndarray:
+def raw_crc_batch(buf, use_pallas: bool | None = None,
+                  c=None) -> jnp.ndarray:
     """Raw (no-inversion) CRC states of right-aligned rows: uint32 [N].
 
     ``buf`` is ``[N, L]`` uint8 with each record's bytes occupying the
-    *rightmost* ``len`` columns and zeros elsewhere.
+    *rightmost* ``len`` columns and zeros elsewhere.  ``c`` is the
+    uploaded ``contribution_matrix(L)`` where the caller has it (the
+    replay times its build and upload as a stage of its own); it is
+    built on the host once per width and uploaded on every call, 32
+    MiB for the 131072-byte class.
     """
     buf = jnp.asarray(buf, dtype=jnp.uint8)
-    c = jnp.asarray(contribution_matrix(buf.shape[1]))
+    if c is None:
+        c = jnp.asarray(contribution_matrix(buf.shape[1]))
     if use_pallas is None:
         use_pallas = _default_use_pallas()
     return _raw_crc_jit(buf, c, use_pallas=use_pallas)

@@ -114,6 +114,7 @@ def raw_crc_pallas(buf: jnp.ndarray, c: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="crc32c_rows",
     )(buf8, c)
     bits32 = jnp.arange(32, dtype=jnp.uint32)
     packed = jnp.sum(parity.astype(jnp.uint32) << bits32, axis=1,

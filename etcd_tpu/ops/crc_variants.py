@@ -5,7 +5,8 @@ The production path (ops/crc_device.py:_raw_crc_jit) materializes the
 contribution matrix.  VERDICT r3 #2 asks for kernel variants that
 avoid the bit expansion and use the MXU better; this module holds the
 candidates, all bit-exact with ``raw_crc_batch`` (property-tested on
-CPU, raced on hardware by scripts/crc_variants_bench.py):
+CPU; the script that raced them on hardware went with PR 27, the
+served path's kernel is measured by the benchmark's ``crc_roofline``):
 
 - ``raw_crc_planes``: NO bit unpack.  Because the final reduction is
   a parity, the exact bit values are not needed — only their sum mod
@@ -43,8 +44,8 @@ CPU, raced on hardware by scripts/crc_variants_bench.py):
   plane remnants ``(x >> k) & 7`` fit int4's [-8, 7]), betting on the
   MXU's higher int4 throughput.  Excluded from the CPU-tested
   VARIANTS dict: XLA's CPU emulation of s4 dots is pathologically
-  slow to compile; they are gated on-hardware by the race script's
-  chain-verify instead (scripts/crc_variants_bench.py).
+  slow to compile, and nothing gates them on hardware since the race
+  script went (PR 27).
 
 Reference semantics being reproduced: the sequential rolling CRC of
 wal/decoder.go:28-47 / pkg/crc (see ops/crc_device.py's module
@@ -298,7 +299,7 @@ def raw_crc_planes4(buf) -> jnp.ndarray:
     return _planes4_jit(buf, ck)
 
 
-#: name -> callable, for the race script (scripts/crc_variants_bench.py)
+#: name -> callable, the CPU-tested candidates
 VARIANTS = {
     "planes": raw_crc_planes,
     "transposed": raw_crc_transposed,

@@ -135,11 +135,15 @@ def term_at(log_term, offset, last, idx):
     if squeeze:
         idx = idx[:, None]
     cap = log_term.shape[1]
-    slot = idx - offset[:, None]
-    valid = (idx >= offset[:, None]) & (idx <= last[:, None]) & \
-        (slot < cap)
-    t = jnp.take_along_axis(log_term, jnp.clip(slot, 0, cap - 1), axis=1)
-    t = jnp.where(valid, t, 0)
+    # one stable name for the gather over the [G, cap] window, which
+    # is most of a round's device time, wherever it is called from
+    with jax.named_scope("term_at"):
+        slot = idx - offset[:, None]
+        valid = (idx >= offset[:, None]) & (idx <= last[:, None]) & \
+            (slot < cap)
+        t = jnp.take_along_axis(log_term, jnp.clip(slot, 0, cap - 1),
+                                axis=1)
+        t = jnp.where(valid, t, 0)
     return t[:, 0] if squeeze else t
 
 

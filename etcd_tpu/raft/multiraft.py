@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.devledger import ledger as _ledger
+from ..utils.trace import tracer
 from .batched import (
     CANDIDATE,
     FOLLOWER,
@@ -99,9 +100,10 @@ def _round_core(states, sels, n_new, drop, e, slots):
         is_lead = sel & (st.role == LEADER)
         valid = valid | is_lead
         base = jnp.where(is_lead, st.last, base)
-        st, err = leader_append(
-            st, jnp.where(sel, n_new, 0),
-            jnp.full((g,), slot, jnp.int32), active=sel)
+        with jax.named_scope("round.append"):
+            st, err = leader_append(
+                st, jnp.where(sel, n_new, 0),
+                jnp.full((g,), slot, jnp.int32), active=sel)
         overflow |= err
         states[slot] = st
     # groups whose append was refused (overflow) must not key host
@@ -111,102 +113,104 @@ def _round_core(states, sels, n_new, drop, e, slots):
     # -- replication: leaders send, followers respond, quorum commits --
     for sel, slot in zip(sels, slots):
         lst = states[slot]
-        for peer in range(m):
-            if peer == slot:
-                continue
-            pst = states[peer]
-            # window: follower's next.. min(next+E-1, leader last)
-            nxt = jnp.take_along_axis(
-                lst.next_, jnp.full((g, 1), peer, jnp.int32),
-                axis=1)[:, 0]
-            # followers at a lower term adopt the leader's
-            # (raft.go:388-396); stale leaders don't send; removed /
-            # not-yet-added slots are masked edges on both ends
-            send = sel & (lst.term >= pst.term) & \
-                (lst.role == LEADER) & ~drop[slot, peer] & \
-                lst.members[:, slot] & lst.members[:, peer]
-            adopt = send & (lst.term > pst.term)
-            pst = pst._replace(
-                term=jnp.where(adopt, lst.term, pst.term),
-                vote=jnp.where(adopt, -1, pst.vote),
-                role=jnp.where(send, FOLLOWER, pst.role),
-                lead=jnp.where(send, slot, pst.lead))
-            # slow follower fell behind the leader's compaction
-            # point: send a snapshot instead (raft.go:207-209,
-            # needSnapshot :556); the follower's log collapses to
-            # the leader's offset entry and normal appends resume.
-            # The whole install path runs under lax.cond — in the
-            # serving steady state no lane ever needs a snapshot, and
-            # the masked [G, cap] log-collapse write was ~1/3 of each
-            # exchange's memory traffic (round-5 profile: the
-            # per-follower exchange is the serving round's cost)
-            needs_snap = send & (nxt <= lst.offset) & (lst.offset > 0)
-            peer_v = jnp.full((g,), peer, jnp.int32)
+        with jax.named_scope("round.exchange"):
+            for peer in range(m):
+                if peer == slot:
+                    continue
+                pst = states[peer]
+                # window: follower's next.. min(next+E-1, leader last)
+                nxt = jnp.take_along_axis(
+                    lst.next_, jnp.full((g, 1), peer, jnp.int32),
+                    axis=1)[:, 0]
+                # followers at a lower term adopt the leader's
+                # (raft.go:388-396); stale leaders don't send; removed /
+                # not-yet-added slots are masked edges on both ends
+                send = sel & (lst.term >= pst.term) & \
+                    (lst.role == LEADER) & ~drop[slot, peer] & \
+                    lst.members[:, slot] & lst.members[:, peer]
+                adopt = send & (lst.term > pst.term)
+                pst = pst._replace(
+                    term=jnp.where(adopt, lst.term, pst.term),
+                    vote=jnp.where(adopt, -1, pst.vote),
+                    role=jnp.where(send, FOLLOWER, pst.role),
+                    lead=jnp.where(send, slot, pst.lead))
+                # slow follower fell behind the leader's compaction
+                # point: send a snapshot instead (raft.go:207-209,
+                # needSnapshot :556); the follower's log collapses to
+                # the leader's offset entry and normal appends resume.
+                # The whole install path runs under lax.cond — in the
+                # serving steady state no lane ever needs a snapshot, and
+                # the masked [G, cap] log-collapse write was ~1/3 of each
+                # exchange's memory traffic (round-5 profile: the
+                # per-follower exchange is the serving round's cost)
+                needs_snap = send & (nxt <= lst.offset) & (lst.offset > 0)
+                peer_v = jnp.full((g,), peer, jnp.int32)
 
-            def with_snap(operand, lst=lst, needs_snap=needs_snap,
-                          peer_v=peer_v, peer=peer, slot=slot):
-                pst, nxt = operand
-                snap_term = term_at(lst.log_term, lst.offset,
-                                    lst.last, lst.offset)
-                follower_commit = pst.commit
-                pst, installed = restore_snapshot(
-                    pst, lst.offset, snap_term,
-                    commit=jnp.minimum(lst.commit, lst.offset),
-                    active=needs_snap, members=lst.members)
-                # installed lanes ack the snapshot index; lanes that
-                # rejected (commit already past it) reply with their
-                # commit, repairing the leader's stale next_ without
-                # any truncation (raft.go:419-424).  Both acks ride
-                # the response edge — droppable like any msgAppResp.
-                snap_ack = ~drop[peer, slot]
-                upd = progress_update(lst, peer_v, lst.offset,
-                                      active=installed & snap_ack)
-                rejected = needs_snap & ~installed
-                upd = progress_update(upd, peer_v, follower_commit,
-                                      active=rejected & snap_ack)
-                nxt = jnp.where(
-                    installed & snap_ack, lst.offset + 1,
-                    jnp.where(rejected & snap_ack,
-                              follower_commit + 1, nxt))
-                return (pst, nxt), (upd.next_, upd.match)
+                def with_snap(operand, lst=lst, needs_snap=needs_snap,
+                              peer_v=peer_v, peer=peer, slot=slot):
+                    pst, nxt = operand
+                    snap_term = term_at(lst.log_term, lst.offset,
+                                        lst.last, lst.offset)
+                    follower_commit = pst.commit
+                    pst, installed = restore_snapshot(
+                        pst, lst.offset, snap_term,
+                        commit=jnp.minimum(lst.commit, lst.offset),
+                        active=needs_snap, members=lst.members)
+                    # installed lanes ack the snapshot index; lanes that
+                    # rejected (commit already past it) reply with their
+                    # commit, repairing the leader's stale next_ without
+                    # any truncation (raft.go:419-424).  Both acks ride
+                    # the response edge — droppable like any msgAppResp.
+                    snap_ack = ~drop[peer, slot]
+                    upd = progress_update(lst, peer_v, lst.offset,
+                                          active=installed & snap_ack)
+                    rejected = needs_snap & ~installed
+                    upd = progress_update(upd, peer_v, follower_commit,
+                                          active=rejected & snap_ack)
+                    nxt = jnp.where(
+                        installed & snap_ack, lst.offset + 1,
+                        jnp.where(rejected & snap_ack,
+                                  follower_commit + 1, nxt))
+                    return (pst, nxt), (upd.next_, upd.match)
 
-            def no_snap(operand, lst=lst):
-                return operand, (lst.next_, lst.match)
+                def no_snap(operand, lst=lst):
+                    return operand, (lst.next_, lst.match)
 
-            (pst, nxt), (l_next, l_match) = jax.lax.cond(
-                needs_snap.any(), with_snap, no_snap, (pst, nxt))
-            lst = lst._replace(next_=l_next, match=l_match)
+                (pst, nxt), (l_next, l_match) = jax.lax.cond(
+                    needs_snap.any(), with_snap, no_snap, (pst, nxt))
+                lst = lst._replace(next_=l_next, match=l_match)
 
-            prev_idx = nxt - 1
-            prev_term = term_at(lst.log_term, lst.offset, lst.last,
-                                prev_idx)
-            n_send = jnp.clip(lst.last - prev_idx, 0, e)
-            ent_idx = prev_idx[:, None] + 1 + \
-                jnp.arange(e, dtype=jnp.int32)
-            ent_terms = term_at(lst.log_term, lst.offset, lst.last,
-                                ent_idx)
-            pst, ok, e_conf, e_over = maybe_append(
-                pst, prev_idx, prev_term, ent_terms, n_send,
-                lst.commit, active=send)
-            conflict |= e_conf
-            overflow |= e_over
-            # any append from the legitimate leader resets the
-            # follower's election timer (otherwise every follower
-            # would depose a healthy leader each `timeout` ticks)
-            pst = pst._replace(elapsed=jnp.where(send, 0, pst.elapsed))
-            states[peer] = pst
-            # msgAppResp: success → progress update; reject →
-            # progress_repair jumps next_ to the follower's commit+1
-            # (one round instead of the reference's decrement-by-one
-            # probe — see the helper's docstring for the safety
-            # argument and the wedge the SET semantics prevent)
-            resp_ok = send & ~drop[peer, slot]
-            acked = prev_idx + n_send
-            lst = progress_update(lst, peer_v, acked,
-                                  active=resp_ok & ok)
-            lst = progress_repair(lst, peer_v, pst.commit,
-                                  active=resp_ok & ~ok)
-        lst = maybe_commit(lst)
+                prev_idx = nxt - 1
+                prev_term = term_at(lst.log_term, lst.offset, lst.last,
+                                    prev_idx)
+                n_send = jnp.clip(lst.last - prev_idx, 0, e)
+                ent_idx = prev_idx[:, None] + 1 + \
+                    jnp.arange(e, dtype=jnp.int32)
+                ent_terms = term_at(lst.log_term, lst.offset, lst.last,
+                                    ent_idx)
+                pst, ok, e_conf, e_over = maybe_append(
+                    pst, prev_idx, prev_term, ent_terms, n_send,
+                    lst.commit, active=send)
+                conflict |= e_conf
+                overflow |= e_over
+                # any append from the legitimate leader resets the
+                # follower's election timer (otherwise every follower
+                # would depose a healthy leader each `timeout` ticks)
+                pst = pst._replace(elapsed=jnp.where(send, 0, pst.elapsed))
+                states[peer] = pst
+                # msgAppResp: success → progress update; reject →
+                # progress_repair jumps next_ to the follower's commit+1
+                # (one round instead of the reference's decrement-by-one
+                # probe — see the helper's docstring for the safety
+                # argument and the wedge the SET semantics prevent)
+                resp_ok = send & ~drop[peer, slot]
+                acked = prev_idx + n_send
+                lst = progress_update(lst, peer_v, acked,
+                                      active=resp_ok & ok)
+                lst = progress_repair(lst, peer_v, pst.commit,
+                                      active=resp_ok & ~ok)
+        with jax.named_scope("round.commit"):
+            lst = maybe_commit(lst)
         states[slot] = lst
 
     commits1 = states[0].commit
@@ -507,8 +511,14 @@ class MultiRaft:
         n_new = np.asarray(n_new, np.int32)
         dense = self._no_drop if not drop else \
             self._put_drop(_drop_dense(drop, self.m, g))
+        # the round's three parts, each a stage at the ledger's seam
+        # (the co-hosted engine's mg.consensus_round tiles into them):
+        # dispatch up to the jitted call's return, wait the first
+        # read-back, which blocks until the device has run the round,
+        # fetch the remaining read-backs and the payload bookkeeping
         _ledger.h2d("multiraft.round", n_new)
-        with _ledger.dispatch("multiraft.round"):
+        with tracer.stage("mg.round.dispatch", cpu=False), \
+                _ledger.dispatch("multiraft.round"):
             if self._route_hot is not None:
                 hot = self._route_hot
                 states, newly, valid, base, overflow, conflict = \
@@ -531,14 +541,17 @@ class MultiRaft:
         # self.leader), keyed from its pre-append last index; the
         # assignment arrays are kept for callers that key their own
         # bookkeeping (the multi-group server's wait registry)
-        self.last_valid = np.asarray(valid)
-        self.last_base = np.asarray(base)
-        if data is not None:
-            for gi in np.nonzero(self.last_valid)[0]:
-                for j, blob in enumerate(data[gi][:int(n_new[gi])]):
-                    self.payloads[gi][int(self.last_base[gi]) + 1 + j] \
-                        = blob
-        return _ledger.fetch("multiraft.round", newly)
+        with tracer.stage("mg.round.wait", cpu=False):
+            self.last_valid = _ledger.fetch("multiraft.round", valid)
+        with tracer.stage("mg.round.fetch", cpu=False):
+            self.last_base = _ledger.fetch("multiraft.round", base)
+            if data is not None:
+                for gi in np.nonzero(self.last_valid)[0]:
+                    for j, blob in enumerate(
+                            data[gi][:int(n_new[gi])]):
+                        self.payloads[gi][
+                            int(self.last_base[gi]) + 1 + j] = blob
+            return _ledger.fetch("multiraft.round", newly)
 
     def propose_rounds(self, n_new: np.ndarray, rounds: int,
                        drop=None) -> np.ndarray:

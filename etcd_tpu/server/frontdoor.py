@@ -62,6 +62,7 @@ from ..utils.errors import (
     EtcdError,
     EtcdOverCapacity,
 )
+from ..utils.trace import tracer
 from .server import gen_id
 
 log = logging.getLogger(__name__)
@@ -676,7 +677,7 @@ class FrontDoor:
             for item in batch:
                 kind = item[0]
                 if kind == "resp":
-                    _k, conn, epoch, parts, close = item
+                    _k, conn, epoch, parts, close, t_post = item
                     if conn.epoch != epoch or conn.mode != "busy":
                         continue  # conn was torn down meanwhile
                     if conn.tenant is not None:
@@ -686,6 +687,9 @@ class FrontDoor:
                     conn.close_after = conn.close_after or close
                     status, headers, body = parts
                     self._reply(conn, status, body, headers)
+                    tracer.record_wait(
+                        "fd.respond_wait",
+                        time.perf_counter() - t_post)
                     if conn.mode != "closed" \
                             and not conn.close_after:
                         self._process_rbuf(conn)
@@ -965,7 +969,7 @@ class FrontDoor:
                 self._jobs.put_nowait(
                     (conn, conn.epoch,
                      ("route", handler, method, path, parsed.query,
-                      headers, body)))
+                      headers, body), time.perf_counter()))
             except queue.Full:
                 conn.mode = "idle"
                 self._reply(conn, 503, b"overloaded\n",
@@ -1004,6 +1008,10 @@ class FrontDoor:
             self._reply(conn, 405, b"Method Not Allowed\n",
                         {"Allow": "GET,PUT,POST,DELETE"})
             return
+        # fd.parse: form, parse_request and the admission decision; a
+        # light record, since this runs for every request on the one
+        # loop thread and a full stage costs about 19 us there
+        t_parse = time.perf_counter()
         try:
             form = self._form(query, headers, body)
             rr = self._http.parse_request(method, path, form,
@@ -1030,6 +1038,7 @@ class FrontDoor:
         tenant = parse_tenant(headers, path)
         is_write = method != "GET"
         outcome, reason, ra = self.admission.decide(tenant, is_write)
+        tracer.record_wait("fd.parse", time.perf_counter() - t_parse)
         if outcome != ADMIT:
             self._reply_error(conn, EtcdOverCapacity(
                 cause=f"{tenant}: {reason}",
@@ -1047,7 +1056,8 @@ class FrontDoor:
         conn.tenant = tenant
         conn.mode = "busy"
         try:
-            self._jobs.put_nowait((conn, conn.epoch, rr))
+            self._jobs.put_nowait((conn, conn.epoch, rr,
+                                   time.perf_counter()))
         except queue.Full:
             # decide() raced a fill-up; shed honestly
             self.admission.finish(tenant)
@@ -1079,7 +1089,9 @@ class FrontDoor:
                 continue
             if job is None:
                 return
-            conn, epoch, rr = job
+            conn, epoch, rr, t_put = job
+            t_get = time.perf_counter()
+            tracer.record_wait("fd.worker_wait", t_get - t_put)
             try:
                 if type(rr) is tuple and rr[0] == "route":
                     _tag, handler, method, path, query, headers, \
@@ -1087,11 +1099,17 @@ class FrontDoor:
                     parts = handler(method, path, query, headers,
                                     body)
                 else:
+                    # do() may rename the method (a quorum GET)
+                    wait = "fd.do.get" if rr.method == "GET" \
+                        else "fd.do.put"
                     parts = self._do_request(rr)
+                    tracer.record_wait(
+                        wait, time.perf_counter() - t_get)
             except Exception as e:  # pragma: no cover
                 log.exception("frontdoor: worker error")
                 parts = _error_parts(e)
-            self._post(("resp", conn, epoch, parts, False))
+            self._post(("resp", conn, epoch, parts, False,
+                        time.perf_counter()))
 
     def _do_request(self, rr) -> tuple[int, dict, bytes]:
         """``(status, headers, body)`` — the loop thread assembles
@@ -1376,8 +1394,6 @@ class FrontDoor:
         elif sub == "leader":
             body = self.etcd.leader_stats.to_json()
         elif sub == "spans":
-            from ..utils.trace import tracer
-
             body = tracer.snapshot_json()
         elif sub == "slo":
             # declared-objective burn-rate verdict over the

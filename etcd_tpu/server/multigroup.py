@@ -102,6 +102,10 @@ class _Pending:
     # explicit group routing (ConfChange entries target a group
     # directly instead of hashing a client path)
     group: int | None = None
+    # stamps of the request's waits (perf_counter): made, which is
+    # when it is put on the engine's queue, and popped by _drain
+    t_put: float = field(default_factory=time.perf_counter)
+    t_pop: float = 0.0
 
 
 class MultiGroupServer:
@@ -237,24 +241,26 @@ class MultiGroupServer:
         frontier = np.zeros(g, np.int64)
         terms = np.zeros(g, np.int64)
         snap_index = 0
-        try:
-            snap = self.ss.load()
-        except NoSnapshotError:
-            snap = None
         applied_total = 0
-        if snap is not None:
-            blob = json.loads(snap.data.decode())
-            if len(blob["frontier"]) != g:
-                raise RuntimeError(
-                    f"snapshot was written with --cohosted-groups "
-                    f"{len(blob['frontier'])}, not {g}")
-            self.store.recovery(blob["store"].encode())
-            frontier = np.asarray(blob["frontier"], np.int64)
-            terms = np.asarray(blob["terms"], np.int64)
-            snap_index = blob["seq"]
-            applied_total = blob.get("applied_total", 0)
-            log.info("multigroup: restart from snapshot seq=%d",
-                     snap_index)
+        with tracer.stage("restart.snapshot_load"):
+            try:
+                snap = self.ss.load()
+            except NoSnapshotError:
+                snap = None
+            if snap is not None:
+                blob = json.loads(snap.data.decode())
+                if len(blob["frontier"]) != g:
+                    raise RuntimeError(
+                        f"snapshot was written with "
+                        f"--cohosted-groups "
+                        f"{len(blob['frontier'])}, not {g}")
+                self.store.recovery(blob["store"].encode())
+                frontier = np.asarray(blob["frontier"], np.int64)
+                terms = np.asarray(blob["terms"], np.int64)
+                snap_index = blob["seq"]
+                applied_total = blob.get("applied_total", 0)
+                log.info("multigroup: restart from snapshot seq=%d",
+                         snap_index)
         snap_frontier = frontier.copy()
         # an empty post-snapshot tail must not reset the sequence
         self.seq = snap_index
@@ -272,94 +278,96 @@ class MultiGroupServer:
             raise RuntimeError(
                 f"unexpected server id {info.id:x}, want {self.id:x}")
 
-        # array pass: ONE native envelope sweep + vectorized
-        # last-record-wins dedup and frontier selection — the device
-        # replay hands back struct-of-arrays and the restart stays in
-        # that shape instead of walking 1M GroupEntry objects
-        # (round-2 weakness #5)
-        stream = ge_stream_scan(raw)
-        if len(stream):
-            self.seq = max(self.seq, int(stream.seq.max()))
-        fpos = stream.last_of_kind(1)
-        if fpos >= 0:
-            v = np.frombuffer(stream.payload(fpos), np.int32)
-            if v.size != 2 * g:
-                raise RuntimeError(
-                    f"data dir was written with --cohosted-groups "
-                    f"{v.size // 2}, not {g}; group routing would "
-                    f"silently change")
-            frontier = v[:g].astype(np.int64)
-            terms = v[g:2 * g].astype(np.int64)
+        with tracer.stage("restart.apply"):
+            # array pass: ONE native envelope sweep + vectorized
+            # last-record-wins dedup and frontier selection — the device
+            # replay hands back struct-of-arrays and the restart stays in
+            # that shape instead of walking 1M GroupEntry objects
+            # (round-2 weakness #5)
+            stream = ge_stream_scan(raw)
+            if len(stream):
+                self.seq = max(self.seq, int(stream.seq.max()))
+            fpos = stream.last_of_kind(1)
+            if fpos >= 0:
+                v = np.frombuffer(stream.payload(fpos), np.int32)
+                if v.size != 2 * g:
+                    raise RuntimeError(
+                        f"data dir was written with --cohosted-groups "
+                        f"{v.size // 2}, not {g}; group routing would "
+                        f"silently change")
+                frontier = v[:g].astype(np.int64)
+                terms = v[g:2 * g].astype(np.int64)
 
-        # committed winners apply in stream order; only the applying
-        # slice materializes Python objects (CONFCHANGE entries touch
-        # the engine, not the store — they re-apply after seeding)
-        winners = stream.winner_positions()
-        committed = winners[
-            (stream.gindex[winners] > snap_frontier[
-                stream.group[winners]])
-            & (stream.gindex[winners] <= frontier[
-                stream.group[winners]])]
-        conf_changes: list[tuple[int, Request]] = []
-        applied_n = int(committed.size)
-        for k in committed:
-            payload = stream.payload(int(k))
-            if not payload:
-                continue
-            r = Request.unmarshal(payload)
-            if r.method == "CONFCHANGE":
-                conf_changes.append((int(stream.group[k]), r))
-            else:
-                apply_request_to_store(self.store, r)
+            # committed winners apply in stream order; only the applying
+            # slice materializes Python objects (CONFCHANGE entries touch
+            # the engine, not the store — they re-apply after seeding)
+            winners = stream.winner_positions()
+            committed = winners[
+                (stream.gindex[winners] > snap_frontier[
+                    stream.group[winners]])
+                & (stream.gindex[winners] <= frontier[
+                    stream.group[winners]])]
+            conf_changes: list[tuple[int, Request]] = []
+            applied_n = int(committed.size)
+            for k in committed:
+                payload = stream.payload(int(k))
+                if not payload:
+                    continue
+                r = Request.unmarshal(payload)
+                if r.method == "CONFCHANGE":
+                    conf_changes.append((int(stream.group[k]), r))
+                else:
+                    apply_request_to_store(self.store, r)
 
         self.applied = frontier.copy()
         self.raft_index = applied_total + applied_n
         self.raft_term = int(terms.max()) if g else 0
         self._snapi = self.raft_index
 
-        # re-seed consensus: every member holds the committed log in
-        # compacted form (offset = last = commit = applied = frontier,
-        # slot 0 carries the frontier term for match checks)
-        import jax.numpy as jnp
+        with tracer.stage("restart.seed"):
+            # re-seed consensus: every member holds the committed log in
+            # compacted form (offset = last = commit = applied = frontier,
+            # slot 0 carries the frontier term for match checks)
+            import jax.numpy as jnp
 
-        mr = MultiRaft(g, self.m, cap, max_batch_ents=max_batch_ents,
-                       live=self.live)
-        fr = jnp.asarray(frontier, jnp.int32)
-        tm = jnp.asarray(terms, jnp.int32)
-        slot0 = jnp.zeros((g, cap), jnp.int32).at[:, 0].set(tm)
-        members = None
-        if snap is not None and "members" in blob:
-            msnap = np.asarray(blob["members"], bool)
-            if msnap.shape[1] < self.m:
-                # restart with MORE spare slots: pad the mask (new
-                # slots start empty — the add_member migration path)
-                msnap = np.pad(msnap,
-                               ((0, 0), (0, self.m - msnap.shape[1])))
-            elif msnap.shape[1] > self.m:
-                extra = msnap[:, self.m:]
-                if extra.any():
-                    raise RuntimeError(
-                        f"snapshot uses member slot(s) >= {self.m}; "
-                        f"restart with spare_member_slots >= "
-                        f"{msnap.shape[1] - self.live}")
-                msnap = msnap[:, :self.m]
-            members = jnp.asarray(msnap)
-        for s in range(self.m):
-            st = mr.states[s]
-            st = st._replace(
-                term=tm, offset=fr, last=fr, commit=fr, applied=fr,
-                log_term=slot0)
-            if members is not None:
+            mr = MultiRaft(g, self.m, cap, max_batch_ents=max_batch_ents,
+                           live=self.live)
+            fr = jnp.asarray(frontier, jnp.int32)
+            tm = jnp.asarray(terms, jnp.int32)
+            slot0 = jnp.zeros((g, cap), jnp.int32).at[:, 0].set(tm)
+            members = None
+            if snap is not None and "members" in blob:
+                msnap = np.asarray(blob["members"], bool)
+                if msnap.shape[1] < self.m:
+                    # restart with MORE spare slots: pad the mask (new
+                    # slots start empty — the add_member migration path)
+                    msnap = np.pad(msnap,
+                                   ((0, 0), (0, self.m - msnap.shape[1])))
+                elif msnap.shape[1] > self.m:
+                    extra = msnap[:, self.m:]
+                    if extra.any():
+                        raise RuntimeError(
+                            f"snapshot uses member slot(s) >= {self.m}; "
+                            f"restart with spare_member_slots >= "
+                            f"{msnap.shape[1] - self.live}")
+                    msnap = msnap[:, :self.m]
+                members = jnp.asarray(msnap)
+            for s in range(self.m):
+                st = mr.states[s]
                 st = st._replace(
-                    members=members,
-                    nmembers=members.sum(axis=1).astype(jnp.int32))
-            mr.states[s] = st
-        self.mr = mr
-        # committed ConfChanges in the replayed window re-apply to
-        # the fresh engine (the snapshot's members mask carries
-        # everything below it)
-        for gi, r in conf_changes:
-            self._apply_conf_change(gi, r)
+                    term=tm, offset=fr, last=fr, commit=fr, applied=fr,
+                    log_term=slot0)
+                if members is not None:
+                    st = st._replace(
+                        members=members,
+                        nmembers=members.sum(axis=1).astype(jnp.int32))
+                mr.states[s] = st
+            self.mr = mr
+            # committed ConfChanges in the replayed window re-apply to
+            # the fresh engine (the snapshot's members mask carries
+            # everything below it)
+            for gi, r in conf_changes:
+                self._apply_conf_change(gi, r)
         log.info("multigroup: replayed %d records, %d applied, "
                  "max term %d", len(stream), applied_n,
                  self.raft_term)
@@ -373,7 +381,7 @@ class MultiGroupServer:
         # the first fused-round jit compile (seconds) must not eat
         # into early clients' 500ms request timeouts
         if (self.mr.leader < 0).any():
-            with tracer.span("mg.bootstrap_election"):
+            with tracer.stage("mg.bootstrap_election"):
                 self._campaign_and_fence(self.mr.leader < 0)
         else:
             self._absorb_commits({})
@@ -592,71 +600,93 @@ class MultiGroupServer:
                 self.store.delete_expired_keys(time.time())
                 next_sync = now + self.sync_interval
 
-            n_new = np.zeros(self.g, np.int32)
-            data: list[list[bytes]] = [[] for _ in range(self.g)]
-            items: list[list[_Pending]] = [[] for _ in range(self.g)]
-            for gi in range(self.g):
-                q = self._requeue[gi]
-                while q and len(items[gi]) < mr.e:
-                    items[gi].append(q.popleft())
-            for p in batch:
-                gi = p.group if p.group is not None \
-                    else group_of(p.req.path, self.g)
-                if len(items[gi]) >= mr.e:
-                    self._requeue[gi].append(p)
-                    continue
-                items[gi].append(p)
-            for gi in range(self.g):
-                n_new[gi] = len(items[gi])
-                data[gi] = [p.data for p in items[gi]]
-
-            if not n_new.any() and (mr.commit_index() ==
-                                    self.applied).all():
-                # idle heartbeat round only when a leader exists
-                if (mr.leader >= 0).any():
-                    mr.replicate()
-                self._absorb_commits({})
-                continue
-
-            with tracer.stage("mg.consensus_round"):
-                mr.propose(n_new, data=data)
-            valid = mr.last_valid
-            base = mr.last_base
-            terms_now = np.max(np.stack(
-                [np.asarray(st.term) for st in mr.states]),
-                axis=0).astype(np.int32)
-            assigned: dict[tuple[int, int], _Pending] = {}
-            to_persist: list[Entry] = []
-            for gi in range(self.g):
-                if not items[gi]:
-                    continue
-                if not valid[gi]:
-                    # no leader / overflow: retry a few rounds, then
-                    # fail the clients (reference: request timeout)
-                    for p in items[gi]:
-                        p.retries += 1
-                        if p.retries < 50:
+            # one iteration of the engine thread; the child stages
+            # tile it.  It is mg.pass where it runs a round, to the
+            # end of _absorb_commits, and learns after the pack
+            # whether it is an idle heartbeat instead
+            with tracer.stage("mg.pass") as it:
+                with tracer.stage("mg.pack", cpu=False) as pk:
+                    n_new = np.zeros(self.g, np.int32)
+                    data: list[list[bytes]] = [
+                        [] for _ in range(self.g)]
+                    items: list[list[_Pending]] = [
+                        [] for _ in range(self.g)]
+                    for gi in range(self.g):
+                        q = self._requeue[gi]
+                        while q and len(items[gi]) < mr.e:
+                            items[gi].append(q.popleft())
+                    for p in batch:
+                        gi = p.group if p.group is not None \
+                            else group_of(p.req.path, self.g)
+                        if len(items[gi]) >= mr.e:
                             self._requeue[gi].append(p)
-                        else:
-                            self.w.trigger(p.id, None)
-                    continue
-                for j, p in enumerate(items[gi]):
-                    idx = int(base[gi]) + 1 + j
-                    assigned[(gi, idx)] = p
-                    self.seq += 1
-                    to_persist.append(Entry(
-                        index=self.seq, term=self.raft_term,
-                        data=GroupEntry(
-                            kind=0, group=gi, gindex=idx,
-                            gterm=int(terms_now[gi]),
-                            payload=p.data).marshal()))
+                            continue
+                        items[gi].append(p)
+                    for gi in range(self.g):
+                        n_new[gi] = len(items[gi])
+                        data[gi] = [p.data for p in items[gi]]
+                    if not n_new.any() and (mr.commit_index() ==
+                                            self.applied).all():
+                        it.name = "mg.heartbeat"
+                        pk.name = "mg.heartbeat.pack"
 
-            self._absorb_commits(assigned, to_persist, terms_now)
-            if mr.errors["overflow"].any():
-                # compaction AFTER absorb: mark_applied(self.applied)
-                # inside _absorb_commits bounds it, so committed-but-
-                # unapplied payloads are never pruned
-                mr.compact()
+                if it.name == "mg.heartbeat":
+                    # idle heartbeat round only when a leader exists
+                    if (mr.leader >= 0).any():
+                        mr.replicate()
+                    self._absorb_commits({})
+                    continue
+
+                with tracer.stage("mg.consensus_round"):
+                    mr.propose(n_new, data=data)
+                with tracer.stage("mg.frontier_fetch", cpu=False):
+                    valid = mr.last_valid
+                    base = mr.last_base
+                    terms_now = np.max(np.stack(
+                        [np.asarray(st.term) for st in mr.states]),
+                        axis=0).astype(np.int32)
+                    commit = mr.commit_index()
+                with tracer.stage("mg.assign", cpu=False):
+                    assigned: dict[tuple[int, int], _Pending] = {}
+                    to_persist: list[Entry] = []
+                    for gi in range(self.g):
+                        if not items[gi]:
+                            continue
+                        if not valid[gi]:
+                            # no leader / overflow: retry a few
+                            # rounds, then fail the clients
+                            # (reference: request timeout)
+                            for p in items[gi]:
+                                p.retries += 1
+                                if p.retries < 50:
+                                    self._requeue[gi].append(p)
+                                else:
+                                    self.w.trigger(p.id, None)
+                            continue
+                        for j, p in enumerate(items[gi]):
+                            idx = int(base[gi]) + 1 + j
+                            assigned[(gi, idx)] = p
+                            self.seq += 1
+                            to_persist.append(Entry(
+                                index=self.seq, term=self.raft_term,
+                                data=GroupEntry(
+                                    kind=0, group=gi, gindex=idx,
+                                    gterm=int(terms_now[gi]),
+                                    payload=p.data).marshal()))
+
+                self._absorb_commits(assigned, to_persist, terms_now,
+                                     commit)
+                if mr.errors["overflow"].any():
+                    # compaction AFTER absorb: mark_applied(
+                    # self.applied) inside _absorb_commits bounds it,
+                    # so committed-but-unapplied payloads are never
+                    # pruned.  The flag stays here, outside
+                    # mg.frontier_fetch: its read-back lets go of the
+                    # GIL for a millisecond right after the
+                    # acknowledgements, and read before the absorb
+                    # instead, a quarter of the rounds at 10k groups
+                    # ran idle (PERF.md section 6, PR 27)
+                    mr.compact()
 
         # server stopping: promptly release EVERY waiter — the final
         # drained batch, anything still queued, and the requeues
@@ -677,31 +707,39 @@ class MultiGroupServer:
         """Block briefly for the first proposal, then sweep the rest
         (request pipelining: one device round serves the batch)."""
         out: list[_Pending] = []
-        try:
-            p = self._queue.get(timeout=timeout)
-        except queue.Empty:
-            return out
-        if p is not None:
-            out.append(p)
-        while True:
+        with tracer.stage("mg.drain_wait", cpu=False):
             try:
-                p = self._queue.get_nowait()
+                p = self._queue.get(timeout=timeout)
             except queue.Empty:
                 return out
-            if p is not None:
-                out.append(p)
+            while True:
+                if p is not None:
+                    out.append(p)
+                try:
+                    p = self._queue.get_nowait()
+                except queue.Empty:
+                    break
+            now = time.perf_counter()
+            for p in out:
+                p.t_pop = now
+                tracer.record_wait("mg.queue_wait", now - p.t_put)
+        return out
 
     def _absorb_commits(self, assigned, to_persist=None,
-                        terms_now=None) -> None:
+                        terms_now=None, commit=None) -> None:
         """Persist-then-apply: newly appended entries and the commit
         frontier go to the WAL (fsync) BEFORE any client ack — the
-        Ready contract's ordering (node.go:41-60) at batch level."""
+        Ready contract's ordering (node.go:41-60) at batch level.
+        ``commit``: the engine's commit index where the caller has
+        fetched it since its last round (mg.frontier_fetch)."""
         mr = self.mr
         if self._nospace:
             # applies and acks queue behind the held persist; the
             # recovery path re-runs this once the save lands
             return
-        commit = mr.commit_index().astype(np.int64)
+        if commit is None:
+            commit = mr.commit_index()
+        commit = commit.astype(np.int64)
         newly = commit > self.applied
         if to_persist or newly.any():
             terms = np.zeros(self.g, np.int32)
@@ -744,7 +782,8 @@ class MultiGroupServer:
             self._apply_newly(assigned, commit, newly)
         _M_APPLY_N.observe(n_apply)
         _M_APPLY_S.observe(time.perf_counter() - t0)
-        mr.mark_applied(self.applied)
+        with tracer.stage("mg.mark_applied", cpu=False):
+            mr.mark_applied(self.applied)
 
         if self.raft_index - self._snapi > self.snap_count:
             try:
@@ -823,6 +862,9 @@ class MultiGroupServer:
                 p = assigned.pop((int(gi), idx), None)
                 if p is not None:
                     self.w.trigger(p.id, resp)
+                    tracer.record_wait(
+                        "mg.commit_wait",
+                        time.perf_counter() - p.t_pop)
                 else:
                     # an entry assigned in an earlier round: find its
                     # waiter via the id embedded in the request
@@ -849,7 +891,7 @@ class MultiGroupServer:
             "members": np.asarray(self.mr.states[0].members)
             .astype(int).tolist(),
         }).encode()
-        with tracer.span("mg.snapshot"):
+        with tracer.stage("mg.snapshot"):
             snap_seq = self.seq
             self.ss.save_snap(Snapshot(data=blob, index=snap_seq,
                                        term=self.raft_term))

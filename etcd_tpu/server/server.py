@@ -606,7 +606,7 @@ def _replay_wal_raw(waldir: str, index: int, backend: str,
             from ..wal.replay_device import open_replay_device
 
             try:
-                with tracer.span("replay.device"):
+                with tracer.stage("replay.device"):
                     w, md, hard_state, block = open_replay_device(
                         waldir, index, route=route)
             except TornTailError:

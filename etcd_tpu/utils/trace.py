@@ -16,6 +16,14 @@ spans also appear in ``GET /metrics`` bucket form for free.  The
 ``/v2/stats/spans`` output is byte-stable against the pre-facade
 implementation — same keys, same percentile index rule
 (``sorted[min(n-1, int(n*q))]``), same rounding.
+
+``tracer.stage()`` is the one timing mechanism the benchmark reads
+(``etcd_stage_seconds{stage,kind}``).  Since PR 27 every stage is also
+a ``jax.profiler.TraceAnnotation`` of the same name, so a profiler
+session shows the program's stages as host events on the clock of the
+device's operations; ``tracer.record_wait`` is the light record: one
+wall sample under the same family, for a request's wait measured from
+stamps the request carries and for work done once per request.
 """
 
 from __future__ import annotations
@@ -23,6 +31,7 @@ from __future__ import annotations
 import json
 import logging
 import os
+import sys
 import threading
 import time
 
@@ -50,6 +59,33 @@ _stage_tls = threading.local()
 #: GIL-atomic dict store per stage pass; the profiler must never
 #: touch another thread's TLS)
 _active_stages: dict[int, str] = {}
+
+
+#: ``jax.profiler.TraceAnnotation`` once JAX is up, else None.  This
+#: module never imports JAX (the launcher parents that must not hold
+#: a chip import it): the class is looked up only when some other
+#: module has already put ``jax`` into ``sys.modules``.
+_annotation_cls = None
+
+
+def _annotation(name: str):
+    """An entered ``TraceAnnotation(name)``, or None while JAX is not
+    loaded.  With it every stage is a host event on the profiler's
+    clock, in the same ``.xplane.pb`` as the device's ``XLA Ops``;
+    without a profiler session a TraceMe is one flag test."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        try:
+            cls = _annotation_cls = jax.profiler.TraceAnnotation
+        except AttributeError:  # jax still half-imported
+            return None
+    ann = cls(name)
+    ann.__enter__()
+    return ann
 
 
 def active_stages() -> dict[int, str]:
@@ -86,13 +122,23 @@ class _StageCtx:
     """One pass through a labeled stage: wall + thread-CPU + device
     attribution.  Also records the plain span (the ``/v2/stats/
     spans`` surface keeps its coverage — byte-stable format, same
-    names)."""
+    names).
 
-    __slots__ = ("tracer", "name", "t0", "c0", "device_s")
+    ``cpu=False`` leaves the thread-CPU column out: the two
+    ``thread_time`` calls are most of a stage's cost where they are
+    system calls, so the children that tile a hot pass take wall (and
+    device) only and the pass itself carries the cpu.
 
-    def __init__(self, tracer: "Tracer", name: str):
+    ``name`` may be set again before the exit: the pass is filed under
+    the name it has then, for a pass that learns what it is on its way
+    (the annotation keeps the name it was opened with)."""
+
+    __slots__ = ("tracer", "name", "cpu", "t0", "c0", "device_s", "ann")
+
+    def __init__(self, tracer: "Tracer", name: str, cpu: bool = True):
         self.tracer = tracer
         self.name = name
+        self.cpu = cpu
 
     def __enter__(self):
         self.device_s = 0.0
@@ -101,17 +147,24 @@ class _StageCtx:
             stack = _stage_tls.stack = []
         stack.append(self)
         _active_stages[threading.get_ident()] = self.name
+        self.ann = _annotation(self.name)
         self.t0 = time.perf_counter()
-        self.c0 = time.thread_time()
+        if self.cpu:
+            self.c0 = time.thread_time()
         return self
 
     def __exit__(self, *exc):
-        cpu = time.thread_time() - self.c0
+        cpu = time.thread_time() - self.c0 if self.cpu else None
         wall = time.perf_counter() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         stack = _stage_tls.stack
         stack.pop()
         tid = threading.get_ident()
         if stack:
+            # all three columns are inclusive: a parent's wall and
+            # cpu hold its children's, so its device does too
+            stack[-1].device_s += self.device_s
             _active_stages[tid] = stack[-1].name
         else:
             _active_stages.pop(tid, None)
@@ -135,20 +188,42 @@ class Tracer:
         # the histogram lock (catalog/label validation only on first
         # use) — the old deque implementation's cost profile
         self._hists: dict[str, _metrics.Histogram] = {}
-        # per-stage handle cache: (wall hist, cpu hist, device hist,
-        # spans counter) — record_stage runs per serving-loop pass
-        self._stages: dict[str, tuple] = {}
+        # (stage, kind) handle cache of etcd_stage_seconds, made on a
+        # kind's first sample — record_stage runs per serving-loop
+        # pass, record_wait per request
+        self._stages: dict[tuple[str, str], _metrics.Histogram] = {}
 
     def span(self, name: str) -> _Span:
         return _Span(self, name)
 
-    def stage(self, name: str) -> _StageCtx:
+    def stage(self, name: str, cpu: bool = True) -> _StageCtx:
         """Like :meth:`span`, plus per-stage CPU/device attribution:
         the pass lands in ``etcd_stage_seconds{stage,kind}`` (wall |
-        cpu | device) and bumps ``etcd_trace_spans_total{stage}``.
-        The plain span family still gets the wall sample, so
-        ``/v2/stats/spans`` output is unchanged."""
-        return _StageCtx(self, name)
+        cpu | device; the wall child's ``count`` is the number of
+        passes; no cpu sample with ``cpu=False``) and, once JAX is
+        loaded, is a ``TraceAnnotation`` of the same name on the
+        profiler's clock.  The plain span family still gets the wall
+        sample, so ``/v2/stats/spans`` output is unchanged."""
+        return _StageCtx(self, name, cpu)
+
+    def _stage_hist(self, name: str, kind: str) -> _metrics.Histogram:
+        h = self._stages.get((name, kind))
+        if h is None:
+            h = self._stages[name, kind] = self._reg.histogram(
+                "etcd_stage_seconds", stage=name, kind=kind)
+        return h
+
+    def record_wait(self, name: str, seconds: float) -> None:
+        """The light record: one sample in
+        ``etcd_stage_seconds{stage=name,kind="wall"}`` and nothing
+        else.  For a wait some request sat through, measured by the
+        caller from stamps that ride on the request, and for a short
+        piece of work done once for every request (``fd.parse``).  No
+        span, no cpu, no annotation: the waits of many requests
+        overlap, and an annotation each would cover, and so name,
+        every idle gap of a trace; and a whole stage costs 19 us on
+        the v5e's host against 0.8 us for this."""
+        self._stage_hist(name, "wall").observe(seconds)
 
     def record(self, name: str, dt: float) -> None:
         h = self._hists.get(name)
@@ -157,27 +232,17 @@ class Tracer:
                 "etcd_span_seconds", span=name)
         h.observe(dt)
 
-    def record_stage(self, name: str, wall: float, cpu: float,
+    def record_stage(self, name: str, wall: float,
+                     cpu: float | None = None,
                      device: float = 0.0) -> None:
-        handles = self._stages.get(name)
-        if handles is None:
-            handles = self._stages[name] = (
-                self._reg.histogram("etcd_stage_seconds",
-                                    stage=name, kind="wall"),
-                self._reg.histogram("etcd_stage_seconds",
-                                    stage=name, kind="cpu"),
-                self._reg.histogram("etcd_stage_seconds",
-                                    stage=name, kind="device"),
-                self._reg.counter("etcd_trace_spans_total",
-                                  stage=name))
-        handles[0].observe(wall)
-        handles[1].observe(cpu)
+        self._stage_hist(name, "wall").observe(wall)
+        if cpu is not None:
+            self._stage_hist(name, "cpu").observe(cpu)
         if device > 0.0:
             # device samples only when the stage actually crossed a
             # ledger seam — an all-zero series would drown the sums'
             # signal in sample count without adding information
-            handles[2].observe(device)
-        handles[3].inc()
+            self._stage_hist(name, "device").observe(device)
 
     def snapshot(self) -> dict:
         out = {}
@@ -209,11 +274,10 @@ class Tracer:
         self._hists = {}
         self._stages = {}
         self._reg.family(_SPAN_FAMILY).clear()
-        for fam in ("etcd_stage_seconds", "etcd_trace_spans_total"):
-            try:
-                self._reg.family(fam).clear()
-            except KeyError:  # pragma: no cover - custom catalogs
-                pass
+        try:
+            self._reg.family("etcd_stage_seconds").clear()
+        except KeyError:  # pragma: no cover - custom catalogs
+            pass
 
 
 #: process-wide default tracer — servers and replay paths record here

@@ -38,6 +38,7 @@ import numpy as np
 from .. import native
 from ..obs import metrics as _obs
 from ..obs.devledger import ledger as _ledger
+from ..utils.trace import tracer
 from ..wire import Entry, HardState
 from ..wire.proto import ProtoError
 from .backend_policy import DEFAULT_CHUNK_BYTES, get_policy
@@ -180,6 +181,19 @@ def _accelerator_absent() -> bool:
         return True
 
 
+def _raw_crc_rows(rows):
+    """``raw_crc_batch(rows)`` with the build and upload of the rows'
+    contribution matrix as the replay's stage ``replay.matrix``: a
+    width's first build is seconds of host work at the wide classes."""
+    import jax.numpy as jnp
+
+    from ..ops.crc_device import contribution_matrix, raw_crc_batch
+
+    with tracer.stage("replay.matrix"):
+        c = jnp.asarray(contribution_matrix(rows.shape[1]))
+    return raw_crc_batch(rows, c=c)
+
+
 def _pad_rows_numpy(blob, doff, dlen, width):
     n = doff.size
     out = np.zeros((n, width), np.uint8)
@@ -225,9 +239,9 @@ class DeviceTransport:
         return jax.device_put(rows)
 
     def verify(self, shipped, stored: np.ndarray):
-        from ..ops.crc_device import chain_links_injected, raw_crc_batch
+        from ..ops.crc_device import chain_links_injected
 
-        return chain_links_injected(raw_crc_batch(shipped), stored)
+        return chain_links_injected(_raw_crc_rows(shipped), stored)
 
     def collect(self, handle) -> np.ndarray:
         return np.asarray(handle)
@@ -533,7 +547,7 @@ def verify_chain_device(blob: np.ndarray, types, crcs, doff, dlen,
             f"crc chain broken at record {bad} "
             f"(stored={int(crcs[bad]):#x})")
 
-    from ..ops.crc_device import _chain_expected, raw_crc_batch
+    from ..ops.crc_device import _chain_expected
 
     stored = np.ascontiguousarray(crcs[start:], np.uint32)
     prev = np.concatenate(
@@ -581,7 +595,7 @@ def verify_chain_device(blob: np.ndarray, types, crcs, doff, dlen,
             _ledger.h2d("replay.verify", rows)
             with _ledger.dispatch("replay.verify"):
                 ok = np.asarray(
-                    _chain_expected(pv, raw_crc_batch(rows),
+                    _chain_expected(pv, _raw_crc_rows(rows),
                                     d_len.astype(np.uint32)) == st)
             _ledger.d2h("replay.verify", ok)
             if not ok.all():

@@ -1,7 +1,7 @@
 """Observability subsystem (PR 2): registry/histogram exactness,
-Prometheus exposition conformance, roofline refusal path, devledger
-accounting, Tracer-facade backward compatibility, and the
-metrics-vocabulary lint checker."""
+Prometheus exposition conformance, devledger accounting,
+Tracer-facade backward compatibility, and the metrics-vocabulary lint
+checker."""
 
 import json
 import re
@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from etcd_tpu.analysis import MetricsVocabularyChecker, run_checkers
-from etcd_tpu.obs import exporter, roofline
+from etcd_tpu.obs import exporter
 from etcd_tpu.obs.devledger import DeviceLedger
 from etcd_tpu.obs.metrics import (
     CATALOG,
@@ -174,46 +174,6 @@ def test_metrics_endpoint_on_client_api(tmp_path):
     finally:
         httpd.shutdown()
         s.stop()
-
-
-# -- 3. roofline refusal path -------------------------------------------------
-
-
-def test_roofline_mfu_fields_clean_case():
-    # 1M entries/s at width 384 = 0.1966 useful TFLOPS; ceiling 10
-    f = roofline.mfu_fields(1e6, 384, measured_tflops_bf16=10.0,
-                            measured_tops_int8=20.0)
-    assert f["flops_per_entry"] == 512 * 384
-    assert f["flops_per_entry_honest"] == 512 * 256
-    assert f["sustained_useful_tflops"] == round(
-        1e6 * 512 * 384 / 1e12, 4)
-    assert f["pct_of_measured_ceiling"] == pytest.approx(1.97, 0.01)
-    assert f["pct_of_measured_ceiling_honest"] < \
-        f["pct_of_measured_ceiling"]
-    assert "ceiling_suspect" not in f
-    assert "ceiling_provenance" not in f
-
-
-def test_roofline_refuses_impossible_ceiling_silently():
-    # the 408%-of-ceiling artifact class: eps implies 4x the ceiling
-    prov = {"probe": "unit-test", "bf16_tflops": 0.05}
-    f = roofline.mfu_fields(1e6, 384, measured_tflops_bf16=0.05,
-                            provenance=prov)
-    assert f["pct_of_measured_ceiling"] > 100.0
-    assert f["ceiling_suspect"] is True
-    assert f["ceiling_provenance"] == prov
-    # provenance defaulting: refusal NEVER lacks provenance
-    f2 = roofline.mfu_fields(1e6, 384, measured_tflops_bf16=0.05)
-    assert f2["ceiling_suspect"] is True
-    assert f2["ceiling_provenance"] == "unspecified"
-
-
-def test_roofline_without_ceiling_emits_flop_fields_only():
-    f = roofline.mfu_fields(2e6, 512)
-    assert f["flops_per_entry"] == 512 * 512
-    assert "pct_of_measured_ceiling" not in f
-    assert "entries_per_sec_per_tflop" not in f
-    assert "ceiling_suspect" not in f
 
 
 # -- 4. devledger on a fake-dispatch fixture ----------------------------------

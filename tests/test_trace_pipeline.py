@@ -160,11 +160,73 @@ def test_stage_records_wall_cpu_and_device():
     assert wall.count == 1 and cpu.count == 1
     assert dev.count == 1 and abs(dev.sum - 0.125) < 1e-9
     assert cpu.sum > 0
-    assert reg.counter("etcd_trace_spans_total", stage="s1") \
-        .get() == 1
+    # the wall child's count IS the number of passes
+    with t.stage("s1"):
+        pass
+    assert wall.count == 2 and dev.count == 1
     # the wall sample also landed in the span family: the
     # /v2/stats/spans surface keeps its coverage
     assert "s1" in t.snapshot()
+
+
+def stage_counts(reg, stage: str) -> dict[str, int]:
+    fam = reg.snapshot(light=True)["etcd_stage_seconds"]["samples"]
+    return {c["labels"]["kind"]: c["count"] for c in fam
+            if c["labels"]["stage"] == stage}
+
+
+@pytest.mark.parametrize("cpu", [True, False])
+def test_stage_takes_thread_time_only_where_asked(cpu, monkeypatch):
+    """A child that tiles a hot pass is opened with ``cpu=False``: it
+    makes no ``thread_time`` call and files no cpu sample, and its
+    device seconds still pass into the stage around it."""
+    from etcd_tpu.utils import trace as trace_mod
+
+    calls = []
+    real = time.thread_time
+    monkeypatch.setattr(trace_mod.time, "thread_time",
+                        lambda: calls.append(1) or real())
+    reg = Registry()
+    t = trace_mod.Tracer(reg)
+    with t.stage("outer"):
+        n_outer = len(calls)
+        with t.stage("inner", cpu=cpu):
+            trace_mod.note_device_seconds(0.25)
+        n_inner = len(calls) - n_outer
+    assert n_inner == (2 if cpu else 0)
+    assert stage_counts(reg, "inner") == (
+        {"wall": 1, "cpu": 1, "device": 1} if cpu
+        else {"wall": 1, "device": 1})
+    assert stage_counts(reg, "outer") == {"wall": 1, "cpu": 1,
+                                          "device": 1}
+
+
+def test_stage_is_filed_under_the_name_it_has_at_its_exit():
+    """The engine loop opens an iteration as mg.pass and names it
+    mg.heartbeat once its pack has found nothing to propose."""
+    from etcd_tpu.utils.trace import Tracer
+
+    reg = Registry()
+    t = Tracer(reg)
+    with t.stage("first") as ctx:
+        ctx.name = "second"
+    assert stage_counts(reg, "first") == {}
+    assert stage_counts(reg, "second") == {"wall": 1, "cpu": 1}
+    assert "second" in t.snapshot() and "first" not in t.snapshot()
+
+
+def test_light_record_is_one_wall_sample_in_the_stage_family():
+    from etcd_tpu.utils.trace import Tracer
+
+    reg = Registry()
+    t = Tracer(reg)
+    t.record_wait("w", 0.5)
+    t.record_wait("w", 0.25)
+    assert stage_counts(reg, "w") == {"wall": 2}
+    assert t.snapshot() == {}          # no span
+    t.reset()
+    t.record_wait("w", 0.5)            # the cache dropped with reset
+    assert stage_counts(reg, "w") == {"wall": 1}
 
 
 def test_devledger_charges_device_once_inside_stage():
@@ -224,8 +286,13 @@ def test_trace_spans_flow_end_to_end(traced_cluster):
     stages = {e["stage"] for e in spans}
     assert {"ingest", "append", "leader_fsync", "commit", "apply",
             "client_ack"} <= stages
-    # one trace id walks every origin stage
-    tid = next(e["trace"] for e in spans if e["stage"] == "ingest")
+    # one trace id walks every origin stage.  Chosen from the far end:
+    # with every ingest sampled, the first id belongs to a proposal of
+    # the cluster's own start (the member's registration), and where
+    # its lane lost leadership in the bootstrap's timer races its
+    # trace context was dropped with the lane (distserver purges
+    # _trace_live of deposed lanes) and rightly ends at leader_fsync
+    tid = next(e["trace"] for e in spans if e["stage"] == "client_ack")
     mine = {e["stage"] for e in spans if e["trace"] == tid}
     assert {"ingest", "append", "leader_fsync", "commit", "apply",
             "client_ack"} <= mine
