@@ -84,6 +84,11 @@ _DEFS = (
         "Entries applied per apply-loop batch.",
         buckets=SIZE_BUCKETS),
     MetricDef(
+        "etcd_pack_groups_visited", "histogram",
+        "Groups the co-hosted engine's pack visited in one pass, "
+        "requeued and new together (0 on an idle pass): the pack's "
+        "cost follows this, not G.", buckets=SIZE_BUCKETS),
+    MetricDef(
         "etcd_election_campaigns_total", "counter",
         "Per-group election campaign lanes fired."),
     MetricDef(

@@ -29,6 +29,7 @@ device owns index/term/commit math, the host owns opaque blobs.
 
 from __future__ import annotations
 
+from collections.abc import Mapping, Sequence
 from functools import partial
 
 import numpy as np
@@ -502,11 +503,13 @@ class MultiRaft:
     # -- the replication hot path (one fused device call per round) ------
 
     def propose(self, n_new: np.ndarray,
-                data: list[list[bytes]] | None = None,
+                data: Sequence[list[bytes]] | Mapping[int, list[bytes]]
+                | None = None,
                 drop=None) -> np.ndarray:
         """Append ``n_new[g]`` proposals to each group's leader and
-        run one full replicate→respond→commit round.  Returns the
-        per-group count of newly committed entries."""
+        run one full replicate→respond→commit round.  ``data[g]``
+        holds the payloads of every group with ``n_new[g] > 0``.
+        Returns the per-group count of newly committed entries."""
         g = self.g
         n_new = np.asarray(n_new, np.int32)
         dense = self._no_drop if not drop else \
@@ -546,7 +549,9 @@ class MultiRaft:
         with tracer.stage("mg.round.fetch", cpu=False):
             self.last_base = _ledger.fetch("multiraft.round", base)
             if data is not None:
-                for gi in np.nonzero(self.last_valid)[0]:
+                # only the groups that took proposals, so a mapping
+                # of those answers as a list of G lists does
+                for gi in np.nonzero(self.last_valid & (n_new > 0))[0]:
                     for j, blob in enumerate(
                             data[gi][:int(n_new[gi])]):
                         self.payloads[gi][

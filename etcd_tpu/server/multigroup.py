@@ -73,6 +73,7 @@ TICK_INTERVAL = 0.1        # reference server.go:182
 # obs seams (PR 2): apply-loop shape + election churn, process-wide
 _M_APPLY_S = _obs.registry.histogram("etcd_apply_seconds")
 _M_APPLY_N = _obs.registry.histogram("etcd_apply_batch_entries")
+_M_PACK_GROUPS = _obs.registry.histogram("etcd_pack_groups_visited")
 _M_CAMPAIGNS = _obs.registry.counter("etcd_election_campaigns_total")
 _M_WINS = _obs.registry.counter("etcd_election_wins_total")
 # read serve paths (PR 7): the co-hosted tier is single-copy — every
@@ -153,7 +154,10 @@ class MultiGroupServer:
         self.done = threading.Event()
         self._thread: threading.Thread | None = None
         self._queue: queue.Queue[_Pending | None] = queue.Queue()
-        self._requeue: list[deque[_Pending]] = [deque() for _ in range(g)]
+        # group -> proposals held over for a later round, in arrival
+        # order; only non-empty deques are kept, so the pass walks the
+        # groups that have work and never range(g)
+        self._requeue: dict[int, deque[_Pending]] = {}
 
         self.server_stats = ServerStats(name, self.id)
         self.leader_stats = LeaderStats(self.id)
@@ -581,10 +585,7 @@ class MultiGroupServer:
                     cause="member is read-only (NOSPACE)")
                 for p in batch:
                     self.w.trigger(p.id, Response(err=err))
-                for q in self._requeue:
-                    while q:
-                        self.w.trigger(q.popleft().id,
-                                       Response(err=err))
+                self._release_requeued(Response(err=err))
                 if now >= self._nospace_probe_t:
                     self._nospace_recover()
                 continue
@@ -606,25 +607,30 @@ class MultiGroupServer:
             # whether it is an idle heartbeat instead
             with tracer.stage("mg.pass") as it:
                 with tracer.stage("mg.pack", cpu=False) as pk:
+                    # items: group -> its proposals of this round,
+                    # holding only the groups that have work (the
+                    # requeued first, then the batch); data is the
+                    # same mapping's payloads
                     n_new = np.zeros(self.g, np.int32)
-                    data: list[list[bytes]] = [
-                        [] for _ in range(self.g)]
-                    items: list[list[_Pending]] = [
-                        [] for _ in range(self.g)]
-                    for gi in range(self.g):
-                        q = self._requeue[gi]
-                        while q and len(items[gi]) < mr.e:
-                            items[gi].append(q.popleft())
+                    items: dict[int, list[_Pending]] = {}
+                    for gi, q in list(self._requeue.items()):
+                        items[gi] = [q.popleft() for _ in
+                                     range(min(len(q), mr.e))]
+                        if not q:
+                            del self._requeue[gi]
                     for p in batch:
                         gi = p.group if p.group is not None \
                             else group_of(p.req.path, self.g)
-                        if len(items[gi]) >= mr.e:
-                            self._requeue[gi].append(p)
+                        got = items.setdefault(gi, [])
+                        if len(got) >= mr.e:
+                            self._hold(gi, p)
                             continue
-                        items[gi].append(p)
-                    for gi in range(self.g):
-                        n_new[gi] = len(items[gi])
-                        data[gi] = [p.data for p in items[gi]]
+                        got.append(p)
+                    data: dict[int, list[bytes]] = {}
+                    for gi, got in items.items():
+                        n_new[gi] = len(got)
+                        data[gi] = [p.data for p in got]
+                    _M_PACK_GROUPS.observe(len(items))
                     if not n_new.any() and (mr.commit_index() ==
                                             self.applied).all():
                         it.name = "mg.heartbeat"
@@ -649,9 +655,7 @@ class MultiGroupServer:
                 with tracer.stage("mg.assign", cpu=False):
                     assigned: dict[tuple[int, int], _Pending] = {}
                     to_persist: list[Entry] = []
-                    for gi in range(self.g):
-                        if not items[gi]:
-                            continue
+                    for gi in sorted(items):
                         if not valid[gi]:
                             # no leader / overflow: retry a few
                             # rounds, then fail the clients
@@ -659,7 +663,7 @@ class MultiGroupServer:
                             for p in items[gi]:
                                 p.retries += 1
                                 if p.retries < 50:
-                                    self._requeue[gi].append(p)
+                                    self._hold(gi, p)
                                 else:
                                     self.w.trigger(p.id, None)
                             continue
@@ -699,9 +703,18 @@ class MultiGroupServer:
                 break
             if p is not None:
                 self.w.trigger(p.id, None)
-        for q in self._requeue:
-            while q:
-                self.w.trigger(q.popleft().id, None)
+        self._release_requeued(None)
+
+    def _hold(self, gi: int, p: _Pending) -> None:
+        """Keep ``p`` for a later round of group ``gi``."""
+        self._requeue.setdefault(gi, deque()).append(p)
+
+    def _release_requeued(self, resp) -> None:
+        """Answer every requeued waiter with ``resp`` and forget it."""
+        for q in self._requeue.values():
+            for p in q:
+                self.w.trigger(p.id, resp)
+        self._requeue.clear()
 
     def _drain(self, timeout: float) -> list[_Pending]:
         """Block briefly for the first proposal, then sweep the rest

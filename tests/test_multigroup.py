@@ -513,3 +513,257 @@ def test_multigroup_restart_heals_torn_wal_tail(tmp_path):
         assert got >= 2
     finally:
         s2.stop()
+
+
+# -- the pass keeps its bookkeeping by the groups that have work --------------
+
+
+def _gated(s):
+    """Start ``s`` with its engine thread held at its first ``_drain``
+    and return the gate: what is enqueued before ``gate.set()`` is one
+    batch of one pass, whatever the scheduler does."""
+    gate = threading.Event()
+    drain = s._drain
+
+    def held(timeout):
+        gate.wait()
+        return drain(timeout)
+
+    s._drain = held
+    s.start()
+    return gate
+
+
+def _enqueue(s, rid, path, val):
+    """What ``do()`` does for a write, less the wait: returns the
+    waiter's channel."""
+    from etcd_tpu.server.multigroup import _Pending
+
+    r = Request(id=rid, method="PUT", path=path, val=val)
+    ch = s.w.register(rid)
+    s._queue.put(_Pending(req=r, data=r.marshal(), id=rid))
+    return ch
+
+
+def _tenants(g, n):
+    """``n`` tenant names that route to ``n`` different groups, and
+    those groups."""
+    names, groups = [], []
+    for i in range(10 * n):
+        gi = group_of(f"/tn{i}/k", g)
+        if gi not in groups:
+            names.append(f"tn{i}")
+            groups.append(gi)
+        if len(names) == n:
+            return names, groups
+    raise AssertionError("no spread")
+
+
+def _pack_groups():
+    from etcd_tpu.server.multigroup import _M_PACK_GROUPS
+
+    return _M_PACK_GROUPS.count, _M_PACK_GROUPS.sum
+
+
+def test_pack_visits_only_the_groups_that_have_work(tmp_path):
+    """Three writes to three tenants in one pass at g = 4096: the pack
+    visits three groups, and the idle passes around it none."""
+    s = _mk(tmp_path, g=4096)
+    names, groups = _tenants(4096, 3)
+    packs0, visited0 = _pack_groups()
+    gate = _gated(s)
+    try:
+        chans = [_enqueue(s, 7000 + i, f"/{t}/k", f"v{i}")
+                 for i, t in enumerate(names)]
+        gate.set()
+        for i, ch in enumerate(chans):
+            resp = ch.get(timeout=90)
+            assert resp.err is None and resp.event.node.value == f"v{i}"
+        packs, visited = _pack_groups()
+        assert visited - visited0 == 3
+        assert packs - packs0 >= 1
+        assert s._requeue == {}
+        for t, gi in zip(names, groups):
+            assert s.applied[gi] == 2      # the leader's entry + one
+            assert _get(s, f"/{t}/k").event.node.value is not None
+    finally:
+        gate.set()
+        s.stop()
+
+
+def test_spill_past_the_round_cap_keeps_arrival_order(tmp_path):
+    """More than ``mr.e`` writes to one group in one batch: the first
+    ``mr.e`` go this round, the rest wait in the group's requeue and
+    go in later passes in arrival order; the entry is gone once
+    drained."""
+    s = _mk(tmp_path, max_batch_ents=4)
+    assert s.mr.e == 4
+    gi = group_of("/spill/k", G)
+    _, visited0 = _pack_groups()
+    gate = _gated(s)
+    try:
+        chans = [_enqueue(s, 7100 + i, "/spill/k", f"v{i}")
+                 for i in range(11)]
+        gate.set()
+        resps = [ch.get(timeout=90) for ch in chans]
+        assert [r.event.node.value for r in resps] == \
+            [f"v{i}" for i in range(11)]
+        idx = [r.event.node.modified_index for r in resps]
+        assert idx == sorted(idx) and len(set(idx)) == 11
+        assert _get(s, "/spill/k").event.node.value == "v10"
+        # 4 + 4 + 3: one group visited in each of three passes
+        assert _pack_groups()[1] - visited0 == 3
+        assert s.applied[gi] == 12
+        assert s._requeue == {}
+    finally:
+        gate.set()
+        s.stop()
+
+
+def test_stop_releases_every_requeued_waiter(tmp_path):
+    """The server stops with writes in a group's requeue: each waiter
+    is released at once, and the requeue is empty."""
+    s = _mk(tmp_path, max_batch_ents=2)
+    absorb = s._absorb_commits
+
+    def absorb_then_stop(assigned, *a, **kw):
+        mine = bool(assigned)          # absorb pops what it applies
+        absorb(assigned, *a, **kw)
+        if mine:
+            s.done.set()               # the loop ends after this pass
+
+    s._absorb_commits = absorb_then_stop
+    gate = _gated(s)
+    try:
+        chans = [_enqueue(s, 7200 + i, "/held/k", f"v{i}")
+                 for i in range(6)]
+        gate.set()
+        got = [ch.get(timeout=90) for ch in chans]
+        assert [r.event.node.value for r in got[:2]] == ["v0", "v1"]
+        assert got[2:] == [None] * 4
+        s._thread.join(timeout=30)
+        assert s._requeue == {}
+    finally:
+        gate.set()
+        s.stop()
+
+
+def test_nospace_rejects_every_requeued_waiter(tmp_path):
+    """A full disk under a pass that left writes in the requeue: the
+    held round is acknowledged after the recovery, every requeued
+    write is rejected with the typed code, none is lost or left."""
+    from etcd_tpu.utils import faults as faults_mod
+    from etcd_tpu.utils.errors import ECODE_NO_SPACE
+
+    s = _mk(tmp_path, max_batch_ents=2)
+    gate = _gated(s)
+    try:
+        faults_mod.FAULTS.configure("wal.append=enospc(for=0.5s)")
+        chans = [_enqueue(s, 7300 + i, "/full/k", f"v{i}")
+                 for i in range(6)]
+        gate.set()
+        got = [ch.get(timeout=90) for ch in chans]
+        assert [r.event.node.value for r in got[:2]] == ["v0", "v1"]
+        assert [r.err.error_code for r in got[2:]] == \
+            [ECODE_NO_SPACE] * 4
+        assert s._requeue == {}
+        assert _put(s, "/full/k", "after").event.node.value == "after"
+    finally:
+        faults_mod.FAULTS.configure("")
+        gate.set()
+        s.stop()
+
+
+def test_wal_order_of_a_batch_is_ascending_group_then_arrival(tmp_path):
+    """A fixed batch over several groups, arriving in no group order:
+    the WAL holds its entries by ascending group and, within a group,
+    by arrival, each the bytes the rule gives (what a restart replays
+    and what the pass wrote before it kept its bookkeeping by touched
+    groups)."""
+    from etcd_tpu.wal import WAL
+    from etcd_tpu.wire import Entry, GroupEntry
+
+    names, groups = _tenants(G, 4)
+    order = [2, 0, 3, 2, 1, 0, 2]      # tenants, as the writes arrive
+    s = _mk(tmp_path)
+    gate = _gated(s)
+    try:
+        seq0, term = s.seq, s.raft_term
+        reqs = [Request(id=7400 + i, method="PUT",
+                        path=f"/{names[t]}/k", val=f"v{i}")
+                for i, t in enumerate(order)]
+        chans = [_enqueue(s, r.id, r.path, r.val) for r in reqs]
+        gate.set()
+        for ch in chans:
+            assert ch.get(timeout=90).err is None
+    finally:
+        gate.set()
+        s.stop()
+    by_group = sorted(range(len(order)),
+                      key=lambda i: groups[order[i]])   # stable
+    want, nth = [], {}
+    for k, i in enumerate(by_group):
+        gi = groups[order[i]]
+        nth[gi] = nth.get(gi, 0) + 1
+        want.append(Entry(
+            index=seq0 + 1 + k, term=term, data=GroupEntry(
+                kind=0, group=gi, gindex=1 + nth[gi], gterm=1,
+                payload=reqs[i].marshal()).marshal()).marshal())
+    w = WAL.open_at_index(str(tmp_path / "data" / "wal"), 0)
+    try:
+        _, _, ents = w.read_all()
+    finally:
+        w.close()
+    got = [e.marshal() for e in ents
+           if seq0 < e.index <= seq0 + len(order)]
+    assert got == want
+
+
+def test_pack_groups_per_pass_as_the_benchmark_reads_it(tmp_path):
+    """``benchmark/layer_metrics/pack_groups_per_pass.json`` through
+    the benchmark's own reader, over registry snapshots around one
+    pass that took three writes to three tenants: the counter's sum
+    over the count of ``mg.pack`` (an idle iteration's pack is filed
+    as ``mg.heartbeat.pack`` and adds 0).  A program without the
+    counter, as the parent is, gives nothing and does not raise."""
+    import os
+    import sys
+
+    from etcd_tpu.obs.metrics import registry
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import bench_reduce
+
+    with open(os.path.join(bench, "layer_metrics",
+                           "pack_groups_per_pass.json")) as f:
+        spec = json.load(f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = [m for m in json.load(f)["per_layer"]
+                 if m["name"] == "pack_groups_per_pass"]
+    assert len(entry) == 1 and entry[0]["source"] == "program_counter"
+    assert entry[0]["layer"] == "coalesce"
+
+    s = _mk(tmp_path)
+    names, _ = _tenants(G, 3)
+    gate = _gated(s)
+    try:
+        before = registry.snapshot(light=True)
+        chans = [_enqueue(s, 7500 + i, f"/{t}/k", "v")
+                 for i, t in enumerate(names)]
+        gate.set()
+        for ch in chans:
+            assert ch.get(timeout=90).err is None
+        after = registry.snapshot(light=True)
+    finally:
+        gate.set()
+        s.stop()
+    ctx = {"registry": {"window": (before, after)}}
+    assert bench_reduce.read_metric(spec, ctx) == 3.0
+    bare = {k: v for k, v in after.items()
+            if k != "etcd_pack_groups_visited"}
+    assert bench_reduce.read_metric(
+        spec, {"registry": {"window": (bare, bare)}}) is None
+    assert bench_reduce.read_metric(spec, {"registry": {}}) is None

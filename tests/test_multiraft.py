@@ -6,7 +6,10 @@ election matrix (raft_test.go:27-240) — G groups live through
 elections, replication, leader loss, and divergent-log repair at once.
 """
 
+import functools
+
 import numpy as np
+import pytest
 
 from etcd_tpu.raft.batched import LEADER, term_at
 from etcd_tpu.raft.multiraft import MultiRaft
@@ -509,3 +512,61 @@ def test_propose_rounds_matches_serial():
     c.campaign(0)
     c.propose_rounds(one, 40)
     assert np.array_equal(a.errors["overflow"], c.errors["overflow"])
+
+
+# -- propose(data=...): a list of G lists or a mapping of touched groups -----
+
+_PAYLOAD_N_NEW = np.array([2, 0, 1, 0, 1, 0], np.int32)
+_PAYLOAD_ROWS = {0: [b"a0", b"a1"], 2: [b"c0"], 4: [b"stale"]}
+_PAYLOAD_FORMS = {
+    "list_of_g_lists": lambda: [list(_PAYLOAD_ROWS.get(gi, []))
+                                for gi in range(6)],
+    "mapping_of_touched_groups": lambda: dict(_PAYLOAD_ROWS),
+    # a row longer than n_new is cut to n_new in either form
+    "list_with_spare_blobs": lambda: [
+        list(_PAYLOAD_ROWS.get(gi, [])) + [b"spare"] for gi in range(6)],
+}
+
+
+def _propose_with_deposed_group(data):
+    """Six groups led by member 0, group 4's leader deposed without
+    ``mr.leader`` learning of it; one propose() of ``_PAYLOAD_N_NEW``
+    with ``data``.  Returns everything the caller may key on."""
+    import jax.numpy as jnp
+    from etcd_tpu.raft.batched import FOLLOWER
+    mr = MultiRaft(g=6, m=3, cap=32)
+    mr.campaign(0)
+    st = mr.states[0]
+    mr.states[0] = st._replace(
+        role=jnp.asarray(st.role).at[4].set(FOLLOWER))
+    newly = mr.propose(_PAYLOAD_N_NEW, data=data)
+    return (mr.payloads, np.asarray(mr.last_valid),
+            np.asarray(mr.last_base), np.asarray(newly))
+
+
+@functools.cache
+def _list_form_reference():
+    return _propose_with_deposed_group(
+        _PAYLOAD_FORMS["list_of_g_lists"]())
+
+
+@pytest.mark.parametrize("form", sorted(_PAYLOAD_FORMS))
+def test_propose_records_payloads_of_touched_groups(form):
+    """``data`` is indexed by group and only where ``n_new > 0`` and
+    the addressed member is leader: a mapping that holds the touched
+    groups alone and a list of G lists give the same bookkeeping."""
+    payloads, valid, base, newly = _propose_with_deposed_group(
+        _PAYLOAD_FORMS[form]())
+    np.testing.assert_array_equal(
+        valid, [True, True, True, True, False, True])
+    # the becoming-leader entry is index 1, so proposals start at 2
+    assert payloads[0] == {2: b"a0", 3: b"a1"}
+    assert payloads[2] == {2: b"c0"}
+    # n_new > 0 but not valid: nothing recorded; untouched: nothing
+    assert all(payloads[gi] == {} for gi in (1, 3, 4, 5))
+    np.testing.assert_array_equal(base[valid], 1)
+    np.testing.assert_array_equal(newly, [2, 0, 1, 0, 0, 0])
+    ref = _list_form_reference()
+    assert payloads == ref[0]
+    for got, want in zip((valid, base, newly), ref[1:]):
+        np.testing.assert_array_equal(got, want)
