@@ -502,14 +502,16 @@ def _mesh_placement(count: int) -> dict:
 def leg_dist(workdir: str, *, seed: int, expect_platform: str,
              g: int, puts: int, member_devices: bool = False,
              election_ticks: int = 60) -> dict:
-    """Three DistServers in this process, built with the arguments
-    ``cli.start_dist`` passes, each behind its own HTTP front door."""
+    """Three DistServers in this process, built and started by the
+    functions ``cli.start_dist`` builds and starts its
+    ``--dist-local-cluster`` with, each behind its own HTTP front
+    door."""
     import logging
 
     import numpy as np
 
+    from etcd_tpu import cli
     from etcd_tpu.server import DEFAULT_SNAP_COUNT
-    from etcd_tpu.server.distserver import DistServer
     from etcd_tpu.server.frontdoor import serve_frontdoor
     from etcd_tpu.wal.backend_policy import get_policy
 
@@ -517,10 +519,6 @@ def leg_dist(workdir: str, *, seed: int, expect_platform: str,
         level=logging.INFO, stream=sys.stderr,
         format="%(asctime)s %(name)s: %(message)s")
     device = _device_in_child(expect_platform)
-    m = 3
-    ports = free_ports(2 * m)
-    peers = [f"http://127.0.0.1:{p}" for p in ports[:m]]
-    urls = [f"http://127.0.0.1:{p}" for p in ports[m:]]
     kv = make_kv(seed, puts, puts)
 
     def mesh_for(slot: int):
@@ -532,18 +530,11 @@ def leg_dist(workdir: str, *, seed: int, expect_platform: str,
         return Mesh(np.asarray([jax.devices()[slot]]), ("g",))
 
     def build() -> list:
-        servers = []
-        for slot in range(m):
-            s = DistServer(
-                os.path.join(workdir, f"d{slot}"), slot=slot,
-                peer_urls=peers, g=g, name=f"smoke-{slot}",
-                snap_count=DEFAULT_SNAP_COUNT,
-                election=election_ticks, storage_backend="tpu",
-                client_urls=[urls[slot]], mesh=mesh_for(slot),
-                peer_tls=None, pipeline_depth=8, coalesce_us=2000,
-                lease_ticks=30)
-            servers.append(s)
-        return servers
+        return cli.local_dist_members(
+            workdir, 3, name="smoke", mesh_of=mesh_for, g=g,
+            snap_count=DEFAULT_SNAP_COUNT, election=election_ticks,
+            storage_backend="tpu", peer_tls=None, pipeline_depth=8,
+            coalesce_us=2000, lease_ticks=30)
 
     def check_policy() -> dict:
         snap = get_policy().snapshot()
@@ -563,14 +554,12 @@ def leg_dist(workdir: str, *, seed: int, expect_platform: str,
     def wait_led(servers, timeout: float = 180.0) -> float:
         t0 = time.monotonic()
         while time.monotonic() - t0 < timeout:
-            led = np.zeros(g, bool)
-            for s in servers:
-                led |= np.asarray(s.mr.is_leader())
-            if led.all():
+            led = cli.dist_groups_led(servers)
+            if led == g:
                 return round(time.monotonic() - t0, 1)
             time.sleep(0.25)
-        raise SmokeError(f"{int((~led).sum())} of {g} groups have no "
-                         f"leader after {timeout:.0f}s")
+        raise SmokeError(f"{g - led} of {g} groups have no leader "
+                         f"after {timeout:.0f}s")
 
     def max_term(servers) -> int:
         return int(max(np.asarray(s.mr.state.term).max()
@@ -580,17 +569,16 @@ def leg_dist(workdir: str, *, seed: int, expect_platform: str,
     def serving(servers):
         """Start the members the way ``cli.start_dist`` does — slot 0
         of a brand-new cluster campaigns — each behind its own HTTP
-        front door; stop everything on the way out."""
+        front door on a port of the system's choosing; yields the
+        doors' URLs and stops everything on the way out."""
         doors = []
         try:
             for s in servers:
-                s.start()
-            if servers[0].fresh:
-                servers[0]._campaign(np.ones(g, bool))
-            for s, u in zip(servers, urls):
-                doors.append(serve_frontdoor(
-                    s, "127.0.0.1", int(u.rsplit(":", 1)[1])))
-            yield
+                doors.append(serve_frontdoor(s, "127.0.0.1", 0))
+                s.client_urls = ["http://%s:%d"
+                                 % doors[-1].server_address[:2]]
+            cli.start_dist_members(servers)
+            yield [s.client_urls[0] for s in servers]
         finally:
             for d in doors:
                 d.shutdown()
@@ -601,7 +589,7 @@ def leg_dist(workdir: str, *, seed: int, expect_platform: str,
                  "member_devices": member_devices}
     servers = build()
     check(all(s.fresh for s in servers), "data dirs are not fresh")
-    with serving(servers):
+    with serving(servers) as urls:
         out["elect_s"] = wait_led(servers)
         out["put"] = put_all(urls[0], kv, clients=4)
         out["get"] = get_all(urls, kv)
@@ -618,7 +606,7 @@ def leg_dist(workdir: str, *, seed: int, expect_platform: str,
     out["rebuild_s"] = round(time.monotonic() - t0, 1)
     check(not any(s.fresh for s in servers),
           "restart found a fresh data dir")
-    with serving(servers):
+    with serving(servers) as urls:
         out["reelect_s"] = wait_led(servers)
         out["get_after_restart"] = get_all(urls, kv)
         out["max_term_after_restart"] = max_term(servers)

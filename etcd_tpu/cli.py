@@ -10,6 +10,7 @@ import argparse
 import logging
 import os
 import sys
+import time
 import urllib.parse
 
 from . import __version__
@@ -115,6 +116,17 @@ def build_parser() -> argparse.ArgumentParser:
                    help="Comma-separated slot-indexed peer base URLs "
                         "for --dist-slot mode (this host's own slot "
                         "included)")
+    p.add_argument("--dist-local-cluster", type=int, default=0,
+                   metavar="M",
+                   help="Host ALL M member slots of a distributed "
+                        "multi-group cluster in this one process "
+                        "(three hosts cut to one chip): member i "
+                        "keeps <data-dir>/slot<i> with its own WAL "
+                        "and fsync, peers exchange the real frames "
+                        "over loopback ports the program binds, "
+                        "--listen-client-urls serves slot 0; "
+                        "exclusive with --dist-slot/--dist-peers "
+                        "(0 = off)")
     p.add_argument("--dist-mesh-devices", type=int, default=0,
                    help="Shard this host's group batch over its first "
                         "N local devices (intra-host tier composed "
@@ -233,41 +245,146 @@ def main(argv: list[str] | None = None) -> int:
     from .utils.jaxenv import configure_compile_cache
 
     configure_compile_cache()
-    if args.dist_slot >= 0:
+    if args.dist_slot >= 0 or args.dist_local_cluster:
         return start_dist(args, explicit)
     if args.cohosted_groups > 0:
         return start_multigroup(args, explicit)
     return start_etcd(args, cluster, explicit)
 
 
-def start_dist(args, explicit: set[str]) -> int:
-    """Distributed multi-group mode: this process is ONE member slot
-    of every co-hosted group; peers listed in --dist-peers carry the
-    other slots (server/distserver.py).  The standard /v2 client API
-    serves from the local replica; writes route to group leaders."""
+#: how long --dist-local-cluster waits for every group's leader
+#: before it gives up instead of serving (the v5e took 8-9 s, PR 21)
+LOCAL_LEADERS_LIMIT_S = 120.0
+
+
+def bind_loopback(n: int) -> list:
+    """``n`` sockets bound to free loopback ports, kept OPEN: whoever
+    listens there takes the socket itself (``DistServer``'s
+    ``peer_sock``), so no other process can take the port between
+    the choice and the listen."""
+    import socket
+
+    socks = []
+    for _ in range(n):
+        sock = socket.socket()
+        sock.bind(("127.0.0.1", 0))
+        socks.append(sock)
+    return socks
+
+
+def dist_member(data_dir: str, slot: int, peers: list[str], **kw):
+    """Member ``slot`` of the cluster ``peers`` names, on a data
+    directory of its own: the one call of the ``DistServer``
+    constructor that ``--dist-slot``, ``--dist-local-cluster``,
+    ``chip_smoke.py`` and the tests' clusters share."""
     from .server.distserver import DistServer
+
+    return DistServer(data_dir, slot=slot, peer_urls=peers, **kw)
+
+
+def local_dist_members(root: str, peers: list[str] | int, *,
+                       name: str | None = None, mesh_of=None,
+                       **kw) -> list:
+    """Every member slot of a cluster in THIS process, not started:
+    member i keeps ``<root>/slot<i>`` with its own WAL, snapshots and
+    fsync and listens for its peers on ``peers[i]``; nothing stands
+    in for the hosts or their network.  ``peers`` is the slot-indexed
+    URL list, or the number of members: then each gets a loopback
+    port bound here and keeps the socket.  ``name`` and ``mesh_of``
+    give each member its own; ``kw`` is every member's."""
+    socks = None
+    if isinstance(peers, int):
+        socks = bind_loopback(peers)
+        peers = ["http://%s:%d" % sock.getsockname() for sock in socks]
+    members = []
+    for i in range(len(peers)):
+        own = dict(kw)
+        if name is not None:
+            own["name"] = f"{name}-{i}"
+        if mesh_of is not None:
+            own["mesh"] = mesh_of(i)
+        if socks is not None:
+            own["peer_sock"] = socks[i]
+        members.append(dist_member(os.path.join(root, f"slot{i}"), i,
+                                   peers, **own))
+    return members
+
+
+def start_dist_members(servers: list, bootstrap: bool = True) -> None:
+    """Start each member's peer listener and its round thread.
+    Slot 0 of a BRAND-NEW cluster (fresh = no prior WAL) then
+    campaigns for every group; a restarted slot 0 rejoins through
+    ordinary elections — mass-campaigning there would depose every
+    established leader on the surviving hosts."""
+    import numpy as np
+
+    for s in servers:
+        s.start()
+    for s in servers:
+        if bootstrap and s.slot == 0 and s.fresh:
+            s._campaign(np.ones(s.g, bool))
+
+
+def dist_groups_led(servers: list) -> int:
+    """Groups that have a leader among ``servers`` which every one of
+    them knows of: a client of any of these members then meets no
+    leaderless group."""
+    import numpy as np
+
+    led = np.zeros(servers[0].g, bool)
+    for s in servers:
+        led |= np.asarray(s.mr.is_leader())
+    for s in servers:
+        led &= np.asarray(s.mr.leader_hint()) >= 0
+    return int(led.sum())
+
+
+def start_dist(args, explicit: set[str]) -> int:
+    """Distributed multi-group mode: a process is ONE member slot of
+    every co-hosted group and the peers listed in --dist-peers carry
+    the other slots (server/distserver.py), or, with
+    --dist-local-cluster M, it hosts all M slots itself.  The standard
+    /v2 client API serves from the local replica; writes route to
+    group leaders."""
+    import signal
+    import threading
+
     from .utils.jaxenv import log_devices
 
-    peers = [u.strip() for u in args.dist_peers.split(",") if u.strip()]
-    if len(peers) < 2 or not (0 <= args.dist_slot < len(peers)):
-        log.error("dist mode needs --dist-peers with >=2 slot-indexed "
-                  "URLs and --dist-slot within range")
-        return 1
-    if args.dist_election_ticks < len(peers):
+    local = args.dist_local_cluster
+    if local:
+        if args.dist_slot >= 0 or args.dist_peers or args.dist_roles:
+            log.error("--dist-local-cluster hosts every member slot "
+                      "itself: it excludes --dist-slot, --dist-peers "
+                      "and --dist-roles")
+            return 1
+        if local < 2:
+            log.error("--dist-local-cluster needs at least 2 members")
+            return 1
+        n_peers = local
+    else:
+        peers = [u.strip() for u in args.dist_peers.split(",")
+                 if u.strip()]
+        if len(peers) < 2 or not (0 <= args.dist_slot < len(peers)):
+            log.error("dist mode needs --dist-peers with >=2 "
+                      "slot-indexed URLs and --dist-slot within range")
+            return 1
+        n_peers = len(peers)
+    if args.dist_election_ticks < n_peers:
         # the distmember election>=m clamp made mechanical at the
         # config surface: refuse rather than silently stretching the
         # operator's number (timeout-bands invariant)
         log.error("--dist-election-ticks=%d is below the host count "
                   "%d: %d disjoint per-slot election bands cannot "
                   "fit in [%d, %d) — pass at least %d",
-                  args.dist_election_ticks, len(peers), len(peers),
+                  args.dist_election_ticks, n_peers, n_peers,
                   args.dist_election_ticks,
-                  2 * args.dist_election_ticks, len(peers))
+                  2 * args.dist_election_ticks, n_peers)
         return 1
     if args.dist_lease_ticks > 0:
         from .server.readindex import lease_drift_ticks
 
-        eff = max(args.dist_election_ticks, len(peers))
+        eff = max(args.dist_election_ticks, n_peers)
         if args.dist_lease_ticks >= eff - lease_drift_ticks(eff):
             # the lease-band invariant made loud at the config
             # surface (the DistServer constructor re-raises the same
@@ -280,7 +397,9 @@ def start_dist(args, explicit: set[str]) -> int:
                       args.dist_lease_ticks, eff,
                       lease_drift_ticks(eff))
             return 1
-    data_dir = args.data_dir or f"{args.name}_dist{args.dist_slot}_data"
+    data_dir = args.data_dir or (
+        f"{args.name}_dist_local_data" if local
+        else f"{args.name}_dist{args.dist_slot}_data")
     os.makedirs(data_dir, mode=0o700, exist_ok=True)
     g = args.cohosted_groups or 64
     if args.dist_roles:
@@ -288,64 +407,110 @@ def start_dist(args, explicit: set[str]) -> int:
     client_tls = TLSInfo(args.cert_file, args.key_file, args.ca_file)
     acurls = urls_from_flags(args, "advertise_client_urls", "addr",
                              explicit, client_tls.empty())
+    lcurls = urls_from_flags(args, "listen_client_urls", "bind_addr",
+                             explicit, client_tls.empty())
     log_devices()
-    # member identity folds the slot in: hosts commonly share a
-    # --name (the default!), and identical names would collapse to
-    # one sha1 id whose registry entries overwrite each other
     try:
+        # with --dist-local-cluster every member shares this mesh
+        # (or, without one, the default device)
         mesh = _local_mesh(args.dist_mesh_devices, g)
     except ValueError as e:
         log.error("--dist-mesh-devices: %s", e)
         return 1
     peer_tls = TLSInfo(args.peer_cert_file, args.peer_key_file,
                        args.peer_ca_file)
+    kw = dict(g=g, snap_count=args.snapshot_count,
+              election=args.dist_election_ticks,
+              storage_backend=args.storage_backend,
+              peer_tls=peer_tls if not peer_tls.empty() else None,
+              pipeline_depth=args.dist_pipeline_depth,
+              coalesce_us=args.dist_coalesce_us,
+              lease_ticks=args.dist_lease_ticks)
+    # member identity folds the slot in: hosts commonly share a
+    # --name (the default!), and identical names would collapse to
+    # one sha1 id whose registry entries overwrite each other
     try:
         # peer-TLS/https scheme agreement is validated by the
         # DistServer constructor (the single copy of that rule)
-        s = DistServer(data_dir, slot=args.dist_slot, peer_urls=peers,
-                       g=g, name=f"{args.name}-{args.dist_slot}",
-                       snap_count=args.snapshot_count,
-                       election=args.dist_election_ticks,
-                       storage_backend=args.storage_backend,
-                       client_urls=list(acurls), mesh=mesh,
-                       peer_tls=peer_tls if not peer_tls.empty()
-                       else None,
-                       pipeline_depth=args.dist_pipeline_depth,
-                       coalesce_us=args.dist_coalesce_us,
-                       lease_ticks=args.dist_lease_ticks)
+        if local:
+            # no port in a configuration file: each member's peer
+            # listener gets a free loopback port, bound here and kept
+            servers = local_dist_members(
+                data_dir, local, name=args.name,
+                mesh_of=lambda slot: mesh, **kw)
+        else:
+            servers = [dist_member(
+                data_dir, args.dist_slot, peers,
+                name=f"{args.name}-{args.dist_slot}", mesh=mesh, **kw)]
     except ValueError as e:
         log.error("%s", e)
         return 1
-    s.start()
-    # flight-recorder crash dump (PR 8): SIGTERM or an unhandled
-    # crash writes the black-box ring next to the data dir (or
-    # ETCD_FLIGHT_DIR) — what the chaos drill's post-mortem reads
-    # when a node died before its ring could be harvested over HTTP
+    # --listen-client-urls is the first member's (slot 0 of a local
+    # cluster, the slot of the one-slot form)
+    servers[0].client_urls = list(acurls)
+    cors = parse_cors(args.cors) if args.cors else None
+    doors = []
+
+    def serve_clients(s, u: str) -> str:
+        host, port = _split_hostport(u)
+        doors.append(_serve_client(args, s, cors, host, port,
+                                   new_listener_context(client_tls)))
+        return "%s://%s:%d" % (urllib.parse.urlsplit(u).scheme,
+                               *doors[-1].server_address[:2])
+
+    # the other members of a local cluster serve clients on loopback
+    # ports of the system's choosing, known (and published under
+    # /_etcd/machines) before the member starts
+    scheme = "http" if client_tls.empty() else "https"
+    for s in servers[1:]:
+        s.client_urls = [serve_clients(s, f"{scheme}://127.0.0.1:0")]
+        log.info("dist slot %d/%d serves clients on %s", s.slot,
+                 n_peers, s.client_urls[0])
+    # one way down, whatever the number of members: SIGTERM wakes the
+    # main thread, which dumps each member's black-box ring next to
+    # its data (or ETCD_FLIGHT_DIR) — what the chaos drill's
+    # post-mortem reads — and stops each member.  An unhandled crash
+    # dumps through install_crash_dump's hooks (PR 8).
+    stop = threading.Event()
+    signal.signal(signal.SIGTERM, lambda *_: stop.set())
+    start_dist_members(servers)
     from .obs.flight import install_crash_dump
 
-    install_crash_dump(s.flight,
-                       os.environ.get("ETCD_FLIGHT_DIR")
-                       or os.path.join(data_dir, "trace_artifacts"))
-    if args.dist_slot == 0 and s.fresh:
-        # slot 0 bootstraps leadership for a BRAND-NEW cluster only
-        # (fresh = no prior WAL); a restarted slot 0 must rejoin via
-        # ordinary elections — mass-campaigning here would depose
-        # every established leader on the surviving hosts
-        import numpy as np
+    def flight_dir(s) -> str:
+        return (os.environ.get("ETCD_FLIGHT_DIR")
+                or os.path.join(s.data_dir, "trace_artifacts"))
 
-        s._campaign(np.ones(g, bool))
-    cors = parse_cors(args.cors) if args.cors else None
-    lcurls = urls_from_flags(args, "listen_client_urls", "bind_addr",
-                             explicit, client_tls.empty())
-    for u in lcurls:
-        host, port = _split_hostport(u)
-        _serve_client(args, s, cors, host, port,
-                      new_listener_context(client_tls))
+    for s in servers:
+        install_crash_dump(s.flight, flight_dir(s), signals=())
+    leaderless = 0
+    if local:
+        # every group has a leader before the line that says the
+        # cluster serves: no client meets a leaderless group.  (One
+        # slot of several hosts cannot see the others' leadership and
+        # listens at once, as before.)
+        deadline = time.monotonic() + LOCAL_LEADERS_LIMIT_S
+        while (leaderless := g - dist_groups_led(servers)) \
+                and time.monotonic() < deadline \
+                and not stop.wait(0.05):
+            pass
+        log.info("dist local cluster: slot 0 leads %d of %d groups",
+                 int(servers[0].mr.is_leader().sum()), g)
+    if leaderless and not stop.is_set():
+        log.error("dist local cluster: %d of %d groups have no leader "
+                  "that every member knows of %.0f s after the start; "
+                  "not serving", leaderless, g, LOCAL_LEADERS_LIMIT_S)
+        stop.set()
+    for u in () if stop.is_set() else lcurls:
+        serve_clients(servers[0], u)
         log.info("Listening for client requests on %s (dist slot "
-                 "%d/%d, %d groups)", u, args.dist_slot, len(peers), g)
-
-    _block_forever()
-    return 0
+                 "%d/%d, %d groups)", u, servers[0].slot, n_peers, g)
+    stop.wait()
+    for d in doors:
+        d.shutdown()
+    for s in servers:
+        s.flight.dump_to(flight_dir(s), tag="sigterm")
+    clean = all([s.stop() for s in servers])
+    return 0 if clean and not leaderless else 1
 
 
 def _start_dist_roles(args, explicit: set[str], peers: list[str],

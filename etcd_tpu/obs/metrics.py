@@ -143,6 +143,13 @@ _DEFS = (
         "--dist-coalesce-us timer, whichever first).",
         buckets=SIZE_BUCKETS),
     MetricDef(
+        "etcd_dist_proposed_entries", "histogram",
+        "Entries one dist leader round proposed (sum) over the "
+        "rounds that proposed any (count).  The leader's own: "
+        "etcd_apply_batch_entries is fed by every member's apply, "
+        "so the members of a --dist-local-cluster count an entry "
+        "there once each.", buckets=SIZE_BUCKETS),
+    MetricDef(
         "etcd_dist_frame_resend_total", "counter",
         "Pipeline frames re-sent or acks dropped, by reason: "
         "reconnect (transport died with frames in flight), reject "

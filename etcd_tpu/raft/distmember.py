@@ -175,6 +175,29 @@ def _build_append_fused(state: GroupState, lane_mask, peer, e):
         terms2], axis=1)
 
 
+@partial(jax.jit, static_argnames=("slot", "keep"))
+def _compact_cut(state: GroupState, slot, keep):
+    """[G] index ``compact`` cuts at.  A follower lane cuts at its
+    applied index.  A LEADER lane keeps the tail its slowest other
+    member has not confirmed (``match``): commit needs only a quorum,
+    so under steady load one follower is a frame behind on some lane
+    at any moment, and a cut at ``applied`` puts its next entry
+    behind the offset — ``need_snap``, a whole-store snapshot pull
+    for a lag of a few entries (the reference keeps its log for the
+    same reason, server.go's snapshot leaves the catch-up entries).
+    At most ``keep`` entries are kept, so a dead member cannot fill
+    the window: past that it installs a snapshot, as before."""
+    others = state.members & (
+        jnp.arange(state.match.shape[1]) != slot)[None, :]
+    slowest = jnp.min(jnp.where(others, state.match,
+                                jnp.iinfo(jnp.int32).max), axis=1)
+    cut = jnp.where(state.role == LEADER,
+                    jnp.minimum(state.applied, slowest),
+                    state.applied)
+    return jnp.maximum(jnp.maximum(cut, state.applied - keep),
+                       state.offset)
+
+
 @partial(jax.jit, static_argnames=("slot",))
 def _begin_campaign(state: GroupState, mask, slot):
     """term+1, vote self, CANDIDATE (raft.go:358-362 batched)."""
@@ -662,9 +685,12 @@ class DistMember:
             st.applied, jnp.minimum(upto, st.commit)))
 
     def compact(self) -> None:
+        """Slide every lane's window up to its applied index; a lane
+        this slot leads stops at the slowest member's confirmed index
+        (see ``_compact_cut``)."""
         st = self.state
-        st, _err = compact_batch(st, jnp.maximum(st.applied,
-                                                 st.offset))
+        st, _err = compact_batch(
+            st, _compact_cut(st, slot=self.slot, keep=self.cap // 2))
         self.state = st
         cut = np.asarray(st.offset)
         for gi in range(self.g):

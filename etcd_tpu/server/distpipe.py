@@ -47,7 +47,7 @@ class FrameMeta:
     """One in-flight append frame's accounting record."""
 
     __slots__ = ("seq", "epoch", "t0", "nbytes", "has_ents", "stripe",
-                 "traced", "n_ents")
+                 "traced", "n_ents", "t_sent")
 
     def __init__(self, seq: int, epoch: int, t0: float, nbytes: int,
                  has_ents: bool, stripe: int, n_ents: int = 0):
@@ -65,6 +65,9 @@ class FrameMeta:
         # matched ack is a flight-recorder frame event (the
         # send/ack half of the stitcher's clock-alignment pairs)
         self.traced = False
+        # when the frame's bytes hit the socket (0.0 = not yet): t0
+        # is the registration, and the channel's queue lies between
+        self.t_sent = 0.0
 
 
 class _PeerPipe:
@@ -113,6 +116,14 @@ class AppendPipeline:
         pp.inflight[seq] = meta
         pp.last_send[stripe] = t0
         return meta
+
+    def mark_sent(self, peer: int, seq: int, now: float) -> None:
+        """The channel writer's stamp; the one call made without the
+        owner's lock (a dict read and one attribute write: a frame
+        that was acked or failed meanwhile is simply not there)."""
+        meta = self._peers[peer].inflight.get(seq)
+        if meta is not None:
+            meta.t_sent = now
 
     def last_send(self, peer: int, stripe: int = 0) -> float:
         return self._peers[peer].last_send.get(stripe, 0.0)

@@ -30,6 +30,7 @@ POST /mraft/propose); reads serve from any host's store replica.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import logging
@@ -130,11 +131,16 @@ class FrameDropped(Exception):
 
 
 class _Pending:
-    __slots__ = ("req", "data", "id", "retries", "group", "trace")
+    __slots__ = ("req", "data", "id", "retries", "group", "trace",
+                 "t_put", "t_pop")
 
     def __init__(self, req, data, id, group=None, trace=None):
         self.req, self.data, self.id = req, data, id
         self.retries = 0
+        # the request's waits (dist.queue_wait, dist.commit_wait):
+        # put into _queue now, popped by _drain at t_pop
+        self.t_put = time.perf_counter()
+        self.t_pop = 0.0
         # explicit group routing (ConfChange entries target a group
         # directly instead of hashing a client path)
         self.group = group
@@ -167,7 +173,8 @@ class DistServer:
                  coalesce_ents: int = 512,
                  coalesce_bytes: int = 1 << 20,
                  snap_keep: int | None = None,
-                 lease_ticks: int | None = None):
+                 lease_ticks: int | None = None,
+                 peer_sock=None):
         self.slot = slot
         self.g, self.m = g, len(peer_urls)
         # live member slots (< m leaves spare slots for runtime
@@ -236,7 +243,12 @@ class DistServer:
         self.server_stats = ServerStats(self.name, self.id)
         self.leader_stats = LeaderStats(self.id)
         self.cluster_store = ClusterStore(self.store)
-        self._client_urls = client_urls or []
+        # what /_etcd/machines advertises; the caller may set it up to
+        # start() (a listener bound at port 0 knows its URL late)
+        self.client_urls = client_urls or []
+        # the peer listener's socket where the caller bound it
+        # already (cli.bind_loopback), else start() binds the URL's
+        self._peer_sock = peer_sock
         self._queue: queue.Queue[_Pending | None] = queue.Queue()
         self._slot_ids: dict[int, int] = {}  # slot -> member id cache
         self._requeue: list[deque] = [deque() for _ in range(g)]
@@ -334,6 +346,9 @@ class DistServer:
             (np.arange(g) % self._n_stripes) == s
             for s in range(self._n_stripes)]
         self._channels: dict[int, PipeChannel] = {}
+        # per peer, the stripe that goes first at the next pump: the
+        # one after the stripe that last sent entries (_pump_peer)
+        self._stripe_turn = {p: 0 for p in range(self.m) if p != slot}
         # per-peer [G] commit vector last shipped (empty-frame dedup:
         # heartbeats go out on commit movement or cadence, not every
         # loop iteration)
@@ -357,6 +372,7 @@ class DistServer:
         self._fr_last: tuple[np.ndarray, np.ndarray] | None = None
 
         os.makedirs(data_dir, mode=0o700, exist_ok=True)
+        self.data_dir = data_dir
         self._snapdir = os.path.join(data_dir, "snap")
         os.makedirs(self._snapdir, mode=0o700, exist_ok=True)
         self._waldir = os.path.join(data_dir, "wal")
@@ -425,6 +441,12 @@ class DistServer:
             "etcd_pending_proposals")
         self._m_coalesce = _obs.registry.histogram(
             "etcd_dist_coalesce_entries")
+        # entries a leader round proposed (sum) over the rounds that
+        # proposed (count): etcd_apply_batch_entries is fed by every
+        # member's apply, so with the members of a local cluster in
+        # one registry it counts an entry up to m times
+        self._m_proposed = _obs.registry.histogram(
+            "etcd_dist_proposed_entries")
         # per-peer in-flight gauges, cached like every other hot-path
         # handle (the labeled registry lookup costs a lock + key
         # build per call, and _set_inflight runs per ack/pump)
@@ -749,7 +771,13 @@ class DistServer:
         threading.Thread(target=self._publish, daemon=True).start()
         u = urlparse(self.peer_urls[self.slot])
         handler = _make_peer_handler(self)
-        self._httpd = _PeerHTTPServer((u.hostname, u.port), handler)
+        self._httpd = _PeerHTTPServer((u.hostname, u.port), handler,
+                                      bind_and_activate=self._peer_sock
+                                      is None)
+        if self._peer_sock is not None:
+            self._httpd.socket.close()
+            self._httpd.socket = self._peer_sock
+            self._httpd.server_activate()
         self._httpd.daemon_threads = True
         if self._peer_ssl_srv is not None:
             # handshake deferred to the per-connection worker thread
@@ -778,7 +806,7 @@ class DistServer:
 
         m = Member(id=self.id, name=self.name,
                    peer_urls=[self.peer_urls[self.slot]],
-                   client_urls=self._client_urls)
+                   client_urls=self.client_urls)
         pairs = [
             (m.store_key() + RAFT_ATTRIBUTES_SUFFIX,
              json.dumps(m.raft_attributes.to_dict())),
@@ -1897,108 +1925,117 @@ class DistServer:
                 max(next_tick - time.monotonic(), 0.001)))
             if self.done.is_set():
                 break
-            now = time.monotonic()
-            if now >= next_sync:
-                # TTL expiry must be REPLICATED, not leader-local: a
-                # follower's replica would otherwise keep expired
-                # keys forever.  The reference's leader SYNC proposal
-                # (server.go:438-456) rides group 0's log here; every
-                # host expires at that entry's apply.  (Cross-group
-                # apply order is not globally serialized, so expiry
-                # interleaving vs OTHER groups' writes can differ per
-                # host by up to one sync interval — the co-hosted
-                # server documents the same class of divergence.)
-                if self.mr.is_leader()[0] and not self._nospace:
-                    r = Request(method="SYNC", id=gen_id(),
-                                time=int(time.time() * 1e9))
-                    self._queue.put(_Pending(req=r, data=r.marshal(),
-                                             id=r.id, group=0))
-                next_sync = now + self.sync_interval
-            if now >= next_tick:
-                # WALL-CLOCK ticking: when a loop iteration overran
-                # (CPU contention, a slow exchange), credit every
-                # missed tick instead of silently dropping it — a
-                # counted-ticks timer stretches the 1-2s election
-                # timeout to tens of seconds under load (observed as
-                # 15s leaderless windows in the batch chaos drill).
-                # The reference's timers are real-time (server.go:182
-                # time.Ticker).  Burst bounded: past 4x the worst-case
-                # timeout nothing new can fire.
-                behind = min(int((now - next_tick)
-                                 / self.tick_interval) + 1,
-                             8 * self.mr.election)
-                next_tick += behind * self.tick_interval
-                if next_tick < now:  # deep pause: resync the phase
-                    next_tick = now + self.tick_interval
+            # one iteration of the round thread past its drain, of a
+            # member that led a lane at its last round (a follower's
+            # is no stage).  It is dist.pass where the leader round
+            # proposed entries (to the end of that round's apply),
+            # else filed as dist.heartbeat: the idle iteration
+            # (heartbeat and commit frames, frontier, apply)
+            with self._leader_stage("dist.pass") as it:
+                now = time.monotonic()
+                if now >= next_sync:
+                    # TTL expiry must be REPLICATED, not leader-local: a
+                    # follower's replica would otherwise keep expired
+                    # keys forever.  The reference's leader SYNC proposal
+                    # (server.go:438-456) rides group 0's log here; every
+                    # host expires at that entry's apply.  (Cross-group
+                    # apply order is not globally serialized, so expiry
+                    # interleaving vs OTHER groups' writes can differ per
+                    # host by up to one sync interval — the co-hosted
+                    # server documents the same class of divergence.)
+                    if self.mr.is_leader()[0] and not self._nospace:
+                        r = Request(method="SYNC", id=gen_id(),
+                                    time=int(time.time() * 1e9))
+                        self._queue.put(_Pending(req=r, data=r.marshal(),
+                                                 id=r.id, group=0))
+                    next_sync = now + self.sync_interval
+                if now >= next_tick:
+                    # WALL-CLOCK ticking: when a loop iteration overran
+                    # (CPU contention, a slow exchange), credit every
+                    # missed tick instead of silently dropping it — a
+                    # counted-ticks timer stretches the 1-2s election
+                    # timeout to tens of seconds under load (observed as
+                    # 15s leaderless windows in the batch chaos drill).
+                    # The reference's timers are real-time (server.go:182
+                    # time.Ticker).  Burst bounded: past 4x the worst-case
+                    # timeout nothing new can fire.
+                    behind = min(int((now - next_tick)
+                                     / self.tick_interval) + 1,
+                                 8 * self.mr.election)
+                    next_tick += behind * self.tick_interval
+                    if next_tick < now:  # deep pause: resync the phase
+                        next_tick = now + self.tick_interval
+                    with self.lock:
+                        fire = self.mr.tick()
+                        for _ in range(behind - 1):
+                            fire = fire | self.mr.tick()
+                        # a follower hearing appends has elapsed reset;
+                        # lanes that fire lost their leader
+                    if fire.any():
+                        self._campaign(fire)
+                if self._nospace \
+                        and time.monotonic() >= self._nospace_probe_t:
+                    self._nospace_recover()
                 with self.lock:
-                    fire = self.mr.tick()
-                    for _ in range(behind - 1):
-                        fire = fire | self.mr.tick()
-                    # a follower hearing appends has elapsed reset;
-                    # lanes that fire lost their leader
-                if fire.any():
-                    self._campaign(fire)
-            if self._nospace \
-                    and time.monotonic() >= self._nospace_probe_t:
-                self._nospace_recover()
-            with self.lock:
-                # handle_frame sets the flag under the lock; an
-                # unlocked test-and-clear here could lose a pull
-                # request that lands between the read and the write.
-                # The backoff gate (_arm_pull_retry) spaces attempts
-                # after failures — the flag itself is NEVER dropped
-                # on failure, only deferred.
-                need_pull = (self._need_pull
-                             and time.monotonic()
-                             >= self._pull_not_before
-                             and (self._pull_thread is None
-                                  or not self._pull_thread.is_alive()))
+                    # handle_frame sets the flag under the lock; an
+                    # unlocked test-and-clear here could lose a pull
+                    # request that lands between the read and the write.
+                    # The backoff gate (_arm_pull_retry) spaces attempts
+                    # after failures — the flag itself is NEVER dropped
+                    # on failure, only deferred.
+                    need_pull = (self._need_pull
+                                 and time.monotonic()
+                                 >= self._pull_not_before
+                                 and (self._pull_thread is None
+                                      or not self._pull_thread.is_alive()))
+                    if need_pull:
+                        self._need_pull = False
                 if need_pull:
-                    self._need_pull = False
-            if need_pull:
-                # off the round loop (same rule as the deferred
-                # snapshot below): the meta fetch + chunk stream of a
-                # big store block for minutes, and this thread is the
-                # tick/heartbeat source — an inline pull would cost
-                # leadership of every lane this host still leads
-                self._pull_thread = threading.Thread(
-                    target=self._pull_snapshot_bg,
-                    name=f"dist{self.slot}-pull", daemon=True)
-                self._pull_thread.start()
-            self._leader_round(batch)
-            # follower wait-point expiry lives HERE, not in
-            # _leader_round: a pure follower's round returns early
-            # there, yet IT is the host that parks wait-points.
-            # Coarse cadence — the sweep is an O(pending) scan.
-            if self._waits.pending \
-                    and time.monotonic() >= self._wait_expire_at:
-                self._wait_expire_at = time.monotonic() + 10.0
+                    # off the round loop (same rule as the deferred
+                    # snapshot below): the meta fetch + chunk stream of a
+                    # big store block for minutes, and this thread is the
+                    # tick/heartbeat source — an inline pull would cost
+                    # leadership of every lane this host still leads
+                    self._pull_thread = threading.Thread(
+                        target=self._pull_snapshot_bg,
+                        name=f"dist{self.slot}-pull", daemon=True)
+                    self._pull_thread.start()
+                proposed = self._leader_round(batch)
+                # follower wait-point expiry lives HERE, not in
+                # _leader_round: a pure follower's round returns early
+                # there, yet IT is the host that parks wait-points.
+                # Coarse cadence — the sweep is an O(pending) scan.
+                if self._waits.pending \
+                        and time.monotonic() >= self._wait_expire_at:
+                    self._wait_expire_at = time.monotonic() + 10.0
+                    with self.lock:
+                        expired_waits = self._waits.expire(
+                            time.monotonic(),
+                            max(35.0, 8.0 * self.post_timeout))
+                    for ch in expired_waits:
+                        ch.close(_EXPIRED)
                 with self.lock:
-                    expired_waits = self._waits.expire(
-                        time.monotonic(),
-                        max(35.0, 8.0 * self.post_timeout))
-                for ch in expired_waits:
-                    ch.close(_EXPIRED)
-            with self.lock:
-                # apply paths raise the flag under the lock; clear it
-                # under the lock too so a set landing between the read
-                # and the write can't be lost.  While a deferred
-                # snapshot is still running the flag stays SET (the
-                # in-flight save captured an older seq; the trigger
-                # re-fires once it finishes).
-                want_snap = (self._want_snap
-                             and (self._snap_thread is None
-                                  or not self._snap_thread.is_alive()))
+                    # apply paths raise the flag under the lock; clear it
+                    # under the lock too so a set landing between the read
+                    # and the write can't be lost.  While a deferred
+                    # snapshot is still running the flag stays SET (the
+                    # in-flight save captured an older seq; the trigger
+                    # re-fires once it finishes).
+                    want_snap = (self._want_snap
+                                 and (self._snap_thread is None
+                                      or not self._snap_thread.is_alive()))
+                    if want_snap:
+                        self._want_snap = False
                 if want_snap:
-                    self._want_snap = False
-            if want_snap:
-                # off the round loop: save_snap's write+fsync of a
-                # big store would stall ticks/heartbeats here long
-                # enough to lose leadership on every big snapshot
-                self._snap_thread = threading.Thread(
-                    target=self._snapshot_bg,
-                    name=f"dist{self.slot}-snap", daemon=True)
-                self._snap_thread.start()
+                    # off the round loop: save_snap's write+fsync of a
+                    # big store would stall ticks/heartbeats here long
+                    # enough to lose leadership on every big snapshot
+                    self._snap_thread = threading.Thread(
+                        target=self._snapshot_bg,
+                        name=f"dist{self.slot}-snap", daemon=True)
+                    self._snap_thread.start()
+                if it is not None and not proposed:
+                    it.name = "dist.heartbeat"
 
         for p in batch:
             self.w.trigger(p.id, None)
@@ -2032,35 +2069,49 @@ class DistServer:
         boundary is gone; a lone write flushes in ~coalesce_us, a
         burst flushes as soon as it fills a batch)."""
         out: list[_Pending] = []
-        try:
-            p = self._queue.get(timeout=timeout)
-        except queue.Empty:
-            return out
-        if p is None:
-            return out
-        out.append(p)
-        nbytes = len(p.data)
-        deadline = time.monotonic() + self.coalesce_us * 1e-6
-        while (len(out) < self.coalesce_ents
-               and nbytes < self.coalesce_bytes):
-            left = deadline - time.monotonic()
-            if left <= 0:
-                break
+        # a member that leads no lane drains nothing, and its wait
+        # is not recorded: dist.drain_wait is the leader's
+        with self._leader_stage("dist.drain_wait", cpu=False):
             try:
-                p = self._queue.get(timeout=left)
+                p = self._queue.get(timeout=timeout)
             except queue.Empty:
-                break
+                return out
             if p is None:
-                break
+                return out
             out.append(p)
-            nbytes += len(p.data)
+            nbytes = len(p.data)
+            deadline = time.monotonic() + self.coalesce_us * 1e-6
+            while (len(out) < self.coalesce_ents
+                   and nbytes < self.coalesce_bytes):
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    break
+                try:
+                    p = self._queue.get(timeout=left)
+                except queue.Empty:
+                    break
+                if p is None:
+                    break
+                out.append(p)
+                nbytes += len(p.data)
+            now = time.perf_counter()
+            for p in out:
+                p.t_pop = now
+                tracer.record_wait("dist.queue_wait", now - p.t_put)
         self._m_coalesce.observe(len(out))
         return out
 
-    def _leader_round(self, batch: list[_Pending]) -> None:
+    def _leader_stage(self, name: str, cpu: bool = True):
+        """``tracer.stage(name)`` on a member that led a lane at its
+        last round, else nothing: with the members of a local cluster
+        in one tracer, the round thread's stages are the leader's."""
+        return (tracer.stage(name, cpu) if self._prev_lead.any()
+                else contextlib.nullcontext())
+
+    def _leader_round(self, batch: list[_Pending]) -> bool:
         """One pipelined leader stage: drain → append → frames OUT →
         own fsync (overlapped with the in-flight sends) → self-ack →
-        commit/apply.
+        commit/apply.  Returns whether it proposed entries.
 
         This is the lockstep round (drain → append → persist →
         exchange → absorb → commit, server.go:247-323) decomposed:
@@ -2229,6 +2280,7 @@ class DistServer:
                         n_new, data=[[p.data for p in items[gi]]
                                      for gi in range(self.g)],
                         self_ack=False)
+                self._m_proposed.observe(int(n_new[valid].sum()))
                 for gi in range(self.g):
                     if not items[gi]:
                         continue
@@ -2260,7 +2312,7 @@ class DistServer:
                     [gi for gi in range(self.g)
                      if items[gi] and valid[gi]], base, items)
             elif not lead.any():
-                return
+                return False
 
             if new_keys:
                 # ack-RTT clock starts NOW: entries are appended and
@@ -2333,6 +2385,7 @@ class DistServer:
                     now_r, max(35.0, 8.0 * self.post_timeout)):
                 pr.ch.close(_EXPIRED)
             self._read_release(now_r)
+            return bool(new_keys)
 
     # -- the append pipeline (PR 5) ---------------------------------------
 
@@ -2392,7 +2445,12 @@ class DistServer:
         # heartbeat cadence — a full window of append frames would
         # all be doomed while its streamed install runs.
         saw_active = saw_appendable = False
-        for stripe in range(self._n_stripes):
+        # the stripes take turns to go first: a busy pipe holds thin
+        # entry frames back (below), so a fixed order would let
+        # stripe 0, which under steady load always has something to
+        # send when its ack re-pumps, starve the other's lanes
+        turn = self._stripe_turn[peer]
+        for stripe in (*range(turn, self._n_stripes), *range(turn)):
             mask = self._stripe_masks[stripe]
             while self.pipe.can_send(peer):
                 b = mr.build_append(peer, lane_mask=mask)
@@ -2468,6 +2526,9 @@ class DistServer:
                 if chan is None:
                     chan = self._channel(peer)
                 chan.send(meta.seq, payload, stripe)
+                if has_ents:
+                    self._stripe_turn[peer] = \
+                        (stripe + 1) % self._n_stripes
                 if not has_ents:
                     break
         if saw_active:
@@ -2494,6 +2555,7 @@ class DistServer:
         (send, recv, resp, ack) clock-alignment quads (stamping at
         register time would fold channel queue wait into the
         network hop).  dict.pop is GIL-atomic; no lock needed."""
+        self.pipe.mark_sent(peer, seq, time.monotonic())
         traces = self._traced_send.pop((peer, seq), None)
         if traces is not None:
             self.flight.record("frame", dir="send", peer=peer,
@@ -2594,6 +2656,11 @@ class DistServer:
             return
         rtt = t1 - meta.t0
         self._m_send_rtt.observe(rtt)
+        if meta.t_sent and meta.has_ents:
+            # socket write to the response read: the wire both ways
+            # and the follower's handle_frame, of a frame that
+            # carries entries (not a heartbeat or a commit advance)
+            tracer.record_wait("dist.peer_rtt", t1 - meta.t_sent)
         self.leader_stats.observe(self._member_id(peer), rtt)
         if meta.traced:
             # the ack edge of the clock-alignment quad (t1 was
@@ -2914,6 +2981,9 @@ class DistServer:
                 p = (assigned or {}).pop((int(gi), idx), None)
                 if p is not None:
                     self.w.trigger(p.id, resp)
+                    tracer.record_wait(
+                        "dist.commit_wait",
+                        time.perf_counter() - p.t_pop)
                 elif payload:
                     self.w.trigger(r.id, resp)
                 if tid is not None:
@@ -3035,7 +3105,13 @@ class DistServer:
                         floor = self.ss.retained_floor()
                         self.wal.gc(snap_seq if floor is None
                                     else floor)
-                self._snapi = self.raft_index
+                with self.lock:
+                    # an apply that landed while the file was written
+                    # still saw the old _snapi and raised the flag
+                    # again: without this the round loop took a
+                    # second snapshot right behind every first one
+                    self._snapi = self.raft_index
+                    self._want_snap = False
         except EtcdNoSpace as e:
             # snapshot save / WAL cut hit a full disk: the one state
             # GC could have shrunk keeps growing, so degrade to

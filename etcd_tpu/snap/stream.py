@@ -21,12 +21,17 @@ snapshot analog of PR 3's streaming replay lane:
   channel's automatic reconnect, and a donor that dropped the pin
   answers 404 → the puller aborts with :class:`StaleSourceError` so
   the caller refetches meta and restarts against a fresh pin.
-- **Verification** (:class:`ChunkVerifier`) routes like the replay
-  lane: host seedable digest when no accelerator is present, the
-  GF(2) seed-stitched device form (ops/crc_device.inject_seeds →
-  one raw-CRC matmul + compare) when there is one — chunk c seeds
-  from chunk c-1's STORED value, the same induction the streaming
-  replay chain uses, so install verifies at replay speed.
+- **Verification** (:class:`ChunkVerifier`): chunk c seeds from
+  chunk c-1's STORED value, the same induction the streaming replay
+  chain uses.  The receiver verifies with the host's seedable digest
+  (0.06 ms a 256 KiB chunk); the GF(2) seed-stitched device form
+  (ops/crc_device.inject_seeds → one raw-CRC matmul + compare) is
+  there for a caller that names it, and is not the default even on
+  an accelerator: it builds ``contribution_matrix(chunk + 4)`` in
+  Python (≈ 11 s for a 256 KiB chunk, a width of its own for the
+  tail chunk) on the interpreter the member serves with, so on the
+  v5e a follower's 1.3 MB pull had not ended after 80 s and the
+  cluster ran at 40 % meanwhile (PERF.md, PR 30).
 
 Nothing here persists partial state: the assembled blob exists only
 in memory until the caller's install commits, so a receiver crash
@@ -191,15 +196,12 @@ class SourceCache:
 
 
 class ChunkVerifier:
-    """Rolling-chain verification of received chunks, routed like the
-    PR 3 replay lane: seedable host digest without an accelerator,
-    GF(2) seed-stitched device batch with one (``route`` forces)."""
+    """Rolling-chain verification of received chunks: the seedable
+    host digest, or with ``route="device"`` the GF(2) seed-stitched
+    device batch (the module docstring says why it is not the
+    default on an accelerator either)."""
 
-    def __init__(self, route: str | None = None):
-        if route is None:
-            from ..wal.replay_device import _accelerator_absent
-
-            route = "host" if _accelerator_absent() else "device"
+    def __init__(self, route: str = "host"):
         if route not in ("host", "device"):
             raise ValueError(f"unknown verify route {route!r}")
         self.route = route

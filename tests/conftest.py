@@ -41,7 +41,7 @@ def make_dist_cluster(tmp_path, m=3, g=8, ports=None, **kw):
     (3s): first-round jit compiles and the shared-CPU test host push
     round latency past the production 0.5-1s window; the protocol is
     what's under test, not the timing margin."""
-    from etcd_tpu.server.distserver import DistServer
+    from etcd_tpu import cli
 
     ports = ports or free_ports(m)
     urls = [f"http://127.0.0.1:{p}" for p in ports]
@@ -49,12 +49,10 @@ def make_dist_cluster(tmp_path, m=3, g=8, ports=None, **kw):
     kw.setdefault("tick_interval", 0.05)
     kw.setdefault("post_timeout", 2.0)
     kw.setdefault("election", 60)
-    servers = []
-    for s in range(m):
-        srv = DistServer(str(tmp_path / f"d{s}"), slot=s,
-                         peer_urls=urls, g=g, **kw)
-        srv.start()
-        servers.append(srv)
+    # the members --dist-local-cluster hosts (tmp_path/slot<i>),
+    # started with no campaign: bootstrap_dist_leader is the tests'
+    servers = cli.local_dist_members(str(tmp_path), urls, g=g, **kw)
+    cli.start_dist_members(servers, bootstrap=False)
     return servers, ports
 
 

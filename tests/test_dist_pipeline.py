@@ -599,8 +599,10 @@ def test_pump_enters_snapshot_mode_for_behind_peer(cluster):
     leader = servers[0]
     elect(leader)
     net.auto_peers = {1}        # peer 2's transport is dead
-    for i in range(20):
-        leader._leader_round([pend(i % G, f"v{i}")])
+    # compaction keeps a lagging member half a log window (cap 64) of
+    # tail: every lane goes further than that past peer 2
+    for i in range(64 // 2 + 4):
+        leader._leader_round([pend(gi, f"v{i}") for gi in range(G)])
     for i, fr in enumerate(net.frames):
         if fr["dst"] == 2 and fr["resp"] is None:
             net.fail(i)         # the channel reports the loss

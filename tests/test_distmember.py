@@ -264,7 +264,8 @@ def test_need_snap_flag_past_compaction():
     elect(ms, 0)
     ms[0].propose(np.ones(G, np.int32),
                   data=[[b""] for _ in range(G)])
-    for i in range(6):
+    # past the tail compaction keeps for a lagging member (cap // 2)
+    for i in range(10):
         ms[0].propose(np.ones(G, np.int32),
                       data=[[bytes([i])] for _ in range(G)])
         replicate(ms, 0, drop={2})

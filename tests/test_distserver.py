@@ -111,7 +111,7 @@ def test_restart_catches_up_from_wal(cluster):
         put(servers[0], f"/k{i}", f"v{i}", timeout=15.0)
     # restart host 1 from its own WAL; replication repairs the gap
     urls = [f"http://127.0.0.1:{p}" for p in ports]
-    s1 = DistServer(str(tmp_path / "d1"), slot=1, peer_urls=urls,
+    s1 = DistServer(str(tmp_path / "slot1"), slot=1, peer_urls=urls,
                     g=G, cap=64, tick_interval=0.05,
                     post_timeout=2.0)
     # pre-restart state survived (committed prefix is in the store)
@@ -134,7 +134,7 @@ def test_snapshot_pull_past_compaction(cluster):
     servers[0].snapshot()
     # restart the laggard: appends reject -> need_snap -> pull
     urls = [f"http://127.0.0.1:{p}" for p in ports]
-    s2 = DistServer(str(tmp_path / "d2"), slot=2, peer_urls=urls,
+    s2 = DistServer(str(tmp_path / "slot2"), slot=2, peer_urls=urls,
                     g=G, cap=64, tick_interval=0.05,
                     post_timeout=2.0)
     s2.start()
@@ -376,7 +376,7 @@ def test_ballot_survives_restart_no_double_vote(tmp_path):
 
     ports = free_ports_n(3)
     urls = [f"http://127.0.0.1:{p}" for p in ports]
-    s = DistServer(str(tmp_path / "d0"), slot=0, peer_urls=urls,
+    s = DistServer(str(tmp_path / "slot0"), slot=0, peer_urls=urls,
                    g=4, cap=64, election=60)
     term5 = np.full(4, 5, np.int32)
     req_a = VoteReq(sender=1, term=term5,
@@ -390,7 +390,7 @@ def test_ballot_survives_restart_no_double_vote(tmp_path):
     # vote-response path itself
     import shutil
 
-    shutil.copytree(str(tmp_path / "d0"), str(tmp_path / "crash"))
+    shutil.copytree(str(tmp_path / "slot0"), str(tmp_path / "crash"))
     s.stop()
 
     s2 = DistServer(str(tmp_path / "crash"), slot=0, peer_urls=urls,
@@ -595,7 +595,7 @@ def test_append_with_term_change_keeps_wal_contiguous(tmp_path):
 
     g = 4
     urls = [f"http://127.0.0.1:{p}" for p in free_ports_n(2)]
-    s = DistServer(str(tmp_path / "d0"), slot=0, peer_urls=urls,
+    s = DistServer(str(tmp_path / "slot0"), slot=0, peer_urls=urls,
                    g=g, cap=64, tick_interval=0.05)
     payload = Request(method="PUT", id=9, path="/x", val="v").marshal()
     term = np.full(g, 5, np.int32)  # far above the fresh server's
@@ -615,14 +615,14 @@ def test_append_with_term_change_keeps_wal_contiguous(tmp_path):
     # the on-disk stream must be index-contiguous from 0
     from etcd_tpu.wal import WAL
 
-    w = WAL.open_at_index(str(tmp_path / "d0" / "wal"), 0)
+    w = WAL.open_at_index(str(tmp_path / "slot0" / "wal"), 0)
     _, _, ents = w.read_all()  # raises 'entry index gap' pre-fix
     w.close()
     idxs = [e.index for e in ents]
     assert idxs == list(range(len(idxs)))
 
     # and a fresh server restarts from the same dir
-    s2 = DistServer(str(tmp_path / "d0"), slot=0, peer_urls=urls,
+    s2 = DistServer(str(tmp_path / "slot0"), slot=0, peer_urls=urls,
                     g=g, cap=64, tick_interval=0.05)
     assert (s2.mr.terms() == 5).all()
     s2.wal.close()
@@ -685,7 +685,7 @@ def test_need_snap_lanes_never_persist_phantom_entries(tmp_path):
 
     g = 4
     urls = [f"http://127.0.0.1:{p}" for p in free_ports_n(2)]
-    s = DistServer(str(tmp_path / "d0"), slot=0, peer_urls=urls,
+    s = DistServer(str(tmp_path / "slot0"), slot=0, peer_urls=urls,
                    g=g, cap=64, tick_interval=0.05)
     payload = Request(method="PUT", id=9, path="/x", val="v").marshal()
     term = np.full(g, 5, np.int32)
@@ -710,7 +710,7 @@ def test_need_snap_lanes_never_persist_phantom_entries(tmp_path):
     from etcd_tpu.wal import WAL
     from etcd_tpu.wire import GroupEntry
 
-    w = WAL.open_at_index(str(tmp_path / "d0" / "wal"), 0)
+    w = WAL.open_at_index(str(tmp_path / "slot0" / "wal"), 0)
     _, _, ents = w.read_all()
     w.close()
     groups_with_entries = {
@@ -720,7 +720,7 @@ def test_need_snap_lanes_never_persist_phantom_entries(tmp_path):
     assert groups_with_entries == {0, 2}
 
     # and the directory restarts cleanly
-    s2 = DistServer(str(tmp_path / "d0"), slot=0, peer_urls=urls,
+    s2 = DistServer(str(tmp_path / "slot0"), slot=0, peer_urls=urls,
                     g=g, cap=64, tick_interval=0.05)
     assert (s2.mr.terms() == 5).all()
     s2.wal.close()
@@ -782,7 +782,7 @@ def test_pull_failure_rearms_need_pull(tmp_path):
 
     ports = free_ports_n(3)
     urls = [f"http://127.0.0.1:{p}" for p in ports]
-    srv = DistServer(str(tmp_path / "d0"), slot=0, peer_urls=urls,
+    srv = DistServer(str(tmp_path / "slot0"), slot=0, peer_urls=urls,
                      g=G, cap=64, tick_interval=0.05,
                      post_timeout=0.3)
     try:
@@ -956,8 +956,8 @@ def test_snapshot_bounds_wal_and_snap_dirs(tmp_path):
                 put(servers[0], f"/b{r}/k{i}", f"v{r}.{i}",
                     timeout=15.0)
             servers[0].snapshot()
-        waldir = str(tp / "d0" / "wal")
-        snapdir = str(tp / "d0" / "snap")
+        waldir = str(tp / "slot0" / "wal")
+        snapdir = str(tp / "slot0" / "snap")
         segs = [n for n in os.listdir(waldir) if n.endswith(".wal")]
         snaps = [n for n in os.listdir(snapdir)
                  if n.endswith(".snap")]
@@ -969,7 +969,7 @@ def test_snapshot_bounds_wal_and_snap_dirs(tmp_path):
         # and the node still restarts cleanly from what survives
         servers[0].stop()
         urls = [f"http://127.0.0.1:{p}" for p in ports]
-        s0 = DistServer(str(tp / "d0"), slot=0, peer_urls=urls,
+        s0 = DistServer(str(tp / "slot0"), slot=0, peer_urls=urls,
                         g=G, cap=64, tick_interval=0.05,
                         post_timeout=2.0)
         assert get(s0, "/b3/k5").event.node.value == "v3.5"
@@ -1000,7 +1000,7 @@ def test_crash_between_snapshot_and_gc_restarts_clean(tmp_path):
                                   index=s0.seq, term=s0.raft_term))
         servers[0].stop()
         urls = [f"http://127.0.0.1:{p}" for p in ports]
-        r0 = DistServer(str(tmp_path / "d0"), slot=0, peer_urls=urls,
+        r0 = DistServer(str(tmp_path / "slot0"), slot=0, peer_urls=urls,
                         g=G, cap=64, tick_interval=0.05,
                         post_timeout=2.0)
         for i in range(8):
@@ -1028,7 +1028,7 @@ def test_corrupt_newest_snapshot_still_restarts_after_gc(tmp_path):
                     timeout=15.0)
             servers[0].snapshot()
         servers[0].stop()
-        snapdir = str(tmp_path / "d0" / "snap")
+        snapdir = str(tmp_path / "slot0" / "snap")
         newest = sorted(n for n in os.listdir(snapdir)
                         if n.endswith(".snap"))[-1]
         fpath = os.path.join(snapdir, newest)
@@ -1039,7 +1039,7 @@ def test_corrupt_newest_snapshot_still_restarts_after_gc(tmp_path):
         # restart must fall back to an older kept snapshot AND find
         # the WAL chain covering its index — with newest-index GC
         # this constructor raised 'no wal file covers index'
-        r0 = DistServer(str(tmp_path / "d0"), slot=0, peer_urls=urls,
+        r0 = DistServer(str(tmp_path / "slot0"), slot=0, peer_urls=urls,
                         g=G, cap=64, tick_interval=0.05,
                         post_timeout=2.0)
         servers[0] = r0
