@@ -147,6 +147,49 @@ def term_at(log_term, offset, last, idx):
     return t[:, 0] if squeeze else t
 
 
+def term_window(log_term, offset, last, start, e: int):
+    """Terms of entries ``start .. start+e-1`` per group, [G, e]:
+    :func:`term_at` of ``start[:, None] + arange(e)``, bit for bit,
+    for the callers whose indices are such a run (the windows of a
+    round).  ``term_at``'s gather of ``e`` elements a group was most
+    of the fused round's device time at 10k groups x cap 1024
+    (ledger, PR 30: ten ``s32[320000]`` fusions a round), and a
+    ``dynamic_slice`` a group is a gather to the TPU too, as slow
+    (chip run, PR 32).  So the run is read with selects alone: the
+    row is cut into blocks at least ``e`` wide, the block the run
+    starts in and the next one are picked by a one-hot over the
+    blocks (one dense pass over the log), and the run is picked out
+    of the pair the same way.
+
+    A block outside the row is zeros, so a run that begins below
+    ``offset`` or runs past slot ``cap`` reads 0 there as in
+    ``term_at``; above ``last`` the mask says so.
+    """
+    g, cap = log_term.shape
+    # the narrowest blocks that tile the row and hold a run
+    w = next((w for w in range(e, cap + 1) if cap % w == 0), None)
+    if w is None:
+        # e > cap: the caller's shape is no window of this log
+        return term_at(log_term, offset, last,
+                       start[:, None] + jnp.arange(e, dtype=jnp.int32))
+    with jax.named_scope("term_window"):
+        slot0 = start - offset
+        b = slot0 // w                  # floor: "block -1" below slot 0
+        blocks = log_term.reshape(g, cap // w, w)
+        k = jnp.arange(cap // w, dtype=jnp.int32)[None, :, None]
+        pair = jnp.concatenate([
+            jnp.sum(jnp.where(k == (b + i)[:, None, None], blocks, 0),
+                    axis=1) for i in (0, 1)], axis=1)
+        # pair[:, c] holds slot b * w + c; entry j of the run wants
+        # slot slot0 + j
+        j = jnp.arange(e, dtype=jnp.int32)
+        c = jnp.arange(2 * w, dtype=jnp.int32)
+        want = (slot0 - b * w)[:, None] + j
+        t = jnp.sum(jnp.where(c[None, None, :] == want[:, :, None],
+                              pair[:, None, :], 0), axis=2)
+        return jnp.where(start[:, None] + j <= last[:, None], t, 0)
+
+
 def match_term(log_term, offset, last, idx, term):
     """Batched ``RaftLog.match_term`` — NB a term-0 entry at a valid
     index cannot be distinguished from absence, exactly like the
@@ -207,7 +250,8 @@ def _maybe_append_jit(state, prev_idx, prev_term, ent_terms, n_ents,
 
     # conflict scan (log.go:77-84) over the incoming window
     e_idx = prev_idx[:, None] + 1 + jnp.arange(e, dtype=jnp.int32)
-    existing = term_at(state.log_term, state.offset, state.last, e_idx)
+    existing = term_window(state.log_term, state.offset, state.last,
+                           prev_idx + 1, e)
     valid_e = jnp.arange(e) < n_ents[:, None]
     mismatch = valid_e & ((e_idx > state.last[:, None]) |
                           (existing != ent_terms))

@@ -60,6 +60,7 @@ from .batched import (
     progress_update,
     restore_snapshot,
     term_at,
+    term_window,
     tick as tick_batch,
 )
 
@@ -163,10 +164,8 @@ def _build_append_fused(state: GroupState, lane_mask, peer, e):
     n_ents = jnp.where(
         sendable, jnp.clip(state.last - prev_idx, 0, e),
         0).astype(jnp.int32)
-    idx = prev_idx[:, None] + 1 + jnp.arange(e, dtype=jnp.int32)
-    terms2 = term_at(state.log_term, state.offset, state.last,
-                     jnp.concatenate([prev_idx[:, None], idx],
-                                     axis=1))
+    terms2 = term_window(state.log_term, state.offset, state.last,
+                         prev_idx, e + 1)
     return jnp.concatenate([
         jnp.stack([active.astype(jnp.int32),
                    need_snap.astype(jnp.int32),

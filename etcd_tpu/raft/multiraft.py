@@ -55,6 +55,7 @@ from .batched import (
     progress_update,
     restore_snapshot,
     term_at,
+    term_window,
     tick as tick_batch,
 )
 
@@ -185,10 +186,8 @@ def _round_core(states, sels, n_new, drop, e, slots):
                 prev_term = term_at(lst.log_term, lst.offset, lst.last,
                                     prev_idx)
                 n_send = jnp.clip(lst.last - prev_idx, 0, e)
-                ent_idx = prev_idx[:, None] + 1 + \
-                    jnp.arange(e, dtype=jnp.int32)
-                ent_terms = term_at(lst.log_term, lst.offset, lst.last,
-                                    ent_idx)
+                ent_terms = term_window(lst.log_term, lst.offset,
+                                        lst.last, prev_idx + 1, e)
                 pst, ok, e_conf, e_over = maybe_append(
                     pst, prev_idx, prev_term, ent_terms, n_send,
                     lst.commit, active=send)

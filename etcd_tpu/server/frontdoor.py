@@ -522,6 +522,11 @@ class FrontDoor:
         # the stateless ingest free of long-held watch connections.
         self.extra_routes = extra_routes or {}
         self.watch_redirect = watch_redirect
+        # a server that can answer some requests without waiting
+        # says so with this method (MultiGroupServer: a plain GET);
+        # one whose reads may wait (DistServer: lease or ReadIndex)
+        # has none, and every request goes to a worker
+        self._do_local = getattr(etcd, "do_local", None)
 
         self._lsock = socket.socket(socket.AF_INET,
                                     socket.SOCK_STREAM)
@@ -1052,6 +1057,25 @@ class FrontDoor:
             self._start_single_watch(conn, rr, tenant, keepalive)
             return
 
+        if self._do_local is not None:
+            # answered here, the request is never in flight beside
+            # another on this thread: admission has nothing to begin
+            # or finish, and the connection stays idle for the next
+            # pipelined request
+            t_do = time.perf_counter()
+            try:
+                parts = self._do_request(rr, local=True)
+            except Exception as e:  # pragma: no cover
+                log.exception("frontdoor: local answer error")
+                parts = _error_parts(e)
+            if parts is not None:
+                took = time.perf_counter() - t_do
+                tracer.record_wait("fd.do.get", took)
+                tracer.record_wait("fd.read_inline", took)
+                status, h, out = parts
+                self._reply(conn, status, out, h)
+                return
+
         self.admission.begin(tenant)
         conn.tenant = tenant
         conn.mode = "busy"
@@ -1111,12 +1135,20 @@ class FrontDoor:
             self._post(("resp", conn, epoch, parts, False,
                         time.perf_counter()))
 
-    def _do_request(self, rr) -> tuple[int, dict, bytes]:
+    def _do_request(self, rr, local: bool = False
+                    ) -> tuple[int, dict, bytes] | None:
         """``(status, headers, body)`` — the loop thread assembles
         the wire response (and adds CORS headers) in the ``resp``
-        completion handler."""
+        completion handler.  ``local``: the loop thread asking for
+        what the server can answer without waiting; None when it
+        cannot, and a worker asks again the ordinary way."""
         try:
-            resp = self.etcd.do(rr, timeout=self.server_timeout)
+            if local:
+                resp = self._do_local(rr)
+                if resp is None:
+                    return None
+            else:
+                resp = self.etcd.do(rr, timeout=self.server_timeout)
         except EtcdError as e:
             return _error_parts(e)
         except TimeoutError:

@@ -479,17 +479,37 @@ class MultiGroupServer:
                 wc = self.store.watch(r.path, r.recursive, r.stream,
                                       r.since)
                 return Response(watcher=wc)
-            if r.serializable:
-                _M_READ_SERIALIZABLE.inc()
-                self.store.stats.inc_read_path("serializable")
-            else:
-                _M_READ_COHOSTED.inc()
-                self.store.stats.inc_read_path("cohosted")
-            ev = self.store.get(r.path, r.recursive, r.sorted)
-            return Response(event=ev)
+            return self._read(r)
         from .server import UnknownMethodError
 
         raise UnknownMethodError(r.method)
+
+    def _read(self, r: Request) -> Response:
+        """A plain GET: the shared store as it stands, which holds
+        every write acknowledged so far (the apply precedes the
+        acknowledgement)."""
+        if r.serializable:
+            _M_READ_SERIALIZABLE.inc()
+            self.store.stats.inc_read_path("serializable")
+        else:
+            _M_READ_COHOSTED.inc()
+            self.store.stats.inc_read_path("cohosted")
+        ev = self.store.get(r.path, r.recursive, r.sorted)
+        return Response(event=ev)
+
+    def do_local(self, r: Request) -> Response | None:
+        """:meth:`do`'s answer for a request the caller's own thread
+        can have at once, None for one that may wait: the front door
+        asks before it hands a request to a worker.  Here that is a
+        GET of one node from the shared store; a quorum GET rides
+        the log, ``wait`` parks a watcher, and a recursive listing
+        walks a subtree under the world lock (not for a thread that
+        serves every connection)."""
+        if r.method != "GET" or r.wait or r.quorum or r.recursive:
+            return None
+        if r.id == 0:
+            raise ValueError("r.id cannot be 0")
+        return self._read(r)
 
     # -- runtime membership (server.go:382-404, 542-559 batched) ----------
 

@@ -27,7 +27,7 @@ ROUND_PARTS = ("mg.round.dispatch", "mg.round.wait", "mg.round.fetch")
 SERVING = ("mg.pass", "mg.drain_wait", *PASS_CHILDREN, *ROUND_PARTS,
            "mg.queue_wait", "mg.commit_wait", "fd.parse",
            "fd.worker_wait", "fd.do.put", "fd.do.get",
-           "fd.respond_wait")
+           "fd.read_inline", "fd.respond_wait")
 RESTART = ("restart.snapshot_load", "replay.device", "replay.matrix",
            "restart.apply", "restart.seed", "mg.bootstrap_election")
 
@@ -78,7 +78,8 @@ def served(tmp_path_factory):
         """The registry once the engine thread has closed its last
         pass (a write is acknowledged inside mg.apply, before mg.pass
         ends) and the loop thread has filed every fd.respond_wait
-        (after _reply returned, which the client need not wait for)."""
+        (after _reply returned, which the client need not wait for;
+        one for each request that went to a worker)."""
         deadline = time.monotonic() + 5.0
         while True:
             now = wall()
@@ -86,7 +87,7 @@ def served(tmp_path_factory):
             if (g.get("mg.pass", (0,))[0]
                     == g.get("mg.consensus_round", (0,))[0]
                     and g.get("fd.respond_wait", (0,))[0]
-                    == g.get("fd.parse", (0,))[0]) \
+                    == g.get("fd.worker_wait", (0,))[0]) \
                     or time.monotonic() > deadline:
                 return now
             time.sleep(0.01)
@@ -136,15 +137,19 @@ def test_round_parts_tile_the_round(served):
 
 
 @pytest.mark.parametrize("wait", ["mg.queue_wait", "mg.commit_wait",
-                                  "fd.do.put", "fd.do.get"])
+                                  "fd.do.put", "fd.do.get",
+                                  "fd.read_inline"])
 def test_each_request_files_its_wait_once(served, wait):
     assert served["grew"][wait][0] == N_PUTS
 
 
 def test_front_door_waits_cover_every_request(served):
+    # every request is parsed; a PUT goes to a worker and comes back
+    # through the mailbox, a plain GET is answered where it was parsed
     g = served["grew"]
-    for name in ("fd.parse", "fd.worker_wait", "fd.respond_wait"):
-        assert g[name][0] == 2 * N_PUTS, (name, g[name])
+    assert g["fd.parse"][0] == 2 * N_PUTS, g["fd.parse"]
+    for name in ("fd.worker_wait", "fd.respond_wait"):
+        assert g[name][0] == N_PUTS, (name, g[name])
 
 
 def test_pass_holds_its_children_and_counts_rounds_only(served):
