@@ -45,7 +45,6 @@ HOT_LOCKS = frozenset({
     "WatcherHub.mutex",    # watcher tables + history scans
     "DistServer.lock",     # raft state; all peer + client traffic
     "FrontDoor._lock",     # loop<->worker mailbox; loop liveness
-    "WorkerEtcd.lock",     # role-split worker mirror store
     "_Stripe.cond",        # peerlink channel stripes
     "KeepAlivePool._lock",  # shared conn pool on the send path
 })

@@ -276,7 +276,7 @@ class CallGraph:
         ``multiprocessing.Process(target=self._run)``, bare
         ``Thread(target=...)``.  Each is the root of a NEW execution
         context: the ownership checker walks the call graph from
-        these (plus the registered role mains), and held-lock
+        these (plus the registered serve entry points), and held-lock
         propagation must NOT cross into them."""
         with self._build_lock:
             if self._entry_points is None:

@@ -2,7 +2,7 @@
 blocking-under-lock and thread-ownership checkers (PR 16).
 
 The class-local lock-discipline checker (locks.py) sees one class at
-a time; the PR-15 role split spread the locking story across modules
+a time; the locking story spans modules
 (store world lock <- server lock <- peerlink channel state), so the
 three concurrency checkers need one *global* view:
 
@@ -857,7 +857,7 @@ class ConcurrencyModel:
                     if k:
                         keys.append(k)
                     elif t is None and len(raw) > 3 and raw[3]:
-                        # module-receiver call (``rolemsg.pack(...)``)
+                        # module-receiver call (``clientmsg.pack(...)``)
                         for rel, scope, _n in self.resolve_name(
                                 fi.relpath, raw[3]):
                             if (rel, scope) in self.functions:

@@ -42,8 +42,8 @@ _ALLOWED = {
     # into a closed connection
     "EtcdNoSpace", "FrameDropped",
     # PR 15: EtcdOverCapacity carries ECODE_OVER_CAPACITY (same
-    # vocabulary-subclass pattern as EtcdNoSpace) — the ingest
-    # role raises it when a shard lane sheds
+    # vocabulary-subclass pattern as EtcdNoSpace) — the front
+    # door raises it when admission sheds
     "EtcdOverCapacity",
     # stdlib
     "ValueError", "TypeError", "KeyError", "IndexError",

@@ -208,8 +208,6 @@ class FrameTotalityChecker(Checker):
                         f"with {typed}",
                 detail=sch.name))
         for flag in sch.flags:
-            if not flag.scope:
-                continue  # carried for a downstream consumer
             fn = funcs.get(flag.scope)
             if fn is None:
                 continue

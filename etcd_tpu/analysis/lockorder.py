@@ -1,7 +1,7 @@
 """Global lock-order checker (PR 16 tentpole, part 1).
 
 The class-local lock-discipline checker (locks.py) orders locks
-*within* one class; the deadlocks the role split can actually
+*within* one class; the deadlocks the serving tier can actually
 manufacture are cross-module: peerlink stripe conds vs. the
 DistServer lock, the store world lock vs. the hub mutex, the
 frontdoor loop lock vs. worker-side state.  This checker builds the

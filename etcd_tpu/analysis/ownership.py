@@ -1,12 +1,10 @@
 """Thread-ownership checker + domain registry (PR 16 tentpole,
 part 3).
 
-PR 15's role split made several pieces of state single-writer by
-*convention*: the frontdoor loop thread is the sole owner of
-per-conn state, only the serving shard's apply path writes the
-shm-ring head, the distpipe per-channel bookkeeping mutates only
-under the owning server's thread.  Those conventions live here as
-checkable facts:
+Several pieces of state are single-writer by *convention*: the
+frontdoor loop thread is the sole owner of per-conn state, the
+distpipe per-channel bookkeeping mutates only under the owning
+server's lock.  Those conventions live here as checkable facts:
 
 - **Annotations** (in server code): ``# owner: <domain>`` trailing
   an ``self.attr = ...`` assignment declares the attribute a member
@@ -15,8 +13,8 @@ checkable facts:
 - **Registry** (this module): ``DOMAINS`` maps each domain name to
   the thread/process roots allowed to write it — ``(relpath,
   scope)`` function keys, typically thread targets discovered by
-  the call graph (``threading.Thread(target=...)``) or role
-  ``main()``s listed in ``EXTRA_ROOTS``.
+  the call graph (``threading.Thread(target=...)``) or serve
+  entry points listed in ``EXTRA_ROOTS``.
 
 The checker walks forward from every root through the resolved
 call-edge map (spawn boundaries cut the walk: a spawned target is a
@@ -59,8 +57,8 @@ class Domain:
 
 
 #: The real tree's domains.  Owner scopes are thread-entry
-#: functions (Thread targets / role mains); a domain member written
-#: from any OTHER root is a finding.
+#: functions (Thread targets / serve entry points); a domain member
+#: written from any OTHER root is a finding.
 DOMAINS: dict[str, Domain] = {
     "frontdoor-loop": Domain(
         owners=(
@@ -70,32 +68,6 @@ DOMAINS: dict[str, Domain] = {
              "tables): written only by the frontdoor event-loop "
              "thread; workers hand results back via the _post "
              "mailbox")),
-    "shmring-producer": Domain(
-        owners=(
-            ("etcd_tpu/server/distserver.py", "DistServer.run"),
-            ("etcd_tpu/server/distserver.py",
-             "_make_peer_handler.Handler.do_POST"),
-        ),
-        doc=("ring head/generation cursors: the serving shard's "
-             "apply path publishes.  SPSC holds because every "
-             "producer-side touch is serialized by the server "
-             "lock (commits can also land from the ack path on "
-             "peerlink reader threads — legal only under the "
-             "lock, which the guard enforces)"),
-        guard="DistServer.lock"),
-    "shmring-consumer": Domain(
-        owners=(
-            ("etcd_tpu/server/roles.py", "run_worker.consume"),
-        ),
-        doc=("ring tail cursor: only the worker consume thread "
-             "pops")),
-    "ingest-lanes": Domain(
-        owners=(
-            ("etcd_tpu/server/roles.py", "RemoteEtcd._lane"),
-        ),
-        doc=("per-lane etcd_index high-water slots: each written "
-             "only by its own lane thread (slot-striped, no lock); "
-             "everyone else reads max()")),
     "distpipe-state": Domain(
         owners=(
             ("etcd_tpu/server/distserver.py", "DistServer.run"),
@@ -111,12 +83,8 @@ DOMAINS: dict[str, Domain] = {
 }
 
 #: Process/serve entry points the Thread(target=...) scan cannot
-#: see: role mains (spawned as OS processes by the supervisor) and
-#: the threaded peer-HTTP handler.
+#: see: the threaded peer-HTTP handler.
 EXTRA_ROOTS: tuple[tuple[str, str], ...] = (
-    ("etcd_tpu/server/roles.py", "run_shard"),
-    ("etcd_tpu/server/roles.py", "run_worker"),
-    ("etcd_tpu/server/roles.py", "run_ingest"),
     ("etcd_tpu/server/distserver.py",
      "_make_peer_handler.Handler.do_POST"),
 )

@@ -39,17 +39,6 @@ _WRITERS = {w: e for e, (w, _r) in _ELEM_CALLS.items()}
 _READERS = {r: e for e, (_w, r) in _ELEM_CALLS.items()}
 
 
-def _magic_literals() -> tuple[set[bytes], set[int]]:
-    bmagics: set[bytes] = set()
-    imagics: set[int] = set()
-    for f in _schema.FORMATS:
-        if isinstance(f.magic, bytes) and f.magic:
-            bmagics.add(f.magic)
-        elif isinstance(f.magic, int):
-            imagics.add(f.magic)
-    return bmagics, imagics
-
-
 def _arg_name(node: ast.AST) -> str:
     """Best-effort payload name of a section write argument:
     ``self.term`` -> term, ``n_ents`` -> n_ents,
@@ -136,7 +125,7 @@ class SchemaDriftChecker(Checker):
 
     def _check_literals(self, relpath: str, tree: ast.AST,
                         out: list[Finding]) -> None:
-        bmagics, imagics = _magic_literals()
+        magics = {f.magic for f in _schema.FORMATS if f.magic}
         for n in ast.walk(tree):
             if isinstance(n, ast.Call) \
                     and dotted_name(n.func).rsplit(".", 1)[-1] \
@@ -155,11 +144,8 @@ class SchemaDriftChecker(Checker):
                             f"is one copy to edit",
                     detail=n.args[0].value))
             elif isinstance(n, ast.Constant) \
-                    and ((isinstance(n.value, bytes)
-                          and n.value in bmagics)
-                         or (isinstance(n.value, int)
-                             and not isinstance(n.value, bool)
-                             and n.value in imagics)):
+                    and isinstance(n.value, bytes) \
+                    and n.value in magics:
                 out.append(Finding(
                     checker=self.name, path=relpath,
                     line=n.lineno, rule="local-magic-literal",

@@ -18,8 +18,8 @@ import ast
 from .engine import iter_functions
 from ..wire import schema
 
-#: the five formats' homes — every wire checker targets exactly these
-WIRE_TARGETS = ("etcd_tpu/wire/", "etcd_tpu/server/shmring.py")
+#: the formats' home — every wire checker targets exactly this
+WIRE_TARGETS = ("etcd_tpu/wire/",)
 
 #: the schema module itself is the one wire file that legitimately
 #: declares layout literals

@@ -166,13 +166,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "when full (entries/bytes) or this many "
                         "microseconds after its first proposal, "
                         "whichever first")
-    p.add_argument("--dist-roles", type=int, default=0, metavar="S",
-                   help="Compartmentalized serving for --dist-slot "
-                        "mode: supervise a stateless ingest, an "
-                        "apply/watch worker and S serving-shard "
-                        "processes on this host instead of one "
-                        "in-process server (--cohosted-groups must "
-                        "divide by S; 0 = single process)")
     # v0.4.6 back-compat (main.go:87-98); values are validated as
     # strict IP:port (pkg/flags/ipaddressport.go semantics)
     p.add_argument("--addr", default=None, type=parse_ip_address_port,
@@ -353,10 +346,10 @@ def start_dist(args, explicit: set[str]) -> int:
 
     local = args.dist_local_cluster
     if local:
-        if args.dist_slot >= 0 or args.dist_peers or args.dist_roles:
+        if args.dist_slot >= 0 or args.dist_peers:
             log.error("--dist-local-cluster hosts every member slot "
-                      "itself: it excludes --dist-slot, --dist-peers "
-                      "and --dist-roles")
+                      "itself: it excludes --dist-slot and "
+                      "--dist-peers")
             return 1
         if local < 2:
             log.error("--dist-local-cluster needs at least 2 members")
@@ -402,8 +395,6 @@ def start_dist(args, explicit: set[str]) -> int:
         else f"{args.name}_dist{args.dist_slot}_data")
     os.makedirs(data_dir, mode=0o700, exist_ok=True)
     g = args.cohosted_groups or 64
-    if args.dist_roles:
-        return _start_dist_roles(args, explicit, peers, data_dir, g)
     client_tls = TLSInfo(args.cert_file, args.key_file, args.ca_file)
     acurls = urls_from_flags(args, "advertise_client_urls", "addr",
                              explicit, client_tls.empty())
@@ -511,57 +502,6 @@ def start_dist(args, explicit: set[str]) -> int:
         s.flight.dump_to(flight_dir(s), tag="sigterm")
     clean = all([s.stop() for s in servers])
     return 0 if clean and not leaderless else 1
-
-
-def _start_dist_roles(args, explicit: set[str], peers: list[str],
-                      data_dir: str, g: int) -> int:
-    """Role-split dist mode (--dist-roles S): this host serves its
-    slot as a supervised family of processes — a stateless ingest on
-    the client port, an apply/watch worker on client port + m, and S
-    serving shards each peering on peer port + m*s
-    (server/roles.py).  Blocks until the supervisor is stopped."""
-    from .server import roles
-
-    if args.dist_roles < 1 or g % args.dist_roles:
-        log.error("--dist-roles=%d must be >= 1 and divide "
-                  "--cohosted-groups=%d", args.dist_roles, g)
-        return 1
-    client_tls = TLSInfo(args.cert_file, args.key_file, args.ca_file)
-    peer_tls = TLSInfo(args.peer_cert_file, args.peer_key_file,
-                       args.peer_ca_file)
-    if not client_tls.empty() or not peer_tls.empty():
-        # the shared-memory handoff and derived-port fan-out are
-        # loopback-only; the TLS story stays with the single-process
-        # server
-        log.error("--dist-roles does not support TLS")
-        return 1
-    lcurls = urls_from_flags(args, "listen_client_urls", "bind_addr",
-                             explicit, True)
-    _, client_port = _split_hostport(next(iter(lcurls)))
-    # slot 0 bootstraps a brand-new cluster only (same rule as the
-    # single-process path); "fresh" = no shard has a data dir yet
-    fresh = not os.path.exists(os.path.join(data_dir, "shard0"))
-    argv = ["--role", "supervise",
-            "--data-dir", data_dir,
-            "--slot", str(args.dist_slot),
-            "--peers", ",".join(peers),
-            "--client-port", str(client_port),
-            "--shards", str(args.dist_roles),
-            "--groups", str(g),
-            "--name", f"{args.name}-{args.dist_slot}",
-            "--election-ticks", str(args.dist_election_ticks),
-            "--lease-ticks", str(args.dist_lease_ticks),
-            "--pipeline-depth", str(args.dist_pipeline_depth),
-            "--coalesce-us", str(args.dist_coalesce_us),
-            "--flight-dir",
-            os.environ.get("ETCD_FLIGHT_DIR")
-            or os.path.join(data_dir, "trace_artifacts")]
-    if args.snapshot_count is not None:
-        argv += ["--snap-count", str(args.snapshot_count)]
-    if args.dist_slot == 0 and fresh:
-        argv.append("--bootstrap")
-    roles.main(argv)
-    return 0
 
 
 def start_multigroup(args, explicit: set[str]) -> int:

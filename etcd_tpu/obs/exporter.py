@@ -84,44 +84,5 @@ def render_prometheus(reg: Registry | None = None) -> bytes:
     return ("\n".join(lines) + "\n").encode()
 
 
-def render_prometheus_snapshot(snap: dict) -> bytes:
-    """Exposition over a snapshot-shaped dict ({family: {kind,
-    help, samples}}) instead of a live Registry — the supervisor's
-    merged cross-role form (PR 17), where samples carry arbitrary
-    label dicts (the injected ``role`` key included) rather than a
-    family's declared label tuple.  Same 0.0.4 conformance as
-    :func:`render_prometheus`: HELP/TYPE once per family, escaped
-    label values, cumulative histogram buckets."""
-    lines: list[str] = []
-    for name in sorted(snap):
-        fam = snap[name]
-        kind = fam.get("kind", "untyped")
-        lines.append(f"# HELP {name} "
-                     f"{escape_help(fam.get('help', ''))}")
-        lines.append(f"# TYPE {name} {kind}")
-        for s in fam.get("samples", ()):
-            base = sorted(s.get("labels", {}).items())
-            if kind == "histogram":
-                cum = 0
-                for bound, n in zip(s["bounds"], s["buckets"]):
-                    cum += n
-                    lines.append(
-                        f"{name}_bucket"
-                        f"{_labelstr(base + [('le', _fmt(bound))])}"
-                        f" {cum}")
-                cum += s["buckets"][-1]
-                lines.append(
-                    f"{name}_bucket"
-                    f"{_labelstr(base + [('le', '+Inf')])} {cum}")
-                lines.append(f"{name}_sum{_labelstr(base)} "
-                             f"{_fmt(s['sum'])}")
-                lines.append(f"{name}_count{_labelstr(base)} "
-                             f"{s['count']}")
-            else:
-                lines.append(f"{name}{_labelstr(base)} "
-                             f"{_fmt(s.get('value', 0.0))}")
-    return ("\n".join(lines) + "\n").encode()
-
-
 __all__ = ["CONTENT_TYPE", "escape_help", "escape_label_value",
-           "render_prometheus", "render_prometheus_snapshot"]
+           "render_prometheus"]

@@ -74,16 +74,9 @@ class FlightRecorder:
     def __init__(self, node: str = "", slot: int = -1,
                  capacity: int | None = None,
                  sample: int | None = None,
-                 registry: Registry | None = None,
-                 role: str = "server"):
+                 registry: Registry | None = None):
         self.node = node
         self.slot = slot
-        # role-split topology (PR 15): each role process is its own
-        # incarnation — the stitcher keys incarnations on
-        # (slot, role) so an ingest restart never shadows the shard
-        # dumps of the same slot.  Single-process servers keep the
-        # default and stitch exactly as before.
-        self.role = role
         self.capacity = (capacity if capacity is not None
                          else _env_int("ETCD_FLIGHT_RING",
                                        DEFAULT_CAPACITY))
@@ -177,7 +170,6 @@ class FlightRecorder:
             pass
         return {
             "node": self.node, "slot": self.slot, "pid": os.getpid(),
-            "role": self.role,
             "wall_anchor": time.time(),
             "mono_anchor": time.monotonic(),
             "capacity": self.capacity, "sample_n": self.sample_n,
@@ -213,7 +205,7 @@ def harvest_rings(urls: list[str], out_dir: str,
     into ``out_dir`` as ``flight_s{i}.json``; returns the paths
     written (unreachable nodes are skipped — their SIGTERM/crash
     dumps, if any, live under their own data dirs).  The one copy of
-    the harvest loop chaos_drill and dist_bench both ride."""
+    the harvest loop (chaos_drill rides it)."""
     import urllib.request
 
     os.makedirs(out_dir, exist_ok=True)
@@ -227,17 +219,7 @@ def harvest_rings(urls: list[str], out_dir: str,
             log.warning("flight harvest: %s unreachable (%s)", u,
                         type(e).__name__)
             continue
-        # name by (slot, role) when the dump says so: a role-split
-        # host contributes several rings per slot and they must not
-        # clobber one another on disk
-        tag = f"s{i}"
-        try:
-            d = json.loads(body)
-            if d.get("role", "server") != "server":
-                tag = f"s{d.get('slot', i)}_{d['role']}"
-        except (ValueError, KeyError, TypeError):
-            pass
-        p = os.path.join(out_dir, f"flight_{tag}.json")
+        p = os.path.join(out_dir, f"flight_s{i}.json")
         with open(p, "wb") as f:
             f.write(body)
         paths.append(p)

@@ -354,9 +354,7 @@ _DEFS = (
     MetricDef(
         "etcd_profile_overhead_ratio", "gauge",
         "Measured profiler self-cost: sampler-thread CPU seconds "
-        "over wall seconds since start (the dist_bench "
-        "--profile-overhead gate bounds the end-to-end acked/s "
-        "cost at 2%; this gauge is the in-process floor)."),
+        "over wall seconds since start."),
     MetricDef(
         "etcd_slo_burn_rate", "gauge",
         "Error-budget burn rate per declared objective "
@@ -369,19 +367,6 @@ _DEFS = (
         "1 while the objective meets its target over its window "
         "(vacuously 1 with no samples), else 0.",
         labels=("objective",)),
-    MetricDef(
-        "etcd_role_up", "gauge",
-        "Supervisor-merged liveness per child role (PR 17): 1 "
-        "while the last /mraft/obs scrape is fresh, 0 while the "
-        "role is down or mid-respawn (its last-known samples stay "
-        "in the merged view, stale-marked — never a scrape "
-        "error).", labels=("role",)),
-    MetricDef(
-        "etcd_obs_scrape_total", "counter",
-        "Supervisor scrape attempts per child role by outcome: "
-        "ok | error (child unreachable or bad snapshot — the "
-        "merged view serves stale instead of failing).",
-        labels=("role", "outcome")),
     MetricDef(
         "etcd_lint_findings", "gauge",
         "Findings per checker in the last static-analysis run "
@@ -484,8 +469,7 @@ class Histogram:
         # invariant a concurrent observe() between two lock takes
         # would break).  ``light`` skips the exact-percentile ring
         # sort — the dominant snapshot cost — for per-second callers
-        # (the time-series ring, the supervisor scrape) that only
-        # consume count/sum/buckets.
+        # (the time-series ring) that only consume count/sum/buckets.
         with self._lock:
             count, total, mx = self.count, self.sum, self.max
             ring = None if light else sorted(self._ring)
@@ -583,8 +567,8 @@ class Registry:
         entry per labeled child (histograms carry bucket counts AND
         exact ring percentiles — the /mraft/obs and soak-artifact
         form).  ``light`` skips the ring-sorted exact percentiles
-        (the ``/mraft/obs/light`` scrape form: cheap enough for a
-        per-second cadence)."""
+        (cheap enough for the time-series ring's per-second
+        cadence)."""
         out = {}
         for fam in self.families():
             samples = []
@@ -601,8 +585,8 @@ class Registry:
                                "samples": samples}
         return out
 
-    def snapshot_json(self, light: bool = False) -> bytes:
-        return (json.dumps(self.snapshot(light=light),
+    def snapshot_json(self) -> bytes:
+        return (json.dumps(self.snapshot(),
                            sort_keys=True) + "\n").encode()
 
     def reset(self) -> None:
@@ -619,8 +603,8 @@ registry = Registry()
 def percentile_from_buckets(bounds: list[float], buckets: list[int],
                             q: float) -> float:
     """Upper-bound percentile estimate from (possibly merged) bucket
-    counts — the cross-process form (scripts/dist_bench.py merges the
-    three hosts' ack-RTT buckets through this).  Returns the ``le``
+    counts — the cross-process form (obs/timeseries.py merges
+    harvested rings' buckets through this).  Returns the ``le``
     boundary of the bucket holding quantile ``q``; the overflow
     bucket reports the last finite boundary (a floor, flagged by the
     caller if it matters)."""

@@ -16,10 +16,7 @@ disables) and attributes every OTHER thread's current stack:
 
 Samples land in ``etcd_profile_samples_total{stage,domain}``; the
 sampler meters its own CPU-per-wall cost into
-``etcd_profile_overhead_ratio``.  The end-to-end cost gate is
-``dist_bench --profile-overhead --check`` (<= 2% acked/s vs a
-profiler-off arm); per-role sample tables merge through the
-supervisor plane like every other family.
+``etcd_profile_overhead_ratio``.
 
 The sampling core is ``sys._current_frames()`` — one C call under
 the GIL, no per-thread locks, no target-thread cooperation — plus a
@@ -164,9 +161,8 @@ _default_lock = threading.Lock()
 
 
 def start_default() -> Profiler | None:
-    """Arm the process-wide profiler (idempotent); every role main
-    and the dist server call this at start.  ``ETCD_PROFILE_HZ=0``
-    disables — the profiler-off arm of the overhead gate."""
+    """Arm the process-wide profiler (idempotent); the dist server
+    calls this at start.  ``ETCD_PROFILE_HZ=0`` disables."""
     global _default
     try:
         hz = float(os.environ.get("ETCD_PROFILE_HZ", DEFAULT_HZ))

@@ -18,8 +18,7 @@ error budget exactly at the sustainable pace, >1 is burning, 0 with
 no traffic (an idle objective is vacuously met).  Every evaluation
 exports ``etcd_slo_burn_rate{objective}`` and
 ``etcd_slo_ok{objective}`` gauges (CATALOG families) and the typed
-``GET /v2/stats/slo`` verdict served by both stats endpoints and the
-role supervisor's merged plane.
+``GET /v2/stats/slo`` verdict served by both stats endpoints.
 """
 
 from __future__ import annotations
@@ -155,7 +154,7 @@ def evaluate(snaps: list[dict],
 
 
 def merge_verdicts(verdicts: list[dict]) -> dict:
-    """Worst-of merge of per-node verdicts (doctor / bench rows):
+    """Worst-of merge of per-node verdicts (scripts/doctor.py):
     each objective keeps its highest burn, the cluster verdict is
     the most severe."""
     out: dict = {"t": time.time(), "objectives": {}}
@@ -203,8 +202,8 @@ _default_lock = threading.Lock()
 
 def default_evaluator() -> SLOEvaluator:
     """Process-wide evaluator over the default ring, exporting its
-    gauges into the default registry (so burn rates ride /metrics
-    and the supervisor merge)."""
+    gauges into the default registry (so burn rates ride
+    /metrics)."""
     global _default
     with _default_lock:
         if _default is None:

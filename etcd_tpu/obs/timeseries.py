@@ -14,13 +14,12 @@ deltas through ``percentile_from_buckets``) are queryable live:
 - a child restart (cumulative value moving BACKWARD) is treated as a
   fresh incarnation: the delta is the new value, never negative;
 - the source is either a :class:`~.metrics.Registry` or any callable
-  returning the registry snapshot dict shape — the supervisor feeds
-  its merged cross-role view through the same ring type;
+  returning the registry snapshot dict shape;
 - family names are CATALOG-checked at query time (a typo'd family
   fails loudly, the metrics-vocabulary stance).
 
 The JSON form (``/mraft/obs/timeseries``) is what chaos_drill
-harvests on gate failure and what dist_bench/doctor merge across
+harvests on gate failure and what scripts/doctor.py merges across
 nodes via :func:`windowed_summary`.
 
 Stdlib-only, like the rest of ``obs/``.
@@ -276,7 +275,7 @@ def snap_rate(snaps: list[dict], family: str,
               window_s: float = 10.0,
               label_filter: dict | None = None) -> float:
     """Summed per-second rate of ``family`` across harvested ring
-    snapshots (one per node/role) over the trailing window."""
+    snapshots (one per node) over the trailing window."""
     flt = label_filter or {}
     total = 0.0
     span = 0.0
@@ -319,9 +318,9 @@ def snap_percentile(snaps: list[dict], family: str, q: float,
 
 
 def windowed_summary(snaps: list[dict]) -> dict:
-    """The standard windowed row embedded in bench results and the
-    doctor report: short-window rates + minute-window percentiles,
-    merged across every harvested ring."""
+    """The standard windowed row of the doctor report: short-window
+    rates + minute-window percentiles, merged across every harvested
+    ring."""
     admit = snap_rate(snaps, "etcd_admission_total", 60.0,
                       {"outcome": "admit"})
     total = snap_rate(snaps, "etcd_admission_total", 60.0)
@@ -348,8 +347,8 @@ _default_lock = threading.Lock()
 
 def start_default() -> TimeSeries:
     """The process-wide ring over the default registry, armed on
-    first use (every role calls this at start; the stats endpoints
-    call it on first query).  Step/retention come from
+    first use (the dist server calls this at start; the stats
+    endpoints call it on first query).  Step/retention come from
     ``ETCD_TS_STEP_S`` / ``ETCD_TS_RETENTION``."""
     global _default
     with _default_lock:

@@ -117,8 +117,7 @@ def _handle_append_fused(state: GroupState, sender_v, term, prev_idx,
 
     The unfused chain (PR 2's shape) cost ~8 eager dispatches per
     frame — at the pipeline's frame rates that fixed per-frame tax
-    was the follower's single largest CPU line (measured via the
-    dist_bench span table)."""
+    was the follower's single largest CPU line."""
     st = _adopt_term(state, term, sender_v, active)
     cur = active & (term == st.term)
     st = st._replace(

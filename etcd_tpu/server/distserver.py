@@ -72,7 +72,6 @@ from ..wal import WAL, exist as wal_exist
 from ..wire import Entry, GroupEntry, HardState, Snapshot
 from ..wire.proto import marshal_group_entries
 from ..wire import clientmsg
-from ..wire import rolemsg
 from ..wire.distmsg import (
     AppendBatch,
     AppendResp,
@@ -108,7 +107,6 @@ log = logging.getLogger(__name__)
 # Peer-tier read endpoints (PR 7 linearizable read path)
 READ_INDEX_PATH = "/mraft/readindex"
 GET_MANY_PATH = "/mraft/get_many"
-ROLE_FWD_PATH = "/mraft/role_fwd"
 
 # read_many result-slot sentinels: identity-compared module objects,
 # never strings — a STORED VALUE equal to any string sentinel would
@@ -560,15 +558,6 @@ class DistServer:
         # fault activations land in this server's black box, and a
         # fail-stop dumps the ring before the process exits
         _faults.FAULTS.attach_sink(self.flight)
-        # committed-stream tap for the role-split topology (PR 15):
-        # server/roles.py attaches a CommitSink AFTER start() so
-        # WAL-replay applies never reach the apply worker twice.
-        # Called under self.lock with (group, gindex, payload) rows;
-        # payload is the already-marshaled Request — the handoff
-        # never re-marshals what raft just committed.
-        # typed (string: roles.py would be a circular import) so
-        # the concurrency model can follow sink.push -> ring.push
-        self.commit_sink: "CommitSink | None" = None
         # (group, gindex) -> trace_id for in-flight TRACED proposals
         # (sampled subset of _ack_clock's keys; guarded by self.lock)
         self._trace_live: dict[tuple[int, int], int] = {}
@@ -2896,17 +2885,8 @@ class DistServer:
         # batch the whole commit window into ONE fanout dispatch; the
         # round scope keeps watcher matching/delivery off this path
         # (we hold self.lock here — the engine thread picks it up)
-        sink = self.commit_sink
-        sink_rows: list | None = [] if sink is not None else None
         with self.store.fanout_round():
-            self._apply_window(assigned, mr, commit, newly,
-                               sink_rows)
-        if sink_rows:
-            # the ring write is a bounded memcpy that never blocks
-            # (shmring drops + counts on overrun), so it can ride
-            # the apply path without threatening raft liveness
-            with tracer.stage("role.handoff_marshal"):
-                sink.push(sink_rows)
+            self._apply_window(assigned, mr, commit, newly)
         self._m_apply_n.observe(n_apply)
         self._m_apply_s.observe(time.perf_counter() - t_apply)
         mr.mark_applied(self.applied)
@@ -2929,8 +2909,7 @@ class DistServer:
             # and snapshot()'s disk I/O must not run there
             self._want_snap = True
 
-    def _apply_window(self, assigned, mr, commit, newly,
-                      sink_rows: list | None = None) -> None:
+    def _apply_window(self, assigned, mr, commit, newly) -> None:
         """Per-group apply loop (split from _apply_committed so the
         fanout round brackets exactly the store mutations)."""
         for gi in np.nonzero(newly)[0]:
@@ -2973,8 +2952,6 @@ class DistServer:
                         resp = Response()
                     else:
                         resp = apply_request_to_store(self.store, r)
-                        if sink_rows is not None:
-                            sink_rows.append((int(gi), idx, payload))
                 self.raft_index += 1
                 if tid is not None:
                     self.flight.span(tid, self.slot, "apply")
@@ -3511,25 +3488,6 @@ def unpack_requests(body: bytes) -> list[Request]:
     return out
 
 
-def _refwd_not_leader(server: "DistServer", reqs: list[Request],
-                      res: list, timeout: float = 30.0) -> list:
-    """do_many answers follower-received writes with
-    ``TimeoutError("not leader")`` — the batch lane never
-    re-forwards (its clients target leaders).  The role-split ingest
-    always posts to its LOCAL shard, so on follower hosts every
-    write would bounce; re-drive just the misses through the
-    single-op path, which forwards to the group leader.  The extra
-    hop is only paid on non-leader hosts for non-leader groups."""
-    out = list(res)
-    for i, x in enumerate(out):
-        if isinstance(x, TimeoutError) and "not leader" in str(x):
-            try:
-                out[i] = server.do(reqs[i], timeout=timeout)
-            except Exception as e:
-                out[i] = e
-    return out
-
-
 def _make_peer_handler(server: DistServer):
     class Handler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"
@@ -3577,11 +3535,25 @@ def _make_peer_handler(server: DistServer):
                 except OSError:
                     self._reply(503, b"")
                     return
+                if server.done.is_set():
+                    # stopping: nothing drains the proposal queue
+                    # any more, so a write taken here would only
+                    # wait out its timeout (the body stays unread:
+                    # the connection ends with the answer)
+                    self.close_connection = True
+                    self._reply(503, b"")
+                    return
                 if self.path == "/mraft":
                     try:
                         out = server.handle_frame(self._body())
                     except ServerStoppedError:
                         self._reply(503, b"")
+                        return
+                    except FrameError as e:
+                        # not a frame: the sender's fault, said so
+                        self._reply(400, json.dumps(
+                            {"ok": False,
+                             "message": str(e)}).encode())
                         return
                     except EtcdNoSpace:
                         # read-only member: a distinct status the
@@ -3666,114 +3638,6 @@ def _make_peer_handler(server: DistServer):
                                  "errs": errs}).encode()
                         self._reply(200, body)
                     except Exception as e:
-                        self._reply(400, json.dumps(
-                            {"ok": False,
-                             "message": str(e)}).encode())
-                elif self.path == ROLE_FWD_PATH:
-                    # role-split ingest -> shard handoff (PR 15):
-                    # the packed DRH1 batch carries per-op flags the
-                    # version-stable Request marshal deliberately
-                    # omits (serializable), and the reply shape is
-                    # frame-negotiated — acks for write batches,
-                    # leaf values for read batches, full v2 events
-                    # for the coalesced single-op lane.  Both
-                    # directions are stage-metered so the bench gate
-                    # can hold the handoff share under the client
-                    # JSON share it replaced.
-                    try:
-                        with tracer.stage("role.handoff_parse"):
-                            blobs, opflags, reply = \
-                                rolemsg.unpack_fwd_request(
-                                    self._body())
-                            reqs = []
-                            for b, fl in zip(blobs,
-                                             opflags.tolist()):
-                                r = Request.unmarshal(b)
-                                if fl & rolemsg.OP_SERIALIZABLE:
-                                    r.serializable = True
-                                reqs.append(r)
-                        if reply == rolemsg.REPLY_ACKS:
-                            res = _refwd_not_leader(
-                                server, reqs,
-                                server.do_many(reqs, timeout=30.0))
-                            with tracer.stage(
-                                    "role.handoff_marshal"):
-                                out = rolemsg.pack_fwd_acks(
-                                    len(res),
-                                    {i: (getattr(x, "error_code",
-                                                 300), str(x))
-                                     for i, x in enumerate(res)
-                                     if not isinstance(x, Response)})
-                        elif reply == rolemsg.REPLY_VALS:
-                            res = server.read_many(reqs,
-                                                   timeout=30.0)
-                            vals: list = []
-                            errs_r: dict = {}
-                            for i, x in enumerate(res):
-                                if isinstance(x, Response):
-                                    ev = x.event
-                                    vals.append(
-                                        ev.node.value
-                                        if ev is not None
-                                        and ev.node is not None
-                                        else None)
-                                else:
-                                    vals.append(None)
-                                    errs_r[i] = (getattr(
-                                        x, "error_code", 300),
-                                        str(x))
-                            with tracer.stage(
-                                    "role.handoff_marshal"):
-                                out = rolemsg.pack_fwd_vals(
-                                    vals, errs_r)
-                        else:
-                            # mixed lane: plain GETs ride the
-                            # zero-WAL read path (linearizable via
-                            # ReadIndex; serializable flag already
-                            # restored above), everything else —
-                            # writes and QGET quorum reads — goes
-                            # through the proposal coalescer; the
-                            # two result streams stitch back in
-                            # request order
-                            ridx = [i for i, r in enumerate(reqs)
-                                    if r.method == "GET"
-                                    and not r.quorum]
-                            widx = [i for i, r in enumerate(reqs)
-                                    if r.method != "GET"
-                                    or r.quorum]
-                            results: list = [None] * len(reqs)
-                            if widx:
-                                wreqs = [reqs[i] for i in widx]
-                                for i, x in zip(
-                                        widx, _refwd_not_leader(
-                                            server, wreqs,
-                                            server.do_many(
-                                                wreqs,
-                                                timeout=30.0))):
-                                    results[i] = x
-                            if ridx:
-                                for i, x in zip(
-                                        ridx, server.read_many(
-                                            [reqs[i] for i in ridx],
-                                            timeout=30.0)):
-                                    results[i] = x
-                            final = []
-                            for x in results:
-                                if isinstance(x, Response):
-                                    final.append(
-                                        x.event if x.event
-                                        is not None else
-                                        EtcdError(300, "no event"))
-                                else:
-                                    final.append(x)
-                            with tracer.stage(
-                                    "role.handoff_marshal"):
-                                out = rolemsg.pack_fwd_response(
-                                    final)
-                        self._reply(200, out)
-                    except ServerStoppedError:
-                        self._reply(503, b"")
-                    except (FrameError, ValueError) as e:
                         self._reply(400, json.dumps(
                             {"ok": False,
                              "message": str(e)}).encode())
@@ -3890,15 +3754,8 @@ def _make_peer_handler(server: DistServer):
                 self._reply(200, server.snapshot_frontier())
             elif self.path == "/mraft/obs":
                 # JSON registry snapshot (bucket counts + exact ring
-                # percentiles): the cross-process merge form —
-                # scripts/dist_bench.py pools the three hosts'
-                # ack-RTT buckets from here
+                # percentiles): the cross-process merge form
                 self._reply(200, _obs.registry.snapshot_json())
-            elif self.path == "/mraft/obs/light":
-                # no exact-percentile ring sorts: the role
-                # supervisor's per-second scrape form (PR 17)
-                self._reply(200,
-                            _obs.registry.snapshot_json(light=True))
             elif self.path == "/mraft/obs/flight":
                 # flight-recorder dump (PR 8): the ring + clock
                 # anchors + per-stage wall/cpu/device sums — what

@@ -493,9 +493,7 @@ class FrontDoor:
                  cors: set[str] | None = None,
                  server_timeout: float | None = None,
                  watch_timeout: float | None = None,
-                 watch_keepalive: float | None = None,
-                 extra_routes: dict | None = None,
-                 watch_redirect: str | None = None):
+                 watch_keepalive: float | None = None):
         # lazy: api.http imports LISTEN_BACKLOG from this module at
         # module level, so the reverse import must happen at runtime
         from ..api import http as _http
@@ -513,15 +511,6 @@ class FrontDoor:
         self.watch_keepalive = (_http.DEFAULT_WATCH_KEEPALIVE
                                 if watch_keepalive is None
                                 else watch_keepalive)
-        # role-split hooks (PR 15).  extra_routes: exact path ->
-        # handler(method, path, query, headers, body) returning
-        # (status, headers, body); runs on the worker pool so batch
-        # endpoints (the ingest role's /mraft/propose_many lineage)
-        # never stall the event loop.  watch_redirect: base URL of
-        # the apply/watch worker — wait= requests 307 there, keeping
-        # the stateless ingest free of long-held watch connections.
-        self.extra_routes = extra_routes or {}
-        self.watch_redirect = watch_redirect
         # a server that can answer some requests without waiting
         # says so with this method (MultiGroupServer: a plain GET);
         # one whose reads may wait (DistServer: lease or ReadIndex)
@@ -967,24 +956,7 @@ class FrontDoor:
             self._serve_machines(conn, method)
             return
 
-        handler = self.extra_routes.get(path)
-        if handler is not None:
-            conn.mode = "busy"
-            try:
-                self._jobs.put_nowait(
-                    (conn, conn.epoch,
-                     ("route", handler, method, path, parsed.query,
-                      headers, body), time.perf_counter()))
-            except queue.Full:
-                conn.mode = "idle"
-                self._reply(conn, 503, b"overloaded\n",
-                            {"Retry-After": "1"})
-            return
-
         if path == _http.WATCH_PREFIX:
-            if self.watch_redirect is not None:
-                self._redirect_watch(conn, _http.WATCH_PREFIX, "")
-                return
             self._serve_watch_many(conn, method, headers, body)
             return
         if path.startswith(_http.KEYS_PREFIX):
@@ -1051,9 +1023,6 @@ class FrontDoor:
             return
 
         if rr.wait:
-            if self.watch_redirect is not None:
-                self._redirect_watch(conn, path, query)
-                return
             self._start_single_watch(conn, rr, tenant, keepalive)
             return
 
@@ -1092,14 +1061,6 @@ class FrontDoor:
                 cause=f"{tenant}: queue_depth",
                 index=self.etcd.store.index(), retry_after=1.0))
 
-    def _redirect_watch(self, conn: _Conn, path: str,
-                        query: str) -> None:
-        """307 to the watch worker: method + body survive the hop,
-        and stock HTTP clients re-issue wait GETs transparently."""
-        loc = self.watch_redirect + path + (f"?{query}" if query
-                                            else "")
-        self._reply(conn, 307, b"", {"Location": loc})
-
     # -- worker pool -------------------------------------------------------
 
     def _worker(self) -> None:
@@ -1117,18 +1078,12 @@ class FrontDoor:
             t_get = time.perf_counter()
             tracer.record_wait("fd.worker_wait", t_get - t_put)
             try:
-                if type(rr) is tuple and rr[0] == "route":
-                    _tag, handler, method, path, query, headers, \
-                        body = rr
-                    parts = handler(method, path, query, headers,
-                                    body)
-                else:
-                    # do() may rename the method (a quorum GET)
-                    wait = "fd.do.get" if rr.method == "GET" \
-                        else "fd.do.put"
-                    parts = self._do_request(rr)
-                    tracer.record_wait(
-                        wait, time.perf_counter() - t_get)
+                # do() may rename the method (a quorum GET)
+                wait = "fd.do.get" if rr.method == "GET" \
+                    else "fd.do.put"
+                parts = self._do_request(rr)
+                tracer.record_wait(
+                    wait, time.perf_counter() - t_get)
             except Exception as e:  # pragma: no cover
                 log.exception("frontdoor: worker error")
                 parts = _error_parts(e)

@@ -39,7 +39,7 @@ def _tagged_string(buf: bytearray, tag: int, s: str) -> None:
 def _string_field(data: bytes, pos: int) -> tuple[str, int]:
     """Length-delimited utf-8 field; a non-utf-8 blob fails typed as
     ProtoError, never an escaping UnicodeDecodeError (these payloads
-    arrive over the FWD_REQ handoff and out of the WAL)."""
+    arrive in peers' packed Request batches and out of the WAL)."""
     b, pos = _bytes_field(data, pos)
     try:
         return b.decode(), pos

@@ -1,19 +1,17 @@
 """Declarative wire-frame schemas — the single source of truth for
 every binary layout the serving path speaks (PR 19).
 
-Five hand-rolled formats cross process and host boundaries: DGB2/DGB3
+Three hand-rolled formats cross process and host boundaries: DGB2/DGB3
 peer frames (``wire/distmsg.py``), the DCB1 client protocol
-(``wire/clientmsg.py``), DRH1 role handoff (``wire/rolemsg.py``), the
-gogoproto codec (``wire/proto.py``), and the SRG1 shm segment layout
-(``server/shmring.py``).  Each used to carry its magic, struct format
-strings, flag bits, and plausibility caps as module-private literals
-maintained by hand in marshal/unmarshal pairs.  This module makes the
-layouts first-class data:
+(``wire/clientmsg.py``) and the gogoproto codec (``wire/proto.py``).
+Each used to carry its magic, struct format strings, flag bits, and
+plausibility caps as module-private literals maintained by hand in
+marshal/unmarshal pairs.  This module makes the layouts first-class
+data:
 
   * ``FrameSchema`` declares magic, the header struct format with
-    named fields, frame kinds with their ordered sections, flag bits
-    mapped to the optional trailing section they gate, and — for the
-    fixed-offset SRG1 header — the field offset table.
+    named fields, frame kinds with their ordered sections, and flag
+    bits mapped to the optional trailing section they gate.
   * ``Bound`` annotates every wire length/count field with its
     plausibility cap (the ``implausible trace count`` guard that
     existed for exactly one field pre-PR-19, made total) and the
@@ -40,7 +38,6 @@ Grammar, informally::
                           sections=(Section(name, elem, rname?)...)),),
               flags=(Flag(name, bit, section, scope)...),
               structs={module const: struct fmt},
-              offsets={field: byte offset},     # SRG1 only
               bounds=(Bound(name, cap, scope)...),
               parse_scopes=(entry scopes...))
 
@@ -58,8 +55,8 @@ from dataclasses import dataclass, field
 
 
 class FrameError(Exception):
-    """Typed parse failure for the frame formats (DGB2/DCB1/DRH1/
-    SRG1).  Lives here — the root of the wire layer — so the schema's
+    """Typed parse failure for the frame formats (DGB2/DCB1).
+    Lives here — the root of the wire layer — so the schema's
     ``check_bound`` can raise it without importing a parser module;
     ``wire/distmsg.py`` re-exports it for the historical import
     path."""
@@ -98,9 +95,7 @@ class Kind:
 @dataclass(frozen=True)
 class Flag:
     """A header flag bit and the optional trailing section it gates.
-    ``scope`` names the parse scope that must test the bit; "" means
-    the bit is carried for a downstream consumer (reply-shape bits)
-    and parse-side handling is not required."""
+    ``scope`` names the parse scope that must test the bit."""
 
     name: str
     bit: int
@@ -147,16 +142,14 @@ class ProtoMessage:
 class FrameSchema:
     name: str
     module: str
-    magic: bytes | int
+    magic: bytes
     error: str
     header: str = ""
     header_fields: tuple[str, ...] = ()
-    header_size: int = 0
     count_fields: tuple[str, ...] = ()
     kinds: tuple[Kind, ...] = ()
     flags: tuple[Flag, ...] = ()
     structs: dict[str, str] = field(default_factory=dict)
-    offsets: dict[str, int] = field(default_factory=dict)
     bounds: tuple[Bound, ...] = ()
     messages: tuple[ProtoMessage, ...] = ()
     parse_scopes: tuple[str, ...] = ()
@@ -183,7 +176,7 @@ class FrameSchema:
 
 
 # ---------------------------------------------------------------------------
-# the five formats
+# the three formats
 # ---------------------------------------------------------------------------
 
 DGB2 = FrameSchema(
@@ -299,81 +292,6 @@ DCB1 = FrameSchema(
                   "unpack_get_response", "unpack_propose_response"),
 )
 
-DRH1 = FrameSchema(
-    name="DRH1",
-    module="etcd_tpu/wire/rolemsg.py",
-    magic=b"DRH1",
-    error="FrameError",
-    header="<4sBBHI",
-    header_fields=("magic", "kind", "flags", "reserved", "count"),
-    count_fields=("count",),
-    kinds=(
-        Kind("KIND_FWD_REQ", 0, unmarshal="unpack_fwd_request",
-             sections=(Section("opflags", "u8"),
-                       Section("rlens", "i32"),
-                       Section("blobs", "blob"))),
-        Kind("KIND_FWD_ACKS", 1, unmarshal="unpack_fwd_acks",
-             sections=(Section("errs", "struct:_ERR"),
-                       Section("msgs", "blob"))),
-        Kind("KIND_FWD_VALS", 2, unmarshal="unpack_fwd_vals",
-             sections=(Section("vlens", "i32"),
-                       Section("errs", "struct:_ERR"),
-                       Section("vals", "blob"),
-                       Section("msgs", "blob"))),
-        Kind("KIND_FWD_RESP", 3, unmarshal="unpack_fwd_response",
-             sections=(Section("rows", "struct:_EVT"),
-                       Section("blobs", "blob"))),
-        Kind("KIND_COMMIT", 4, unmarshal="unpack_commit",
-             sections=(Section("seq", "u64"),
-                       Section("groups", "i32"),
-                       Section("gindex", "i64"),
-                       Section("rlens", "i32"),
-                       Section("payloads", "blob"))),
-    ),
-    flags=(
-        # reply-shape bits ride the header for the shard-side
-        # dispatcher (server/roles.py); the parser hands them through
-        Flag("REPLY_ACKS", 0x01),
-        Flag("REPLY_VALS", 0x02),
-    ),
-    structs={"_HDR": "<4sBBHI", "_ERR": "<iii",
-             "_EVT": "<iBBHqqqqqdiiii"},
-    bounds=(
-        Bound("drh1.count", 1 << 20, scope="_parse_header",
-              doc="ops / rows per handoff frame"),
-        Bound("drh1.blob_len", 1 << 26, scope="_slice_blobs",
-              doc="one request/payload blob"),
-        Bound("drh1.val_len", 1 << 26, scope="unpack_fwd_vals",
-              doc="one value blob"),
-        Bound("drh1.msg_len", 1 << 16, scope="_unpack_errs",
-              doc="one error message"),
-    ),
-    parse_scopes=("_parse_header", "unpack_fwd_request",
-                  "_unpack_errs", "_slice_msgs", "_slice_blobs",
-                  "unpack_fwd_acks", "unpack_fwd_vals",
-                  "unpack_fwd_response", "unpack_commit"),
-)
-
-SRG1 = FrameSchema(
-    name="SRG1",
-    module="etcd_tpu/server/shmring.py",
-    magic=0x31475253,  # "SRG1" little-endian
-    error="FrameError",
-    # fixed-offset header, not a packed struct: cursors are single
-    # aligned 8-byte stores and must not move if a field is added
-    header_size=64,
-    offsets={"magic": 0, "generation": 4, "head": 8, "tail": 16,
-             "dropped": 24, "capacity": 32},
-    bounds=(
-        Bound("srg1.capacity", 1 << 30, scope="ShmRing._attach",
-              doc="ring byte span, validated against segment size"),
-        Bound("srg1.record_len", 1 << 26,
-              doc="one length-prefixed record"),
-    ),
-    parse_scopes=("ShmRing._attach", "ShmRing._peek",
-                  "ShmRing.pop"),
-)
-
 GPB1 = FrameSchema(
     name="GPB1",
     module="etcd_tpu/wire/proto.py",
@@ -423,7 +341,7 @@ GPB1 = FrameSchema(
                   "GroupEntry.unmarshal", "SnapPb.unmarshal"),
 )
 
-FORMATS: tuple[FrameSchema, ...] = (DGB2, DCB1, DRH1, SRG1, GPB1)
+FORMATS: tuple[FrameSchema, ...] = (DGB2, DCB1, GPB1)
 
 #: schema by owning module relpath — the wire checkers key on this
 MODULE_SCHEMAS: dict[str, FrameSchema] = {
@@ -441,7 +359,7 @@ BOUNDS: dict[str, int] = {
 #: parse_scopes pin the real modules' entry points exactly
 PARSE_NAME_RE = re.compile(
     r"^(unmarshal|unpack_|parse_|_parse_|_read_|_unpack_|_slice_"
-    r"|uvarint$|_tag$|_skip_field$|_bytes_field$|_peek$|pop$)")
+    r"|uvarint$|_tag$|_skip_field$|_bytes_field$)")
 
 
 def check_bound(name: str, value: int,

@@ -75,15 +75,6 @@ if "--wire" in _argv:
     if WIRE not in ("json", "binary"):
         raise SystemExit(f"--wire must be json|binary, got {WIRE!r}")
     _argv = _argv[:_wi] + _argv[_wi + 2:]
-# --roles S (PR 15): every host runs the compartmentalized role
-# family (ingest + apply/watch worker + S serving shards under a
-# supervisor) instead of one in-process server; extracted like --seed
-# so the shard count is never mistaken for the CYCLES positional
-ROLES = 0
-if "--roles" in _argv:
-    _oi = _argv.index("--roles")
-    ROLES = int(_argv[_oi + 1])
-    _argv = _argv[:_oi] + _argv[_oi + 2:]
 _pos = [a for a in _argv if a.isdigit()]
 CYCLES = int(_pos[0]) if _pos else 6
 deep_lag = "--deep-lag" in sys.argv
@@ -103,12 +94,6 @@ env.update(JAX_PLATFORMS="cpu", ETCD_DEBUG_ELECTIONS="1",
 
 
 def start(slot, extra=()):
-    if ROLES:
-        # role-split topology: the cli hands the slot to the role
-        # supervisor; the pinned election/lease ticks below pass
-        # through to the shard children, so the recovery gates stay
-        # calibrated
-        extra = ("--dist-roles", str(ROLES), *extra)
     return subprocess.Popen(
         [sys.executable, "-m", "etcd_tpu.cli", "--name", "chaos",
          "--data-dir", f"{BASE}/d{slot}", "--dist-slot", str(slot),
@@ -161,11 +146,9 @@ _BID = [1 << 48]
 
 def put_batch(slot, items, timeout=20):
     """One /mraft/propose_many frame of (key, val) writes against the
-    PEER port of ``slot`` (role mode: the ingest CLIENT port — the
-    batch routes moved to the front of the role family); returns the
-    per-item ok verdicts.  With ``--wire binary`` the reply rides the
-    DCB1 framing (the request body is the version-stable packed form
-    either way)."""
+    PEER port of ``slot``; returns the per-item ok verdicts.  With
+    ``--wire binary`` the reply rides the DCB1 framing (the request
+    body is the version-stable packed form either way)."""
     from etcd_tpu.server.distserver import pack_requests
     from etcd_tpu.wire import clientmsg
     from etcd_tpu.wire.requests import Request
@@ -178,7 +161,7 @@ def put_batch(slot, items, timeout=20):
     if WIRE == "binary":
         hdrs["Accept"] = clientmsg.CONTENT_TYPE
     req = urllib.request.Request(
-        (CLIENT if ROLES else PEERS)[slot] + "/mraft/propose_many",
+        PEERS[slot] + "/mraft/propose_many",
         data=pack_requests(reqs), method="POST", headers=hdrs)
     with urllib.request.urlopen(req, timeout=timeout) as r:
         data = r.read()
@@ -234,33 +217,13 @@ def harvest_flight(tag):
     ts = time.strftime("%Y%m%dT%H%M%SZ", time.gmtime())
     art = os.path.join(REPO, "trace_artifacts", f"chaos_{tag}_{ts}")
     urls = list(PEERS)
-    sup_urls = []
-    if ROLES:
-        # every role process is its own flight incarnation — harvest
-        # each port listed in the slot's roles.json (falling back to
-        # the shard-0 peer port if a supervisor died pre-write).
-        # The supervisor's merged-obs port (PR 17) carries no flight
-        # ring — it joins the timeseries/SLO harvest only.
-        urls = []
-        for s in range(3):
-            try:
-                with open(f"{BASE}/d{s}/roles.json") as f:
-                    info = json.load(f)
-                for name, r in sorted(info.items()):
-                    u = f"http://127.0.0.1:{r['port']}"
-                    if name == "supervisor":
-                        sup_urls.append(u)
-                    else:
-                        urls.append(u)
-            except Exception:
-                urls.append(PEERS[s])
     paths = harvest_rings(urls, art, timeout=5)
     if len(paths) < len(urls):
         print(f"flight harvest: {len(urls) - len(paths)} "
               f"process(es) unreachable — their SIGTERM/crash "
               f"dumps, if any, are under "
               f"{BASE}/d*/trace_artifacts/", flush=True)
-    obs_paths = harvest_obs_plane(urls + sup_urls, art)
+    obs_paths = harvest_obs_plane(urls, art)
     print("GATE FAILURE FORENSICS — flight dumps harvested "
           f"({len(paths)}/{len(urls)} processes):", flush=True)
     for p in paths:
@@ -743,21 +706,6 @@ def linz_drill(cycles: int) -> None:
 
 NEMESIS_KINDS = ("one_way_partition", "link_delay", "fsync_eio",
                  "nospace", "leader_kill", "overload")
-# Role mode (PR 15) swaps fsync_eio for role_kill: the fail-stop
-# exit is absorbed by the role supervisor (the shard respawns; the
-# HOST process the drill watches never exits), so the process-exit
-# gate cannot be expressed — role_kill covers the crash-recovery
-# surface at finer grain (one role process, not the whole node).
-ROLE_NEMESIS_KINDS = ("role_kill", "one_way_partition", "link_delay",
-                      "nospace", "role_kill", "leader_kill",
-                      "overload", "role_kill")
-
-
-def _role_choice(rng):
-    return rng.choice(("ingest", "worker")
-                      + tuple(f"shard{s}" for s in range(ROLES)))
-
-
 def _delay_params(rng, dur_lo=6.0):
     src = rng.randrange(3)
     return {"src": src, "dst": (src + 1 + rng.randrange(2)) % 3,
@@ -773,19 +721,6 @@ def plan_nemesis(seed: int, cycles: int, smoke: bool) -> list[list]:
     sub-faults) come from the seeded RNG.  Returns a list of cycles,
     each a list of op dicts."""
     rng = random.Random(seed)
-    if smoke and ROLES:
-        # one cycle that kills each role class once — ingest, the
-        # apply/watch worker, one serving shard — under live client
-        # load, then a delay window over the respawned tier
-        return [[
-            {"kind": "role_kill", "host": rng.randrange(3),
-             "role": "ingest"},
-            {"kind": "role_kill", "host": rng.randrange(3),
-             "role": "worker"},
-            {"kind": "role_kill", "host": rng.randrange(3),
-             "role": f"shard{rng.randrange(ROLES)}"},
-            dict(_delay_params(rng, dur_lo=4.0), kind="link_delay"),
-        ]]
     if smoke:
         # one short cycle: delay window + NOSPACE episode + an
         # overload burst composed with link delay (PR 12) + EIO
@@ -802,17 +737,14 @@ def plan_nemesis(seed: int, cycles: int, smoke: bool) -> list[list]:
                            kind="link_delay")},
             {"kind": "fsync_eio"},
         ]]
-    kinds = ROLE_NEMESIS_KINDS if ROLES else NEMESIS_KINDS
+    kinds = NEMESIS_KINDS
     plan = []
     for c in range(cycles):
         ops = []
         for k in (kinds[(2 * c) % len(kinds)],
                   kinds[(2 * c + 1) % len(kinds)]):
             op = {"kind": k}
-            if k == "role_kill":
-                op["host"] = rng.randrange(3)
-                op["role"] = _role_choice(rng)
-            elif k == "one_way_partition":
+            if k == "one_way_partition":
                 op["victim"] = rng.randrange(3)
                 op["dur"] = 8.0 + rng.randrange(5)
             elif k == "link_delay":
@@ -835,42 +767,22 @@ def plan_nemesis(seed: int, cycles: int, smoke: bool) -> list[list]:
     return plan
 
 
-def _fault_ports(slot):
-    """Peer ports carrying a slot's fault registry.  Single-process
-    mode: the node's one peer port.  Role mode: every serving shard
-    (shard s listens on the slot's peer port + 3*s) — the fault
-    points (wal.*, peerlink.*) all live in the shard tier, so a spec
-    arms uniformly across the slot's shards."""
-    base = int(PEERS[slot].rpartition(":")[2])
-    if not ROLES:
-        return [base]
-    return [base + 3 * s for s in range(ROLES)]
-
-
 def set_faults(slot, spec, seed=None, timeout=5):
-    body = json.dumps({"spec": spec, "seed": seed}).encode()
-    for port in _fault_ports(slot):
-        req = urllib.request.Request(
-            f"http://127.0.0.1:{port}/mraft/faults", data=body,
-            method="POST",
-            headers={"Content-Type": "application/json"})
-        with urllib.request.urlopen(req, timeout=timeout) as r:
-            out = json.loads(r.read())
-        assert out.get("ok"), out
+    req = urllib.request.Request(
+        PEERS[slot] + "/mraft/faults",
+        data=json.dumps({"spec": spec, "seed": seed}).encode(),
+        method="POST",
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=timeout) as r:
+        out = json.loads(r.read())
+    assert out.get("ok"), out
     return out
 
 
 def get_faults(slot, timeout=5):
-    out = {"injected": {}}
-    for port in _fault_ports(slot):
-        with urllib.request.urlopen(
-                f"http://127.0.0.1:{port}/mraft/faults",
-                timeout=timeout) as r:
-            d = json.loads(r.read())
-        for k, v in d.pop("injected", {}).items():
-            out["injected"][k] = out["injected"].get(k, 0) + v
-        out.update(d)
-    return out
+    with urllib.request.urlopen(PEERS[slot] + "/mraft/faults",
+                                timeout=timeout) as r:
+        return json.loads(r.read())
 
 
 def obs_gauge(snap, family):
@@ -890,8 +802,7 @@ def nemesis_drill(cycles: int, smoke: bool, check: bool) -> None:
           f"chaos_drill.py --nemesis {cycles} --seed {seed}"
           f"{' --smoke' if smoke else ''}"
           f"{' --check' if check else ''}"
-          f"{' --wire binary' if WIRE == 'binary' else ''}"
-          f"{f' --roles {ROLES}' if ROLES else ''})",
+          f"{' --wire binary' if WIRE == 'binary' else ''})",
           flush=True)
     print("NEMESIS PLAN: " + json.dumps(plan), flush=True)
     # replay determinism: the schedule is a pure function of the seed
@@ -923,7 +834,6 @@ def nemesis_drill(cycles: int, smoke: bool, check: bool) -> None:
     eio_results = []      # (victim, returncode, dump_ok)
     nospace_results = []  # (rejected_405, read_ok, recovered)
     overload_results = []  # (sub_kind, sheds, typed_bad, ok)
-    role_results = []     # (host, role, old_pid, new_pid)
 
     def client_loop(t):
         # writer-reader pair per key: a linearizable default GET may
@@ -1191,63 +1101,12 @@ def nemesis_drill(cycles: int, smoke: bool, check: bool) -> None:
               f"admitted, {burst['conn_fail']} conn failures",
               flush=True)
 
-    def op_role_kill(op):
-        # PR 15: kill ONE role process, not the node.  The
-        # supervisor must respawn it (fresh pid in roles.json, the
-        # same port serving again) while the host's OTHER roles keep
-        # serving — clients are NOT steered away, so the zero-stale /
-        # zero-lost invariants are enforced straight through the
-        # role restart.
-        v = op["host"]
-        role = op["role"]
-        rj = f"{BASE}/d{v}/roles.json"
-        with open(rj) as f:
-            info = json.load(f)
-        old_pid = info[role]["pid"]
-        port = info[role]["port"]
-        print(f"  nemesis: kill -9 role {role} on s{v} "
-              f"(pid {old_pid}, port {port})", flush=True)
-        os.kill(old_pid, signal.SIGKILL)
-        deadline = time.time() + 30
-        new_pid = None
-        while time.time() < deadline:
-            try:
-                with open(rj) as f:
-                    cur = json.load(f)[role]["pid"]
-                if cur != old_pid:
-                    new_pid = cur
-                    break
-            except Exception:
-                pass
-            time.sleep(0.3)
-        assert new_pid is not None, \
-            f"supervisor never respawned {role} on s{v}"
-        deadline = time.time() + 30
-        while True:
-            try:
-                with urllib.request.urlopen(
-                        f"http://127.0.0.1:{port}/mraft/obs",
-                        timeout=2):
-                    break
-            except urllib.error.HTTPError:
-                break  # listening (any HTTP answer counts)
-            except Exception:
-                assert time.time() < deadline, \
-                    (f"respawned {role} on s{v} never served port "
-                     f"{port}")
-                time.sleep(0.3)
-        role_results.append((v, role, old_pid, new_pid))
-        print(f"  nemesis: {role} on s{v} respawned "
-              f"pid {old_pid}->{new_pid}", flush=True)
-        wait_writable(45, who=f"post-{role}-kill cluster")
-
     OPS = {"one_way_partition": op_one_way_partition,
            "link_delay": op_link_delay,
            "fsync_eio": op_fsync_eio,
            "nospace": op_nospace,
            "leader_kill": op_leader_kill,
-           "overload": op_overload,
-           "role_kill": op_role_kill}
+           "overload": op_overload}
 
     try:
         time.sleep(22)
@@ -1343,13 +1202,6 @@ def nemesis_drill(cycles: int, smoke: bool, check: bool) -> None:
                 assert typed_bad == 0, \
                     (f"overload({sub}): {typed_bad} sheds missing "
                      f"the typed 429 vocabulary")
-            # PR 15: every planned role kill ended with a verified
-            # respawn (fresh pid, port serving) — op_role_kill only
-            # appends after the supervisor gate passed, so count
-            # equality IS the gate
-            n_rk = sum(1 for ops in plan for op in ops
-                       if op["kind"] == "role_kill")
-            assert len(role_results) == n_rk
             assert stats["acked"] > 0 and stats["reads_ok"] > 0
             # replay determinism, stated precisely: the plan is a
             # pure function of the seed (re-derived + compared at
@@ -1372,16 +1224,14 @@ def nemesis_drill(cycles: int, smoke: bool, check: bool) -> None:
               f"{len(nospace_results)} NOSPACE episode(s) "
               f"recovered, "
               f"{sum(r[1] for r in overload_results)} overload "
-              f"shed(s) across {len(overload_results)} burst(s), "
-              f"{len(role_results)} role respawn(s)",
+              f"shed(s) across {len(overload_results)} burst(s)",
               flush=True)
     except (AssertionError, RuntimeError):
         stop.set()
         print(f"NEMESIS GATE FAILURE — replay with: python "
               f"scripts/chaos_drill.py --nemesis {cycles} "
               f"--seed {seed}"
-              f"{' --wire binary' if WIRE == 'binary' else ''}"
-              f"{f' --roles {ROLES}' if ROLES else ''}",
+              f"{' --wire binary' if WIRE == 'binary' else ''}",
               flush=True)
         harvest_flight("nemesis")
         raise

@@ -53,44 +53,7 @@ def main() -> None:
                          "segment GC cadence; default 10000)")
     ap.add_argument("--bootstrap", action="store_true",
                     help="campaign for every group before READY")
-    ap.add_argument("--roles", type=int, default=0, metavar="S",
-                    help="role-split topology (PR 15): supervise an "
-                         "ingest + apply/watch worker + S serving "
-                         "shard processes instead of one in-process "
-                         "server (requires --client-port)")
-    ap.add_argument("--client-port", type=int, default=None,
-                    help="ingest client port (role mode only)")
     args = ap.parse_args()
-
-    if args.roles:
-        # compartmentalized serving: hand the whole slot to the role
-        # supervisor (its own module so each child re-execs into a
-        # clean process image); blocks until stopped
-        if args.client_port is None:
-            ap.error("--roles requires --client-port")
-        from etcd_tpu.server import roles
-
-        argv = ["--role", "supervise",
-                "--data-dir", args.data_dir,
-                "--slot", str(args.slot),
-                "--peers", args.peers,
-                "--client-port", str(args.client_port),
-                "--shards", str(args.roles),
-                "--groups", str(args.groups),
-                "--cap", str(args.cap),
-                "--max-batch-ents", str(args.max_batch_ents),
-                "--pipeline-depth", str(args.pipeline_depth),
-                "--coalesce-us", str(args.coalesce_us),
-                "--lease-ticks", str(args.lease_ticks),
-                "--flight-dir",
-                os.environ.get("ETCD_FLIGHT_DIR")
-                or os.path.join(args.data_dir, "trace_artifacts")]
-        if args.snap_count is not None:
-            argv += ["--snap-count", str(args.snap_count)]
-        if args.bootstrap:
-            argv.append("--bootstrap")
-        roles.main(argv)
-        return
 
     configure_compile_cache()
     srv = DistServer(args.data_dir, slot=args.slot,

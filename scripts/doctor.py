@@ -1,34 +1,25 @@
 """Cluster doctor: one CLI that turns the observability plane into
 a single human-readable health report (PR 17).
 
-Feed it supervisor merged-obs URLs (role-split topology, PR 15) or
-flat node URLs (single-process dist nodes) — it auto-detects which
-it got via ``GET /mraft/roles`` and harvests, per host:
+Feed it dist nodes' peer URLs; it harvests, per host:
 
-  - the merged/flat metrics snapshot (``/mraft/obs``) — role
-    liveness and the profiler's stage×domain sample attribution;
+  - the metrics snapshot (``/mraft/obs``) — the profiler's
+    stage×domain sample attribution;
   - the time-series ring (``/mraft/obs/timeseries``) — the last
     ~2 minutes of windowed deltas, pooled cross-host into the
     standard windowed row (acked/s and reads/s over 10 s, RTT p99
     over 60 s, shed rate);
   - the SLO verdict (``/mraft/obs/slo``) — merged worst-of across
     hosts with per-objective burn rates;
-  - the flight ring (``/mraft/obs/flight``, flat nodes only) —
-    span/frame counts plus cross-node clock offsets recovered by
+  - the flight ring (``/mraft/obs/flight``) — cross-node clock
+    offsets recovered by
     scripts/trace_stitch.py's NTP-style frame-quad alignment.
 
 A host that fails to answer is reported DOWN and skipped — the
-doctor never turns one dead process into a harvest error, same
-contract as the supervisor's merged exposition.
+doctor never turns one dead process into a harvest error.
 
   JAX_PLATFORMS=cpu python scripts/doctor.py URL [URL ...]
   JAX_PLATFORMS=cpu python scripts/doctor.py --json URL [URL ...]
-  JAX_PLATFORMS=cpu python scripts/doctor.py --smoke
-
-``--smoke`` spawns a 3-host role-split family (the dist_bench
-helpers), drives a small write load, runs the full harvest against
-the supervisors' merged planes, asserts roles are up with nonzero
-windowed rates and an SLO verdict, and prints DOCTOR SMOKE CLEAN.
 """
 
 from __future__ import annotations
@@ -37,8 +28,6 @@ import argparse
 import json
 import os
 import shutil
-import signal
-import subprocess
 import sys
 import tempfile
 import time
@@ -65,12 +54,6 @@ def harvest_host(base: str, timeout: float = 5.0) -> dict:
     """Everything one host's obs plane offers, each endpoint
     independently best-effort."""
     host: dict = {"url": base, "up": False}
-    try:
-        host["roles"] = _get_json(base + "/mraft/roles",
-                                  timeout)["roles"]
-        host["kind"] = "supervisor"
-    except Exception:
-        host["kind"] = "node"
     for key, sub in (("obs", "/mraft/obs"),
                      ("timeseries", "/mraft/obs/timeseries"),
                      ("slo", "/mraft/obs/slo")):
@@ -79,14 +62,11 @@ def harvest_host(base: str, timeout: float = 5.0) -> dict:
             host["up"] = True
         except Exception:
             pass
-    if host["kind"] == "node":
-        # flat nodes carry their own flight ring; supervisors don't
-        # (each role process owns its ring — harvest those directly)
-        try:
-            host["flight"] = _get_bytes(base + "/mraft/obs/flight",
-                                        timeout)
-        except Exception:
-            pass
+    try:
+        host["flight"] = _get_bytes(base + "/mraft/obs/flight",
+                                    timeout)
+    except Exception:
+        pass
     return host
 
 
@@ -109,25 +89,23 @@ def collect(urls: list[str], timeout: float = 5.0) -> dict:
 
 
 def profile_table(hosts: list[dict], top: int = 8) -> list[dict]:
-    """Top stage×domain×role rows off the always-on sampling
+    """Top stage×domain rows off the always-on sampling
     profiler's etcd_profile_samples_total — where the threads
     actually were, merged across every harvested host."""
     agg: dict[tuple, float] = {}
     for h in hosts:
         obs = h.get("obs") or {}
-        fams = obs.get("families", obs)  # merged vs flat shape
-        for s in (fams.get("etcd_profile_samples_total") or
+        for s in (obs.get("etcd_profile_samples_total") or
                   {}).get("samples", []):
             lb = s.get("labels", {})
-            k = (lb.get("stage", "-"), lb.get("domain", "-"),
-                 lb.get("role", "-"))
+            k = (lb.get("stage", "-"), lb.get("domain", "-"))
             agg[k] = agg.get(k, 0.0) + s.get("value", 0.0)
     total = sum(agg.values())
     rows = []
-    for (stage, domain, role), n in sorted(agg.items(),
-                                           key=lambda kv: -kv[1]):
+    for (stage, domain), n in sorted(agg.items(),
+                                     key=lambda kv: -kv[1]):
         rows.append({"stage": stage, "domain": domain,
-                     "role": role, "samples": int(n),
+                     "samples": int(n),
                      "share": round(n / total, 4) if total else 0.0})
     return rows[:top]
 
@@ -151,8 +129,8 @@ def clock_offsets(hosts: list[dict]) -> dict | None:
             paths.append(p)
         nodes = trace_stitch.load_dumps(paths)
         off = trace_stitch.align(nodes)
-        return {f"slot{slot}/{role}": round(v * 1e3, 3)
-                for (slot, role), v in sorted(off.items())}
+        return {f"slot{slot}": round(v * 1e3, 3)
+                for slot, v in sorted(off.items())}
     except Exception as e:
         return {"error": str(e)}
     finally:
@@ -169,13 +147,7 @@ def render(rep: dict) -> str:
     L.append(f"hosts: {up}/{len(rep['hosts'])} answering")
     for h in rep["hosts"]:
         mark = "up" if h["up"] else "DOWN"
-        L.append(f"  {h['url']} [{h['kind']}] {mark}")
-        for role, info in sorted((h.get("roles") or {}).items()):
-            alive = "up" if info.get("up") else "STALE"
-            extra = ""
-            if not info.get("up") and "stale_s" in info:
-                extra = f" ({info['stale_s']:.1f}s stale)"
-            L.append(f"    role {role:<12} {alive}{extra}")
+        L.append(f"  {h['url']} {mark}")
     w = rep.get("windowed")
     if w:
         L.append("windowed (time-series rings):")
@@ -195,11 +167,11 @@ def render(rep: dict) -> str:
                      f" (target {o['target']}, "
                      f"{o.get('samples', 0)} samples)")
     if rep.get("profile"):
-        L.append("profiler (top stage x domain x role by samples):")
+        L.append("profiler (top stage x domain by samples):")
         for r in rep["profile"]:
             L.append(f"  {r['share'] * 100:5.1f}%  "
                      f"stage={r['stage']} domain={r['domain']} "
-                     f"role={r['role']} ({r['samples']})")
+                     f"({r['samples']})")
     c = rep.get("clocks")
     if c:
         L.append("clock offsets vs reference (ms, flight-ring "
@@ -209,114 +181,17 @@ def render(rep: dict) -> str:
     return "\n".join(L)
 
 
-# -- smoke: spawn a role family and doctor it -------------------------------
-
-
-def smoke() -> None:
-    import http.client
-
-    import dist_bench as db
-    from etcd_tpu.server.distserver import pack_requests
-    from etcd_tpu.wire.requests import Request
-
-    m, shards = 3, 2
-    peer_base = db.free_port_block(m * shards)
-    client_base = db.free_port_block(3 * m)
-    urls = [f"http://127.0.0.1:{peer_base + i}" for i in range(m)]
-    tmp = tempfile.mkdtemp()
-    procs = [db.spawn_roles(tmp, s, urls, client_base + s, shards)
-             for s in range(m)]
-    try:
-        for p in procs:
-            db.wait_ready(p)
-        # drive a small write load so the rings and the SLO layer
-        # have something to window over
-        c = http.client.HTTPConnection("127.0.0.1", client_base,
-                                       timeout=60)
-        # warm until the shard leaders elect (verdicts are final,
-        # so the counted load only starts once a write acks)
-        for _ in range(200):
-            n, nerr = db._propose(c, pack_requests([Request(
-                method="PUT", id=(1 << 50) + 1,
-                path="/warm/k", val="v")]), "binary")
-            if n - nerr == 1:
-                break
-            time.sleep(0.1)
-        else:
-            raise AssertionError("role family never acked a write")
-        # fresh ids per batch until 200 ack — the warm write only
-        # proves ONE shard's leader; a batch spanning namespaces can
-        # land on a shard still electing, and verdicts are final.
-        # 200 over 90 s is load enough to window over: one
-        # sequential conn pays full round latency per batch (~2-7 s
-        # each on a busy 1-core host), and the smoke gates plumbing,
-        # not throughput
-        acked, nid, deadline = 0, 0, time.monotonic() + 90
-        while acked < 200 and time.monotonic() < deadline:
-            reqs = [Request(method="PUT", id=nid + j + 1,
-                            path=f"/d{(nid + j) % 16}/k", val="v")
-                    for j in range(50)]
-            nid += 50
-            n, nerr = db._propose(c, pack_requests(reqs), "binary")
-            acked += n - nerr
-            if nerr:
-                time.sleep(0.2)
-        c.close()
-        assert acked >= 200, acked
-        # let the 1 s scrape/step loops take at least two steps
-        time.sleep(2.5)
-
-        sup_urls = [f"http://127.0.0.1:{client_base + 2 * m + i}"
-                    for i in range(m)]
-        rep = collect(sup_urls)
-        print(render(rep), flush=True)
-
-        assert all(h["up"] and h["kind"] == "supervisor"
-                   for h in rep["hosts"]), rep["hosts"]
-        for h in rep["hosts"]:
-            roles = h["roles"]
-            for want in ("ingest", "worker", "shard0", "shard1",
-                         "supervisor"):
-                assert roles.get(want, {}).get("up"), (want, roles)
-        assert rep["windowed"]["acked_per_s_10s"] > 0, \
-            rep["windowed"]
-        assert rep["slo"]["verdict"] in ("ok", "burning"), \
-            rep["slo"]
-        assert "write_ack_p99" in rep["slo"]["objectives"], \
-            rep["slo"]
-        assert rep["profile"], "no profiler samples harvested"
-        print("DOCTOR SMOKE CLEAN", flush=True)
-    finally:
-        for p in procs:
-            try:
-                p.send_signal(signal.SIGTERM)
-            except OSError:
-                pass
-        for p in procs:
-            try:
-                p.wait(timeout=15)
-            except subprocess.TimeoutExpired:
-                p.kill()
-        shutil.rmtree(tmp, ignore_errors=True)
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("urls", nargs="*",
-                    help="supervisor merged-obs or flat node URLs")
+                    help="dist nodes' peer base URLs")
     ap.add_argument("--json", action="store_true",
                     help="emit the raw report dict instead of the "
                          "rendered text")
     ap.add_argument("--timeout", type=float, default=5.0)
-    ap.add_argument("--smoke", action="store_true",
-                    help="self-contained 3-host role-family check "
-                         "for scripts/test")
     args = ap.parse_args()
-    if args.smoke:
-        smoke()
-        return
     if not args.urls:
-        ap.error("need at least one URL (or --smoke)")
+        ap.error("need at least one URL")
     rep = collect(args.urls, timeout=args.timeout)
     if args.json:
         # flight bodies are bytes and huge — the JSON view carries

@@ -3,7 +3,7 @@
 Sweeps the streaming pipeline's chunk size over the host path —
 1 / 4 / 16 / 64 MiB — on a synthetic WAL stream, plus the unchunked
 fused pass as the reference point, and writes one JSON artifact to
-``bench_artifacts/replay_pipeline_<stamp>.json``.  This is the
+``trace_artifacts/replay_pipeline_<stamp>.json``.  This is the
 measurement behind ``wal/backend_policy.DEFAULT_CHUNK_BYTES``.
 
     python scripts/replay_bench.py [entries] [payload]
@@ -29,7 +29,7 @@ import numpy as np  # noqa: E402
 
 SWEEP_MIB = (1, 4, 16, 64)
 _ART_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "bench_artifacts")
+    os.path.abspath(__file__))), "trace_artifacts")
 
 
 def _gen(entries: int, payload: int):

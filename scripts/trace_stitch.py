@@ -1,13 +1,11 @@
 """Offline cross-node trace stitcher (PR 8).
 
 Merges flight-recorder dumps from several nodes (written by
-``GET /mraft/obs/flight`` harvests, SIGTERM crash dumps, or
-``dist_bench --smoke``'s per-run harvest), aligns their monotonic
-clocks, reconstructs per-proposal timelines and prints the per-stage
-wall breakdown plus the cluster CPU budget table — the evidence
-ROADMAP open item 2 (compartmentalized serving) needs: WHICH stage
-eats the core, and where a proposal's wall time actually goes
-(queue wait vs marshal vs network vs fsync vs apply).
+``GET /mraft/obs/flight`` harvests or SIGTERM crash dumps), aligns
+their monotonic clocks, reconstructs per-proposal timelines and
+prints the per-stage wall breakdown plus the cluster CPU budget
+table: WHICH stage eats the core, and where a proposal's wall time
+actually goes (queue wait vs marshal vs network vs fsync vs apply).
 
 Clock alignment: each node's events carry ITS monotonic clock.  For
 every traced frame the leader stamps send (socket write) and ack
@@ -26,8 +24,7 @@ Usage:
 
 A timeline is COMPLETE when every origin-side stage from ingest to
 client-ack is present AND at least one follower hop (send → recv →
-follower_fsync → resp → ack) stitched — the acceptance unit the
-dist_bench smoke asserts ≥ 100 of.
+follower_fsync → resp → ack) stitched.
 """
 
 from __future__ import annotations
@@ -75,57 +72,38 @@ def load_dumps(paths: list[str]) -> list[dict]:
     return nodes
 
 
-def _nid(n: dict) -> tuple[int, str]:
-    """Process identity for stitching: (slot, role).  Role-split
-    hosts (PR 15) contribute several rings per slot — ingest,
-    apply worker, one per serving shard — each its own incarnation
-    with its own clock base and seq counters.  Single-process dumps
-    carry the default role and collapse to plain per-slot identity."""
-    return (n["slot"], n.get("role", "server"))
-
-
-def _nid_s(nid: tuple[int, str]) -> str:
-    slot, role = nid
-    return str(slot) if role == "server" else f"{slot}/{role}"
-
-
 def _frame_quads(nodes: list[dict]) -> dict[tuple, list]:
-    """(sender_nid, receiver_nid) -> [(t_send, t_recv, t_resp,
-    t_ack), ...] joined on the frame's per-channel seq.  The role
-    rides in the join key: shard0 and shard1 processes both talk
-    slot->slot with independent seq counters, and mixing their
-    frames would fabricate clock quads."""
+    """(sender slot, receiver slot) -> [(t_send, t_recv, t_resp,
+    t_ack), ...] joined on the frame's per-channel seq."""
     send: dict[tuple, float] = {}
     ack: dict[tuple, float] = {}
     recv: dict[tuple, float] = {}
     resp: dict[tuple, float] = {}
     for n in nodes:
         slot = n["slot"]
-        role = n.get("role", "server")
         for e in n["events"]:
             if e["c"] != "frame":
                 continue
             if e["dir"] == "send":
-                send[(role, slot, e["peer"], e["seq"])] = e["t"]
+                send[(slot, e["peer"], e["seq"])] = e["t"]
             elif e["dir"] == "ack":
-                ack[(role, slot, e["peer"], e["seq"])] = e["t"]
+                ack[(slot, e["peer"], e["seq"])] = e["t"]
             elif e["dir"] == "recv":
-                recv[(role, e["src"], slot, e["seq"])] = e["t"]
+                recv[(e["src"], slot, e["seq"])] = e["t"]
             elif e["dir"] == "resp":
-                resp[(role, e["src"], slot, e["seq"])] = e["t"]
+                resp[(e["src"], slot, e["seq"])] = e["t"]
     quads: dict[tuple, list] = {}
     for key, t0 in send.items():
         t1, t2, t3 = recv.get(key), resp.get(key), ack.get(key)
         if t1 is None or t2 is None or t3 is None:
             continue
-        role, a, b, _seq = key
-        quads.setdefault(((a, role), (b, role)), []).append(
-            (t0, t1, t2, t3))
+        a, b, _seq = key
+        quads.setdefault((a, b), []).append((t0, t1, t2, t3))
     return quads
 
 
-def align(nodes: list[dict]) -> dict[tuple[int, str], float]:
-    """(slot, role) -> clock offset vs the reference node (subtract
+def align(nodes: list[dict]) -> dict[int, float]:
+    """slot -> clock offset vs the reference node (subtract
     it from a node's event times to land on the reference clock).
     The reference is the process with the most span events (normally
     the serving leader)."""
@@ -136,11 +114,12 @@ def align(nodes: list[dict]) -> dict[tuple[int, str], float]:
         ests = sorted(((t1 - t0) + (t2 - t3)) / 2
                       for t0, t1, t2, t3 in qs)
         pair_off[(a, b)] = ests[len(ests) // 2]
-    spans_per_nid: dict[tuple[int, str], int] = {}
+    spans_per_slot: dict[int, int] = {}
     for n in nodes:
-        spans_per_nid[_nid(n)] = spans_per_nid.get(_nid(n), 0) + sum(
-            1 for e in n["events"] if e["c"] == "span")
-    ref = max(spans_per_nid, key=spans_per_nid.get)
+        spans = sum(1 for e in n["events"] if e["c"] == "span")
+        spans_per_slot[n["slot"]] = \
+            spans_per_slot.get(n["slot"], 0) + spans
+    ref = max(spans_per_slot, key=spans_per_slot.get)
     off = {ref: 0.0}
     # BFS over the (undirected) pair graph
     frontier = [ref]
@@ -154,11 +133,11 @@ def align(nodes: list[dict]) -> dict[tuple[int, str], float]:
                 off[a] = off[b] - ab
                 frontier.append(a)
     for n in nodes:
-        if _nid(n) not in off:
+        if n["slot"] not in off:
             # no traced exchange with the aligned set: leave its
             # events out rather than stitch on a wild clock
-            print(f"trace_stitch: WARNING node {_nid_s(_nid(n))} "
-                  f"has no alignment path to {_nid_s(ref)}; "
+            print(f"trace_stitch: WARNING node {n['slot']} "
+                  f"has no alignment path to {ref}; "
                   f"skipping its events", file=sys.stderr)
     return off
 
@@ -174,21 +153,21 @@ def stitch(nodes: list[dict]) -> dict:
     unrelated proposals into one timeline.  We keep the incarnation
     with the newest wall anchor (the one that served last) and warn;
     stitch an earlier incarnation by passing only its files."""
-    by_nid: dict[tuple[int, str], dict] = {}
+    by_slot: dict[int, dict] = {}
     for n in nodes:
-        cur = by_nid.get(_nid(n))
+        cur = by_slot.get(n["slot"])
         if cur is None:
-            by_nid[_nid(n)] = n
+            by_slot[n["slot"]] = n
             continue
         newer, older = ((n, cur) if n.get("wall_anchor", 0)
                         >= cur.get("wall_anchor", 0) else (cur, n))
-        print(f"trace_stitch: WARNING node {_nid_s(_nid(n))} has "
+        print(f"trace_stitch: WARNING node {n['slot']} has "
               f"multiple incarnations; keeping {newer.get('_file')},"
               f" dropping {older.get('_file')}", file=sys.stderr)
-        by_nid[_nid(n)] = newer
-    nodes = list(by_nid.values())
+        by_slot[n["slot"]] = newer
+    nodes = list(by_slot.values())
     offsets = align(nodes)
-    aligned = [n for n in nodes if _nid(n) in offsets]
+    aligned = [n for n in nodes if n["slot"] in offsets]
 
     # per-(origin, trace) timeline: stage -> earliest aligned t
     timelines: dict[tuple[int, int], dict[str, float]] = {}
@@ -198,23 +177,19 @@ def stitch(nodes: list[dict]) -> dict:
         if stage not in tl or t < tl[stage]:
             tl[stage] = t
 
-    # frame events indexed per trace for the network hop legs; the
-    # recording process's role joins the key — co-hosted shard rings
-    # reuse (origin, trace) ids, and the same proposal IS recorded
-    # under the same role on every host it touches
+    # frame events indexed per trace for the network hop legs
     for n in aligned:
-        off = offsets[_nid(n)]
-        role = n.get("role", "server")
+        off = offsets[n["slot"]]
         for e in n["events"]:
             if e["c"] == "span":
-                note((role, e["origin"], e["trace"]), e["stage"],
+                note((e["origin"], e["trace"]), e["stage"],
                      e["t"] - off)
             elif e["c"] == "frame" and "traces" in e:
                 leg = {"send": "net_send", "recv": "net_recv"}.get(
                     e["dir"])
                 if leg:
                     for tid, org in e["traces"]:
-                        note((role, org, tid), leg, e["t"] - off)
+                        note((org, tid), leg, e["t"] - off)
 
     complete = []
     partial = 0
@@ -257,8 +232,8 @@ def stitch(nodes: list[dict]) -> dict:
     # must count ONCE, not once per co-hosted node.
     budget: dict[str, dict[str, float]] = {}
     seen_pids: set = set()
-    # budget sums need no clock alignment — include processes (e.g.
-    # the ingest/worker roles) that never exchange traced frames
+    # budget sums need no clock alignment — include processes that
+    # never exchanged traced frames
     for n in nodes:
         pid = n.get("pid")
         if pid and pid in seen_pids:
@@ -276,14 +251,10 @@ def stitch(nodes: list[dict]) -> dict:
         for k in ("wall_s", "cpu_s", "device_s"):
             row[k] = round(row[k], 4)
 
-    plain = all(n.get("role", "server") == "server" for n in aligned)
     return {
-        # back-compat: all-default-role reports keep bare slot ints;
-        # role-split reports name each process "slot/role"
-        "nodes": (sorted(n["slot"] for n in aligned) if plain
-                  else sorted(_nid_s(_nid(n)) for n in aligned)),
-        "offsets_s": {_nid_s(nid): round(o, 6)
-                      for nid, o in sorted(offsets.items())},
+        "nodes": sorted(n["slot"] for n in aligned),
+        "offsets_s": {str(slot): round(o, 6)
+                      for slot, o in sorted(offsets.items())},
         "traces": len(timelines),
         "complete": len(complete),
         "partial": partial,

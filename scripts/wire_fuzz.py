@@ -6,7 +6,7 @@
     scripts/wire_fuzz.py --check       >= 100k mutated frames per
                                        format (the acceptance gate)
     scripts/wire_fuzz.py --frames N    explicit per-format budget
-    scripts/wire_fuzz.py --formats dgb2,srg1   restrict formats
+    scripts/wire_fuzz.py --formats dgb2,gpb1   restrict formats
     scripts/wire_fuzz.py --seed N      rng seed (default 20190814)
 
 The declarative schemas (etcd_tpu/wire/schema.py) drive the
@@ -27,10 +27,6 @@ UnicodeDecodeError, MemoryError — is a crasher: it is persisted to
 ``tests/fixtures/wire_crashers/<fmt>/`` as a regression fixture
 (replayed at the start of every run and by tests/test_wire_fuzz.py)
 and the run exits nonzero.
-
-SRG1 is fuzzed as a whole ring image via ``ShmRing.from_buffer``: a
-mutated header must fail typed on attach or the consumer must drain
-via its resync-never-raise contract.
 """
 
 from __future__ import annotations
@@ -47,9 +43,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import numpy as np  # noqa: E402
 
-from etcd_tpu.server.shmring import ShmRing  # noqa: E402
-from etcd_tpu.store.event import Event, NodeExtern  # noqa: E402
-from etcd_tpu.wire import clientmsg, distmsg, proto, rolemsg  # noqa: E402
+from etcd_tpu.wire import clientmsg, distmsg, proto  # noqa: E402
 from etcd_tpu.wire import schema as wschema  # noqa: E402
 from etcd_tpu.wire.requests import Info, Request  # noqa: E402
 from etcd_tpu.wire.schema import FrameError  # noqa: E402
@@ -119,65 +113,6 @@ def _dcb1_seeds():
     return [(_dcb1_parse, bytes(f)) for f in (req, resp, prop)]
 
 
-def _drh1_parse(data):
-    for fn in (rolemsg.unpack_fwd_request, rolemsg.unpack_fwd_acks,
-               rolemsg.unpack_fwd_vals, rolemsg.unpack_fwd_response,
-               rolemsg.unpack_commit):
-        try:
-            fn(data)
-        except FrameError:
-            pass
-    rolemsg.unpack_fwd_request(data)
-
-
-def _drh1_seeds():
-    req = rolemsg.pack_fwd_request(
-        [Request(method="PUT", path="/k", val="v").marshal(),
-         Request(method="GET", path="/q").marshal()],
-        [0, rolemsg.OP_SERIALIZABLE], rolemsg.REPLY_VALS)
-    acks = rolemsg.pack_fwd_acks(2, {0: (100, "Key not found")})
-    vals = rolemsg.pack_fwd_vals(["leaf", None, b"x"],
-                                 {1: (100, "missing")})
-    ev = Event(action="set",
-               node=NodeExtern(key="/k", value="v",
-                               modified_index=3, created_index=3),
-               etcd_index=9)
-    resp = rolemsg.pack_fwd_response([ev, RuntimeError("boom")])
-    commit = rolemsg.pack_commit(
-        7, [(0, 5, b"p1"), (1, 6, b""), (0, 6, b"zz")])
-    return [(_drh1_parse, bytes(f))
-            for f in (req, acks, vals, resp, commit)]
-
-
-def _srg1_image() -> bytes:
-    cap = 192
-    buf = bytearray(wschema.SRG1.header_size + cap)
-    struct.pack_into("<I", buf, wschema.SRG1.offsets["magic"],
-                     wschema.SRG1.magic)
-    struct.pack_into("<Q", buf, wschema.SRG1.offsets["capacity"],
-                     cap)
-    ring = ShmRing.from_buffer(buf, "fuzz-seed")
-    ring.bump_generation()
-    for payload in (b"hello", b"x" * 60, b"", b"tail-record"):
-        ring.push(payload)
-    ring.pop()  # cursors mid-ring, wrap marker territory ahead
-    ring.push(b"y" * 80)
-    return bytes(buf)
-
-
-def _srg1_parse(data):
-    # attach must fail typed on a corrupt header; a consumer on a
-    # corrupt-but-attachable ring drains via resync, never raises
-    ring = ShmRing.from_buffer(bytearray(data), "fuzz")
-    for _ in range(64):
-        if ring.pop() is None:
-            break
-
-
-def _srg1_seeds():
-    return [(_srg1_parse, _srg1_image())]
-
-
 def _gpb1_seeds():
     ent = proto.Entry(type=1, term=2, index=3, data=b"payload")
     snap = proto.Snapshot(data=b"sd", nodes=[1, 2], index=9,
@@ -207,8 +142,6 @@ def _gpb1_seeds():
 FORMATS = {
     "dgb2": (wschema.DGB2, _dgb2_seeds),
     "dcb1": (wschema.DCB1, _dcb1_seeds),
-    "drh1": (wschema.DRH1, _drh1_seeds),
-    "srg1": (wschema.SRG1, _srg1_seeds),
     "gpb1": (wschema.GPB1, _gpb1_seeds),
 }
 
@@ -307,18 +240,6 @@ def _field_mutations(sch, seed: bytes):
             yield bytes(m)
 
 
-def _srg1_header_mutations(sch, seed: bytes):
-    """SRG1 has no packed header struct — hammer every declared
-    fixed-offset field instead (cursors, capacity, magic)."""
-    for field, off in sch.offsets.items():
-        width = 4 if field in ("magic", "generation") else 8
-        for v in EXTREMES:
-            m = bytearray(seed)
-            struct.pack_into("<I" if width == 4 else "<Q", m, off,
-                             v & ((1 << (8 * width)) - 1))
-            yield bytes(m)
-
-
 def fuzz_format(fmt: str, budget: int, rng: random.Random,
                 verbose: bool = True) -> tuple[int, list[str]]:
     sch, make_seeds = FORMATS[fmt]
@@ -347,9 +268,6 @@ def fuzz_format(fmt: str, budget: int, rng: random.Random,
             run(parser, m)
         for m in _field_mutations(sch, seed):
             run(parser, m)
-        if fmt == "srg1":
-            for m in _srg1_header_mutations(sch, seed):
-                run(parser, m)
 
     # randomized remainder: byte flips + aligned signed extremes
     while count < budget:
@@ -388,7 +306,7 @@ def main() -> int:
                     help="explicit per-format frame budget")
     ap.add_argument("--formats", default="",
                     help="comma-separated subset "
-                         "(dgb2,dcb1,drh1,srg1,gpb1)")
+                         "(dgb2,dcb1,gpb1)")
     ap.add_argument("--seed", type=int, default=20190814)
     args = ap.parse_args()
 

@@ -1672,34 +1672,30 @@ def test_ownership_guard_lock_escape(tmp_path):
     assert "without its guard lock State.lk" in findings[0].message
 
 
-def test_ownership_annotations_pin_real_server_state():
+@pytest.mark.parametrize("domain,rel,members", [
+    ("frontdoor-loop", "etcd_tpu/server/frontdoor.py",
+     {"mode", "rbuf", "out", "watchers", "deadline_at"}),
+    ("distpipe-state", "etcd_tpu/server/distpipe.py",
+     {"register", "ack", "bump_epoch"}),
+])
+def test_ownership_annotations_pin_real_server_state(domain, rel,
+                                                     members):
     """Drift guard: the in-tree ``# owner:`` annotations must keep
-    naming the attributes/methods the PR-15/16 ownership story is
-    about — silently dropping one would hollow out the checker
-    without failing any fixture."""
+    naming the attributes/methods the ownership story is about —
+    silently dropping one would hollow out the checker without
+    failing any fixture."""
     import re
 
     owner_re = re.compile(
         r"(?:self\.(\w+)\s*[:=]|def\s+(\w+)\().*#\s*owner:\s*(\S+)")
     tagged: dict[str, set[str]] = {}
-    for rel in ("etcd_tpu/server/frontdoor.py",
-                "etcd_tpu/server/shmring.py",
-                "etcd_tpu/server/distpipe.py",
-                "etcd_tpu/server/roles.py"):
-        with open(os.path.join(REPO, rel)) as fh:
-            for ln in fh:
-                m = owner_re.search(ln)
-                if m:
-                    tagged.setdefault(m.group(3), set()).add(
-                        m.group(1) or m.group(2))
-    assert {"mode", "rbuf", "out", "watchers",
-            "deadline_at"} <= tagged.get("frontdoor-loop", set())
-    assert {"push", "bump_generation"} <= tagged.get(
-        "shmring-producer", set())
-    assert {"pop", "_peek"} <= tagged.get("shmring-consumer", set())
-    assert {"register", "ack", "bump_epoch"} <= tagged.get(
-        "distpipe-state", set())
-    assert "_hiwat" in tagged.get("ingest-lanes", set())
+    with open(os.path.join(REPO, rel)) as fh:
+        for ln in fh:
+            m = owner_re.search(ln)
+            if m:
+                tagged.setdefault(m.group(3), set()).add(
+                    m.group(1) or m.group(2))
+    assert members <= tagged.get(domain, set())
     # and every tagged domain is registered (checker enforces it on
     # the tree; this keeps the registry and annotations honest even
     # if the checker is ever detuned)
@@ -1833,42 +1829,39 @@ def test_wirebounds_closes_the_bound_vocabulary(tmp_path):
 def test_wirebounds_fires_on_missing_plausibility_cap(tmp_path):
     from etcd_tpu.analysis import WireBoundsChecker
 
-    # a partial shmring at the real relpath is held to the REAL SRG1
-    # schema: srg1.capacity must be capped in ShmRing._attach and
-    # srg1.record_len somewhere in the module
-    root = _fixture_root(tmp_path, "etcd_tpu/server/shmring.py", """
+    # a partial distmsg at the real relpath is held to the REAL DGB2
+    # schema: dgb2.groups and dgb2.ents_per_lane must be capped in
+    # parse_header
+    root = _fixture_root(tmp_path, "etcd_tpu/wire/distmsg.py", """
         import struct
-        from ..wire.schema import FrameError
+        from .schema import FrameError
 
-        class ShmRing:
-            def _attach(self, buf):
-                if len(buf) < 64:
-                    raise FrameError("short segment")
-                (cap,) = struct.unpack_from("<Q", buf, 32)
-                self.capacity = cap
+        def parse_header(data):
+            if len(data) < 24:
+                raise FrameError("short frame")
+            g, e = struct.unpack_from("<II", data, 8)
+            return g, e
         """)
     findings = run_checkers(root, [WireBoundsChecker()])
     assert _rules(findings) == {"missing-plausibility-cap"}
-    assert {f.detail for f in findings} == {"srg1.capacity",
-                                            "srg1.record_len"}
+    assert {f.detail for f in findings} == {"dgb2.groups",
+                                            "dgb2.ents_per_lane"}
 
 
 def test_wirebounds_quiet_when_caps_enforced(tmp_path):
     from etcd_tpu.analysis import WireBoundsChecker
 
-    root = _fixture_root(tmp_path, "etcd_tpu/server/shmring.py", """
+    root = _fixture_root(tmp_path, "etcd_tpu/wire/distmsg.py", """
         import struct
-        from ..wire.schema import BOUNDS, FrameError, check_bound
+        from .schema import FrameError, check_bound
 
-        _REC_CAP = BOUNDS["srg1.record_len"]
-
-        class ShmRing:
-            def _attach(self, buf):
-                if len(buf) < 64:
-                    raise FrameError("short segment")
-                (cap,) = struct.unpack_from("<Q", buf, 32)
-                check_bound("srg1.capacity", cap)
-                self.capacity = cap
+        def parse_header(data):
+            if len(data) < 24:
+                raise FrameError("short frame")
+            g, e = struct.unpack_from("<II", data, 8)
+            check_bound("dgb2.groups", g)
+            check_bound("dgb2.ents_per_lane", e)
+            return g, e
         """)
     assert not run_checkers(root, [WireBoundsChecker()])
 
@@ -2077,87 +2070,66 @@ def test_schemadrift_fires_on_proto_field_divergence(tmp_path):
 # -- 21. the schemas pin the real modules (PR 19) -----------------------------
 
 
-def test_wire_schema_matches_real_modules():
+@pytest.mark.parametrize("name", ["DGB2", "DCB1", "GPB1"])
+def test_wire_schema_matches_real_modules(name):
     """Drift guard in the OTHER direction: the declarative schemas
     (wire/schema.py) must describe the code that actually ships —
-    struct formats, magics, kind values, flag bits, SRG1 offsets,
-    and section/field names that exist on the real dataclasses."""
+    struct formats, magics, kind values, flag bits, and
+    section/field names that exist on the real dataclasses."""
     import dataclasses
     import struct as pystruct
 
-    from etcd_tpu.server import shmring
-    from etcd_tpu.wire import clientmsg, distmsg, proto, rolemsg
-    from etcd_tpu.wire import schema
+    from etcd_tpu.wire import clientmsg, distmsg, proto, schema
 
-    # header formats and magics are what the modules actually use
-    assert distmsg._HDR.format == schema.DGB2.header
-    assert clientmsg._HDR.format == schema.DCB1.header
-    assert rolemsg._HDR.format == schema.DRH1.header
-    assert distmsg._MAGIC == schema.DGB2.magic
-    assert clientmsg._MAGIC == schema.DCB1.magic
-    assert rolemsg._MAGIC == schema.DRH1.magic
-    assert shmring._MAGIC == schema.SRG1.magic
+    (sch,) = [f for f in schema.FORMATS if f.name == name]
+    mod = {"DGB2": distmsg, "DCB1": clientmsg, "GPB1": proto}[name]
+    assert sch.module == "etcd_tpu/wire/%s.py" \
+        % mod.__name__.rpartition(".")[2]
 
-    # kind values and flag bits equal the module constants
-    for mod, sch in ((distmsg, schema.DGB2), (clientmsg, schema.DCB1),
-                     (rolemsg, schema.DRH1)):
-        for kind in sch.kinds:
-            assert getattr(mod, kind.name) == kind.value, kind.name
-        for flag in sch.flags:
-            assert getattr(mod, flag.name) == flag.bit, flag.name
-
-    # the struct catalog round-trips through the modules
-    assert distmsg._TRACE_ENT.format == schema.DGB2.structs["_TRACE_ENT"]
-    assert clientmsg._ERR.format == schema.DCB1.structs["_ERR"]
-    assert rolemsg._ERR.format == schema.DRH1.structs["_ERR"]
-    assert rolemsg._EVT.format == schema.DRH1.structs["_EVT"]
-
-    # SRG1 fixed offsets are the shmring's real field offsets
-    assert shmring._HDR_SIZE == schema.SRG1.header_size
-    for field, off in (("magic", shmring._OFF_MAGIC),
-                       ("generation", shmring._OFF_GEN),
-                       ("head", shmring._OFF_HEAD),
-                       ("tail", shmring._OFF_TAIL),
-                       ("dropped", shmring._OFF_DROPPED),
-                       ("capacity", shmring._OFF_CAP)):
-        assert schema.SRG1.offsets[field] == off, field
-
-    # header_offsets() tiles the whole packed header exactly
-    for sch in (schema.DGB2, schema.DCB1, schema.DRH1):
+    if sch.header:
+        # header format and magic are what the module actually uses
+        assert mod._HDR.format == sch.header
+        assert mod._MAGIC == sch.magic
+        # header_offsets() tiles the whole packed header exactly
         offs = sch.header_offsets()
         assert set(offs) == set(sch.header_fields)
         assert sum(w for _o, w, _s in offs.values()) \
             == pystruct.calcsize(sch.header)
         for cf in sch.count_fields:
             assert cf in offs, cf
+    # kind values and flag bits equal the module constants
+    for kind in sch.kinds:
+        assert getattr(mod, kind.name) == kind.value, kind.name
+    for flag in sch.flags:
+        assert getattr(mod, flag.name) == flag.bit, flag.name
+    # the struct catalog round-trips through the module
+    for const, fmt in sch.structs.items():
+        assert getattr(mod, const).format == fmt, const
 
-    # DGB2 section names name real dataclass fields ("lens" is the
+    # section names name real dataclass fields ("lens" is the
     # derived payload length table, the one non-attribute section)
-    for kind in schema.DGB2.kinds:
+    for kind in sch.kinds:
         if not kind.cls:
             continue
-        cls = getattr(distmsg, kind.cls)
-        fields = {f.name for f in dataclasses.fields(cls)}
+        fields = {f.name for f in dataclasses.fields(
+            getattr(mod, kind.cls))}
         for s in kind.sections:
             assert s.name in fields | {"lens"}, \
                 f"{kind.cls}.{s.name}"
 
-    # GPB1 field names are real attributes of the real messages
-    for msg in schema.GPB1.messages:
-        cls = getattr(proto, msg.cls, None) or {
-            "Entry": proto.Entry}[msg.cls]
+    # message field names are real attributes of the real messages
+    for msg in sch.messages:
+        cls = getattr(mod, msg.cls)
         names = {f.name for f in dataclasses.fields(cls)} \
             if dataclasses.is_dataclass(cls) else set(cls.__slots__)
         for f in msg.fields:
             assert f.name in names, f"{msg.cls}.{f.name}"
 
     # every declared bound cap is positive and every flag scope /
-    # bound scope that is non-empty appears in parse_scopes
-    for sch in schema.FORMATS:
-        for b in sch.bounds:
-            assert b.cap > 0
-            if b.scope:
-                assert b.scope in sch.parse_scopes, b.name
-        for fl in sch.flags:
-            if fl.scope:
-                assert fl.scope in sch.parse_scopes, fl.name
+    # bound scope appears in parse_scopes
+    assert sch.bounds
+    for b in sch.bounds:
+        assert b.cap > 0
+        assert b.scope in sch.parse_scopes, b.name
+    for fl in sch.flags:
+        assert fl.scope in sch.parse_scopes, fl.name

@@ -255,14 +255,32 @@ def test_local_and_worker_reads_bill_the_same_read_path(cohosted):
         n0 = n1
 
 
-def test_servers_whose_reads_may_wait_have_no_seam():
-    from etcd_tpu.server import roles
-    from etcd_tpu.server.distserver import DistServer
-    from etcd_tpu.server.server import EtcdServer
+@pytest.mark.parametrize("module,cls,seam", [
+    ("distserver", "DistServer", False),
+    ("server", "EtcdServer", False),
+    ("multigroup", "MultiGroupServer", True),
+])
+def test_only_the_server_whose_get_cannot_wait_has_the_seam(
+        module, cls, seam):
+    """The front door asks for ``do_local`` by name: a server whose
+    default GET may wait (lease, ReadIndex, the raft loop) must not
+    grow one, and the co-hosted server must keep it."""
+    import importlib
 
-    for cls in (DistServer, EtcdServer, roles.RemoteEtcd,
-                roles.WorkerEtcd):
-        assert not hasattr(cls, "do_local"), cls
+    server = getattr(importlib.import_module(
+        f"etcd_tpu.server.{module}"), cls)
+    assert hasattr(server, "do_local") is seam
+
+
+@pytest.mark.parametrize("hook", [
+    {"extra_routes": {}},
+    {"watch_redirect": "http://127.0.0.1:1"},
+])
+def test_the_front_door_takes_no_routes_of_a_callers(cohosted, hook):
+    """The paths a front door serves are the ones in its source: a
+    caller cannot hang a handler on it or send its watches away."""
+    with pytest.raises(TypeError):
+        FrontDoor(cohosted["server"], "127.0.0.1", 0, **hook)
 
 
 def test_every_get_to_a_dist_server_goes_to_a_worker(tmp_path):

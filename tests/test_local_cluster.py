@@ -245,7 +245,6 @@ def test_the_quorum_is_real(tmp_path):
     (["--dist-local-cluster", "3", "--dist-slot", "0"], "--dist-slot"),
     (["--dist-local-cluster", "3", "--dist-peers",
       "http://127.0.0.1:1,http://127.0.0.1:2"], "--dist-peers"),
-    (["--dist-local-cluster", "3", "--dist-roles", "2"], "--dist-roles"),
     (["--dist-local-cluster", "1"], "at least 2"),
     (["--dist-local-cluster", "3", "--dist-election-ticks", "2"],
      "--dist-election-ticks"),
@@ -255,6 +254,22 @@ def test_flag_refuses_the_mix_and_the_senseless(argv, why, caplog,
     with caplog.at_level("ERROR", logger="etcd_tpu.cli"):
         assert cli.main(argv + ["--data-dir", str(tmp_path / "d")]) == 1
     assert why in caplog.text
+    assert not os.path.exists(tmp_path / "d")
+
+
+@pytest.mark.parametrize("argv", [
+    ["--dist-local-cluster", "3", "--dist-roles", "2"],
+    ["--dist-slot", "0", "--dist-peers",
+     "http://127.0.0.1:1,http://127.0.0.1:2,http://127.0.0.1:3",
+     "--dist-roles", "2"],
+], ids=["local-cluster", "one-slot"])
+def test_the_parser_knows_no_role_split(argv, capsys, tmp_path):
+    """One member is one process: a flag that asks for a process
+    tree a slot is refused where every unknown flag is."""
+    with pytest.raises(SystemExit) as e:
+        cli.main(argv + ["--data-dir", str(tmp_path / "d")])
+    assert e.value.code == 2
+    assert "--dist-roles" in capsys.readouterr().err
     assert not os.path.exists(tmp_path / "d")
 
 

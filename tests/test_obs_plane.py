@@ -1,20 +1,13 @@
 """Cluster observability plane (PR 17): time-series ring deltas and
-windowed queries, SLO burn-rate math, sampling-profiler attribution,
-cross-role aggregation (monotone across respawn, stale-marked never
-erroring), and the merged Prometheus exposition's 0.0.4 conformance
-with the injected ``role`` label."""
+windowed queries, SLO burn-rate math, sampling-profiler
+attribution."""
 
-import json
-import re
 import threading
 import time
-import urllib.request
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import pytest
 
-from etcd_tpu.obs import exporter, profiler, slo, timeseries
-from etcd_tpu.obs.aggregate import MetricsAggregator
+from etcd_tpu.obs import profiler, slo, timeseries
 from etcd_tpu.obs.metrics import CATALOG, Registry
 
 # -- 1. time-series ring: deltas, retention, restart, queries ---------------
@@ -234,226 +227,3 @@ def test_profiler_domain_roots_speak_ownership_vocabulary():
     assert set(roots.values()) <= set(DOMAINS)
     # a known owner root resolves to its domain
     assert roots[("frontdoor.py", "_run")] == "frontdoor-loop"
-
-
-# -- 4. cross-role aggregation ----------------------------------------------
-
-
-def _reg_snap(value):
-    reg = Registry()
-    reg.counter("etcd_wal_append_entries_total").inc(value)
-    return reg.snapshot()
-
-
-def test_aggregator_monotone_across_respawn():
-    agg = MetricsAggregator()
-    agg.observe("shard0", _reg_snap(5), t=0.0)
-    # same incarnation scraped again at a higher value: no fold
-    agg.observe("shard0", _reg_snap(6), t=1.0)
-    # respawn: cumulative drops to 2 -> previous final (6) folds in
-    agg.observe("shard0", _reg_snap(2), t=2.0)
-    fams = agg.merged_families(now=2.0)
-    s, = fams["etcd_wal_append_entries_total"]["samples"]
-    assert s["labels"] == {"role": "shard0"}
-    assert s["value"] == 8.0  # 6 + 2, monotone, no double-count
-    agg.observe("shard0", _reg_snap(3), t=3.0)
-    s, = agg.merged_families(
-        now=3.0)["etcd_wal_append_entries_total"]["samples"]
-    assert s["value"] == 9.0
-
-
-def test_aggregator_histogram_fold_and_estimated_percentiles():
-    agg = MetricsAggregator()
-
-    def snap(vals):
-        reg = Registry()
-        h = reg.histogram("etcd_ack_rtt_seconds")
-        for v in vals:
-            h.observe(v)
-        return reg.snapshot()
-
-    agg.observe("ingest", snap([0.004] * 50), t=0.0)
-    agg.observe("ingest", snap([0.004] * 20), t=1.0)  # respawned
-    s, = agg.merged_families(
-        now=1.0)["etcd_ack_rtt_seconds"]["samples"]
-    assert s["count"] == 70
-    assert s["sum"] == pytest.approx(0.28)
-    assert s["estimator"] == "bucket-le-upper-bound"
-    bounds = list(CATALOG["etcd_ack_rtt_seconds"].buckets)
-    assert s["p99"] == min(b for b in bounds if b >= 0.004)
-
-
-def test_aggregator_stale_marking_never_errors():
-    agg = MetricsAggregator(stale_after=5.0)
-    agg.observe("worker", _reg_snap(4), t=1.0)
-    agg.scrape_failed("worker")
-    roles = agg.roles(now=11.0)  # last good scrape 10 s ago
-    assert roles["worker"]["up"] is False
-    assert roles["worker"]["stale_s"] == pytest.approx(10.0)
-    assert roles["worker"]["errors"] == 1
-    # the last-known samples stay served, with liveness at 0
-    fams = agg.merged_families(now=11.0)
-    s, = fams["etcd_wal_append_entries_total"]["samples"]
-    assert s["value"] == 4.0
-    up, = fams["etcd_role_up"]["samples"]
-    assert up == {"labels": {"role": "worker"}, "value": 0.0}
-
-
-# -- 5. merged exposition conformance ---------------------------------------
-
-_NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
-
-
-def test_merged_exposition_keeps_0_0_4_conformance_with_role():
-    agg = MetricsAggregator()
-    reg = Registry()
-    reg.counter("etcd_wal_append_entries_total").inc(3)
-    reg.histogram("etcd_wal_fsync_seconds").observe(0.004)
-    agg.observe("shard0", reg.snapshot(), t=1.0)
-    agg.observe("worker", _reg_snap(2), t=1.0)
-    text = exporter.render_prometheus_snapshot(
-        agg.merged_families(now=1.0)).decode()
-    types = dict(re.findall(r"# TYPE (\S+) (\S+)", text))
-    # the merged view announces every catalog family, like the
-    # per-process exposition (test_obs.py contract)
-    assert set(types) == set(CATALOG)
-    for name, kind in types.items():
-        assert _NAME_RE.match(name)
-        assert kind in ("counter", "gauge", "histogram")
-    # every sample carries its source role
-    assert ('etcd_wal_append_entries_total{role="shard0"} 3'
-            in text)
-    assert ('etcd_wal_append_entries_total{role="worker"} 2'
-            in text)
-    assert 'etcd_role_up{role="shard0"} 1' in text
-    # histogram structure survives the merge: cumulative buckets,
-    # +Inf terminal, sum/count, role on every series
-    assert ('etcd_wal_fsync_seconds_bucket{role="shard0",'
-            'le="0.005"} 1' in text)
-    assert ('etcd_wal_fsync_seconds_bucket{role="shard0",'
-            'le="+Inf"} 1' in text)
-    assert 'etcd_wal_fsync_seconds_count{role="shard0"} 1' in text
-    cums = [int(m) for m in re.findall(
-        r'etcd_wal_fsync_seconds_bucket\{[^}]*\} (\d+)', text)]
-    assert cums == sorted(cums)
-    sample_re = re.compile(
-        r"^[a-zA-Z_:][a-zA-Z0-9_:]*(\{.*\})? \S+$")
-    for line in text.splitlines():
-        assert line.startswith("#") or sample_re.match(line), line
-
-
-# -- 6. live supervisor plane across role death -----------------------------
-
-
-class _FakeRole:
-    """A stand-in role process: serves its registry's snapshot at
-    /mraft/obs like every real role port does."""
-
-    def __init__(self):
-        self.reg = Registry()
-        fake = self
-
-        class H(BaseHTTPRequestHandler):
-            def log_message(self, *a):
-                pass
-
-            def do_GET(self):
-                body = fake.reg.snapshot_json()
-                self.send_response(200)
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
-
-        self.httpd = ThreadingHTTPServer(("127.0.0.1", 0), H)
-        self.port = self.httpd.server_address[1]
-        threading.Thread(target=self.httpd.serve_forever,
-                         daemon=True).start()
-
-    def stop(self):
-        self.httpd.shutdown()
-        self.httpd.server_close()
-
-
-def test_supervisor_obs_aggregates_across_role_death():
-    from etcd_tpu.server.roles import SupervisorObs
-
-    a, b = _FakeRole(), _FakeRole()
-    a.reg.counter("etcd_wal_append_entries_total").inc(5)
-    b.reg.counter("etcd_wal_append_entries_total").inc(11)
-    sup = SupervisorObs({"ingest": a.port, "worker": b.port},
-                        port=0, interval=0.05, stale_after=0.4)
-    sup._httpd = ThreadingHTTPServer(("127.0.0.1", 0),
-                                     sup._make_handler())
-    sup.port = sup._httpd.server_address[1]
-    threading.Thread(target=sup._httpd.serve_forever,
-                     daemon=True).start()
-    base = f"http://127.0.0.1:{sup.port}"
-
-    def merged():
-        with urllib.request.urlopen(base + "/mraft/obs",
-                                    timeout=5) as r:
-            assert r.status == 200
-            return json.loads(r.read())
-
-    try:
-        sup.scrape_once()
-        m = merged()
-        vals = {s["labels"]["role"]: s["value"]
-                for s in m["families"][
-                    "etcd_wal_append_entries_total"]["samples"]}
-        assert vals == {"ingest": 5.0, "worker": 11.0}
-        assert m["roles"]["ingest"]["up"]
-
-        # kill the worker: scrapes fail, but the merged endpoint
-        # still answers 200 with the last-known samples, stale-
-        # marked — never a scrape error
-        b.stop()
-        deadline = time.monotonic() + 5
-        while time.monotonic() < deadline:
-            sup.scrape_once()
-            if not sup.agg.roles()["worker"]["up"]:
-                break
-            time.sleep(0.1)
-        m = merged()
-        assert not m["roles"]["worker"]["up"]
-        assert m["roles"]["worker"]["errors"] >= 1
-        vals = {s["labels"]["role"]: s["value"]
-                for s in m["families"][
-                    "etcd_wal_append_entries_total"]["samples"]}
-        assert vals["worker"] == 11.0  # last known, not dropped
-        ups = {s["labels"]["role"]: s["value"]
-               for s in m["families"]["etcd_role_up"]["samples"]}
-        assert ups["worker"] == 0.0 and ups["ingest"] == 1.0
-
-        # respawn the worker as a NEW incarnation on the same port
-        # slot with a FRESH registry at a lower cumulative value:
-        # the merged counter must fold monotone, and the new
-        # incarnation must be visible (role back up)
-        b2 = _FakeRole()
-        b2.reg.counter("etcd_wal_append_entries_total").inc(3)
-        sup.targets["worker"] = b2.port
-        try:
-            sup.scrape_once()
-            m = merged()
-            assert m["roles"]["worker"]["up"]
-            vals = {s["labels"]["role"]: s["value"]
-                    for s in m["families"][
-                        "etcd_wal_append_entries_total"]["samples"]}
-            assert vals["worker"] == 14.0  # 11 + 3, no double-count
-            # Prometheus view serves the same merged families
-            with urllib.request.urlopen(base + "/metrics",
-                                        timeout=5) as r:
-                text = r.read().decode()
-            assert ('etcd_wal_append_entries_total{role="worker"}'
-                    ' 14' in text)
-            # SLO verdict rides the supervisor plane too
-            with urllib.request.urlopen(base + "/v2/stats/slo",
-                                        timeout=5) as r:
-                v = json.loads(r.read())
-            assert v["verdict"] in ("ok", "burning", "no_data")
-        finally:
-            b2.stop()
-    finally:
-        a.stop()
-        sup._httpd.shutdown()
-        sup._httpd.server_close()

@@ -8,17 +8,15 @@ Three proof obligations:
    boundaries that split a record mid-frame, and raises the same
    typed errors (same first-bad-record, torn tails in the last
    chunk).
-2. **Overlap**: under a deterministic fake transport (injectable
-   per-chunk H2D latency + host-scan rate), pipeline wall-clock is
-   within 1.3x of max(stage total) — NOT sum(stages) — proving the
-   double buffering actually overlaps the stages.
+2. **Overlap**: under a fake transport whose stages each hold a
+   chunk until the stage before has started the next one, the run
+   ends — proving the double buffering actually overlaps the stages.
 3. **Plumbing**: the sharded native chain verify agrees with the
    sequential sweep; per-chunk progress lands in the devledger.
 """
 
 import os
 import threading
-import time
 
 import numpy as np
 import pytest
@@ -148,31 +146,73 @@ def test_sharded_chain_verify_matches_sequential(tmp_path):
 # -- 2. overlap under a deterministic fake transport --------------------------
 
 
+class _Stages:
+    """How far each stage of the pipeline has come, and the one wait
+    the overlap test is made of: a stage holds its chunk until
+    another stage has STARTED a later one.  A pipeline that runs its
+    stages one after another never lets that happen, and the wait
+    runs to its deadline; how fast the machine is changes nothing."""
+
+    DEADLINE_S = 60.0
+
+    def __init__(self, chunks: int):
+        self.chunks = chunks
+        self.started = {"scan": 0, "h2d": 0}
+        self.cond = threading.Condition()
+        self.missed: list[str] = []
+
+    def start(self, stage: str) -> int:
+        """This call's chunk number within ``stage``."""
+        with self.cond:
+            k = self.started[stage]
+            self.started[stage] = k + 1
+            self.cond.notify_all()
+        return k
+
+    def hold_until(self, stage: str, k: int, who: str) -> None:
+        """Return once ``stage`` has started chunk ``k`` (the last
+        chunk has no later one to wait for)."""
+        want = min(k + 1, self.chunks)
+        with self.cond:
+            if self.missed:
+                return
+            if not self.cond.wait_for(
+                    lambda: self.started[stage] >= want,
+                    timeout=self.DEADLINE_S):
+                self.missed.append(
+                    f"{who}: {stage} never started chunk {k}")
+
+
 class _FakeTransport(DeviceTransport):
-    """Programmable per-chunk latencies: ``ship`` sleeps h2d_s on the
-    caller thread (the H2D seam), ``verify`` dispatches to a worker
-    that sleeps verify_s (the device working asynchronously),
+    """``ship`` is the H2D seam on the caller thread, ``verify``
+    dispatches to a worker (the device working asynchronously),
     ``collect`` joins it.  Verification itself stays REAL (numpy
     host math over the injected-seed rows), so the overlap test also
-    re-proves bit-exactness end to end."""
+    re-proves bit-exactness end to end.  With ``stages``, the H2D of
+    chunk k holds until the scan of chunk k+1 has started, and the
+    verify of chunk k until the H2D of chunk k+1 has."""
 
-    def __init__(self, h2d_s: float, verify_s: float):
-        self.h2d_s = h2d_s
-        self.verify_s = verify_s
-        self.stage_seconds = {"h2d": 0.0, "verify": 0.0}
+    def __init__(self, stages: _Stages | None = None):
+        self.stages = stages
+        self.verified = 0
 
     def ship(self, rows):
-        time.sleep(self.h2d_s)
-        self.stage_seconds["h2d"] += self.h2d_s
+        if self.stages is not None:
+            k = self.stages.start("h2d")
+            self.stages.hold_until("scan", k + 1, f"h2d of chunk {k}")
         return rows
 
     def verify(self, shipped, stored):
         from etcd_tpu.crc import crc32c
 
         out = {}
+        k = self.verified
+        self.verified += 1
 
         def work():
-            time.sleep(self.verify_s)
+            if self.stages is not None:
+                self.stages.hold_until("h2d", k + 1,
+                                       f"verify of chunk {k}")
             got = np.empty(shipped.shape[0], np.uint32)
             for i, row in enumerate(shipped):
                 got[i] = crc32c.raw_update(0, row.tobytes()) \
@@ -181,7 +221,6 @@ class _FakeTransport(DeviceTransport):
 
         th = threading.Thread(target=work, daemon=True)
         th.start()
-        self.stage_seconds["verify"] += self.verify_s
         return (th, out)
 
     def collect(self, handle):
@@ -190,47 +229,33 @@ class _FakeTransport(DeviceTransport):
         return out["ok"]
 
 
-class _SlowScan:
-    """Wrap native.scan_chunk with a per-chunk delay (the injectable
-    host-scan rate)."""
-
-    def __init__(self, delay_s: float):
-        self.delay_s = delay_s
-        self.calls = 0
-        self.total = 0.0
-        self._real = native.scan_chunk
-
-    def __call__(self, *a, **k):
-        time.sleep(self.delay_s)
-        self.calls += 1
-        self.total += self.delay_s
-        return self._real(*a, **k)
-
-
-def test_pipeline_wall_clock_is_max_not_sum(tmp_path, monkeypatch):
-    """With scan 6ms, H2D 20ms, verify 6ms per chunk over 12 chunks,
-    sum(stages) = 384ms but the pipeline must land within 1.3x of
-    max(stage total) = 240ms — the stages genuinely overlap."""
+def test_pipeline_stages_overlap(tmp_path, monkeypatch):
+    """Host framing of chunk k+1 overlaps the H2D of chunk k and the
+    device verify of chunk k-1: each stage of the fake transport
+    holds its chunk until the stage before it has started the next
+    one, so the run ends only if the stages really run side by
+    side."""
     # chunk budget 1 byte -> every record is its own chunk (10
     # entries + the segment's crc/metadata head records = 12 chunks)
     blob = _wal_blob(tmp_path / "wal", n_entries=10, cuts=(),
                      sizes=[64] * 10)
-    slow = _SlowScan(0.006)
-    monkeypatch.setattr(native, "scan_chunk", slow)
-    fake = _FakeTransport(h2d_s=0.020, verify_s=0.006)
-    t0 = time.perf_counter()
+    want = native.wal_scan(blob)
+    stages = _Stages(chunks=int(want[0].size))
+    real_scan = native.scan_chunk
+
+    def scan(*a, **k):
+        stages.start("scan")
+        return real_scan(*a, **k)
+
+    monkeypatch.setattr(native, "scan_chunk", scan)
+    fake = _FakeTransport(stages)
     got = stream_scan_verify(blob, route="stream", chunk_bytes=1,
                              transport=fake)
-    wall = time.perf_counter() - t0
-    _assert_arrays_equal(native.wal_scan(blob), got)
-    assert slow.calls >= 9  # really chunked
-    stage_totals = [slow.total, fake.stage_seconds["h2d"],
-                    fake.stage_seconds["verify"]]
-    biggest = max(stage_totals)
-    assert wall < 1.3 * biggest, (
-        f"pipeline {wall * 1e3:.0f}ms vs 1.3 x max-stage "
-        f"{biggest * 1e3:.0f}ms — stages are serialized")
-    assert wall < 0.75 * sum(stage_totals)
+    assert not stages.missed, stages.missed
+    _assert_arrays_equal(want, got)
+    assert stages.started == {"scan": stages.chunks,
+                              "h2d": stages.chunks}
+    assert stages.chunks >= 9  # really chunked
 
 
 def test_pipeline_fake_transport_catches_corruption(tmp_path):
@@ -240,7 +265,7 @@ def test_pipeline_fake_transport_catches_corruption(tmp_path):
     # flip deep inside record 11's payload bytes (not the proto tag
     # bytes at the span head — that would be a parse error, not CRC)
     bad[int(do[11]) + int(dl[11]) - 3] ^= 0x01
-    fake = _FakeTransport(h2d_s=0.0, verify_s=0.0)
+    fake = _FakeTransport()
     with pytest.raises(CRCMismatchError, match="at record 11"):
         stream_scan_verify(bad, route="stream", chunk_bytes=256,
                            transport=fake)

@@ -429,9 +429,7 @@ def test_stitcher_drops_stale_incarnation(tmp_path):
 
 
 def test_stitched_cluster_run(traced_cluster, tmp_path):
-    """Real cluster -> harvested dumps -> stitched timelines: the
-    in-process miniature of the dist_bench --smoke acceptance
-    path."""
+    """Real cluster -> harvested dumps -> stitched timelines."""
     servers = traced_cluster
     for i in range(10):
         servers[0].do(Request(method="PUT", id=rid(),

@@ -173,7 +173,7 @@ def _dist_eq(a, b) -> bool:
     return True
 
 
-@pytest.mark.parametrize("seed", range(10))
+@pytest.mark.parametrize("seed", range(14))
 def test_dist_frame_roundtrip_fuzz(seed):
     """Every dist frame kind survives marshal→unmarshal with the
     seq/epoch header tags intact (the pipeline's ack matching rides
@@ -265,7 +265,7 @@ def test_dist_packed_table_validated_against_sections():
         unmarshal_any(bytes(wire))
 
 
-@pytest.mark.parametrize("seed", range(10))
+@pytest.mark.parametrize("seed", range(22))
 def test_dist_decoder_total_on_mutations(seed):
     """Bit-flipped / truncated / extended dist frames never escape
     the codec as anything but FrameError (the drop-tolerant peer
@@ -356,9 +356,18 @@ def test_schema_flag_and_count_extremes(fmt):
             wire_fuzz._run_one(fmt, sch, parser, m)
         for m in wire_fuzz._field_mutations(sch, seed):
             wire_fuzz._run_one(fmt, sch, parser, m)
-        if fmt == "srg1":
-            for m in wire_fuzz._srg1_header_mutations(sch, seed):
-                wire_fuzz._run_one(fmt, sch, parser, m)
+
+
+def test_the_fuzzer_covers_exactly_the_declared_formats():
+    """A format declared in wire/schema.py and not fuzzed, or fuzzed
+    and no longer declared, fails here and not at the next
+    crasher."""
+    from etcd_tpu.wire import schema
+
+    assert {sch.name for sch, _seeds in wire_fuzz.FORMATS.values()} \
+        == {f.name for f in schema.FORMATS} == {"DGB2", "DCB1", "GPB1"}
+    assert set(wire_fuzz.FORMATS) == {f.name.lower()
+                                      for f in schema.FORMATS}
 
 
 def test_persisted_crashers_stay_fixed():
