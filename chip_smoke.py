@@ -74,13 +74,15 @@ COHOSTED = dict(g=10_000, members=5, puts=1000, tenants=1000,
 DIST = dict(g=1024, puts=300)
 
 #: (width, rows) for the direct kernel checks — the stream lane's
-#: width classes at the power-of-two row counts
-#: ``_dispatch_chunk_verify`` produces (floor 8, ceiling 2^17), the
-#: config-4 frontier row, and the monolithic lane's floor class
+#: width classes at the one row count each ships in
+#: (``replay_device._tile_rows``: 8 MiB of rows at the default
+#: chunk, the config-4 frontier row among them), the power-of-two
+#: counts of the monolithic lane (floor 8, ceiling 2^17) and its
+#: floor class
 KERNEL_SHAPES = (
-    (128, 8), (128, 1 << 17),
+    (128, 8), (128, 1 << 16), (128, 1 << 17),
     (384, 8), (384, 1 << 14), (384, 1 << 17),
-    (2048, 8), (2048, 1 << 11), (2048, 1 << 17),
+    (2048, 8), (2048, 1 << 12), (2048, 1 << 17),
     (131072, 64),
     (64, 8), (64, 1024),
 )

@@ -29,6 +29,7 @@ device owns index/term/commit math, the host owns opaque blobs.
 
 from __future__ import annotations
 
+import time
 from collections.abc import Mapping, Sequence
 from functools import partial
 
@@ -68,9 +69,50 @@ def _drop_dense(drop, m: int, g: int) -> np.ndarray:
     return dense
 
 
+#: rows of the round's packed result, an ``int32[len(PACK), G]``
+PACK = ("valid", "base", "newly", "overflow", "conflict", "terms",
+        "commit")
+
+
+def readback(stage: str, value) -> np.ndarray:
+    """A device value as a host array: the ledger's ``fetch`` (bytes
+    and block time billed to ``stage``), its wait filed as one sample
+    of ``mg.readback``.  Every device→host materialisation of the
+    engine thread goes through here, so the count of the wait over
+    the count of rounds says how many crossings a round cost."""
+    t0 = time.perf_counter()
+    out = _ledger.fetch(stage, value)
+    tracer.record_wait("mg.readback", time.perf_counter() - t0)
+    return out
+
+
+def _max_over(states, field: str):
+    out = getattr(states[0], field)
+    for st in states[1:]:
+        out = jnp.maximum(out, getattr(st, field))
+    return out
+
+
+def _take_applied(states, upto):
+    """Every member's ``applied`` raised to ``min(upto, commit)``:
+    what the host declared applied since the last round, absorbed
+    before the round reads anything else (all zeros: a no-op)."""
+    return tuple(st._replace(applied=jnp.maximum(
+        st.applied, jnp.minimum(upto, st.commit))) for st in states)
+
+
 def _round_core(states, sels, n_new, drop, e, slots):
     """The propose→replicate→respond→commit round body, parametric in
     which member slots participate as leaders.
+
+    Returns ``(states', rows)``: ``rows`` maps each name of
+    :data:`PACK` to a [G] array, the round's whole answer to the
+    host — ``valid`` / ``base`` key the host payload store (which
+    groups had a real leader, and its pre-append last index),
+    ``newly`` is the commit delta, ``overflow`` / ``conflict`` the
+    per-group error lanes, ``terms`` / ``commit`` the maxima over the
+    members at the round's end.  The programs hand it over as ONE
+    array (:func:`_pack`).
 
     ``sels[i]``: [G] bool router mask for ``slots[i]`` (which groups
     address that slot as leader).  The general round passes every
@@ -87,9 +129,7 @@ def _round_core(states, sels, n_new, drop, e, slots):
     m = len(states)
     g = n_new.shape[0]
 
-    commits0 = states[0].commit
-    for st in states[1:]:
-        commits0 = jnp.maximum(commits0, st.commit)
+    commits0 = _max_over(states, "commit")
 
     valid = jnp.zeros((g,), bool)
     base = jnp.zeros((g,), jnp.int32)
@@ -213,80 +253,99 @@ def _round_core(states, sels, n_new, drop, e, slots):
             lst = maybe_commit(lst)
         states[slot] = lst
 
-    commits1 = states[0].commit
-    for st in states[1:]:
-        commits1 = jnp.maximum(commits1, st.commit)
-    return (tuple(states), commits1 - commits0, valid, base,
-            overflow, conflict)
+    commits1 = _max_over(states, "commit")
+    return tuple(states), dict(
+        valid=valid, base=base, newly=commits1 - commits0,
+        overflow=overflow, conflict=conflict,
+        terms=_max_over(states, "term"), commit=commits1)
+
+
+def _pack(rows):
+    """A round's rows as ONE ``int32[len(PACK), G]`` array: one
+    output buffer and one transfer, the ``g`` axis last (placed like
+    the fault mask where the state is sharded)."""
+    return jnp.stack([rows[k].astype(jnp.int32) for k in PACK])
 
 
 @partial(jax.jit, static_argnames=("e",))
-def _fused_round(states, leader, n_new, drop, e):
+def _fused_round(states, leader, inp, drop, e):
     """One full propose→replicate→respond→commit round, on device.
 
     ``states``: tuple of M GroupState pytrees; ``leader``: [G] i32
-    member slot per group (-1 none); ``n_new``: [G] i32 proposals to
-    append at each group's leader; ``drop``: [M, M, G] bool per-edge
-    fault mask (drop[a, b, g] kills a→b messages of group g).
+    member slot per group (-1 none); ``inp``: [2, G] i32, row 0 the
+    proposals to append at each group's leader, row 1 what the host
+    has applied since the last round (:func:`_take_applied`);
+    ``drop``: [M, M, G] bool per-edge fault mask (drop[a, b, g] kills
+    a→b messages of group g).
 
-    Returns ``(states', newly_committed, valid, base, overflow,
-    conflict)`` — valid/base key the host payload store (which groups
-    had a real leader, and its pre-append last index); overflow /
-    conflict are the per-group error lanes.
+    Returns ``(states', pack)`` (:func:`_pack`).
     """
     m = len(states)
     sels = [leader == s for s in range(m)]
-    return _round_core(states, sels, n_new, drop, e, tuple(range(m)))
+    states, rows = _round_core(_take_applied(states, inp[1]), sels,
+                               inp[0], drop, e, tuple(range(m)))
+    return states, _pack(rows)
 
 
 @partial(jax.jit, static_argnames=("e", "slot"))
-def _fused_round_hot(states, sel, n_new, drop, e, slot):
+def _fused_round_hot(states, sel, inp, drop, e, slot):
     """The single-addressed-slot round (serving steady state: every
     group routes to one member slot — the bootstrap shape and the
     common shape between elections).  Compiles 1/M of the append work
     and 1/M of the pair exchanges; exactly equivalent to
     :func:`_fused_round` under that routing (see _round_core)."""
-    return _round_core(states, [sel], n_new, drop, e, (slot,))
+    states, rows = _round_core(_take_applied(states, inp[1]), [sel],
+                               inp[0], drop, e, (slot,))
+    return states, _pack(rows)
+
+
+def _round_train(states, sels, inp, drop, e, k, slots):
+    """``k`` rounds of ``inp[0]`` proposals each, the host's applied
+    vector absorbed before the first.  The pack holds the train's
+    total of ``newly``, its error lanes ORed and the frontier at its
+    end; ``valid`` / ``base`` are a single round's keying and read 0
+    here."""
+    def body(_, carry):
+        states, total, overflow, conflict = carry
+        states, rows = _round_core(states, sels, inp[0], drop, e,
+                                   slots)
+        return (states, total + rows["newly"],
+                overflow | rows["overflow"],
+                conflict | rows["conflict"])
+
+    g = inp.shape[1]
+    none = jnp.zeros((g,), bool)
+    zero = jnp.zeros((g,), jnp.int32)
+    states, total, overflow, conflict = jax.lax.fori_loop(
+        0, k, body, (_take_applied(states, inp[1]), zero, none, none))
+    return states, _pack(dict(
+        valid=none, base=zero, newly=total, overflow=overflow,
+        conflict=conflict, terms=_max_over(states, "term"),
+        commit=_max_over(states, "commit")))
 
 
 @partial(jax.jit, static_argnames=("e", "k", "slot"))
-def _fused_multi_round_hot(states, sel, n_new, drop, e, k, slot):
+def _fused_multi_round_hot(states, sel, inp, drop, e, k, slot):
     """``k`` hot-slot rounds in one dispatch (propose_rounds')."""
-    def body(_, carry):
-        states, total, overflow, conflict = carry
-        states, newly, _v, _b, o, c = _round_core(
-            states, [sel], n_new, drop, e, (slot,))
-        return states, total + newly, overflow | o, conflict | c
-
-    g = n_new.shape[0]
-    init = (states, jnp.zeros((g,), jnp.int32),
-            jnp.zeros((g,), bool), jnp.zeros((g,), bool))
-    return jax.lax.fori_loop(0, k, body, init)
+    return _round_train(states, [sel], inp, drop, e, k, (slot,))
 
 
 @partial(jax.jit, static_argnames=("e", "k"))
-def _fused_multi_round(states, leader, n_new, drop, e, k):
+def _fused_multi_round(states, leader, inp, drop, e, k):
     """``k`` consecutive fused rounds in ONE device dispatch.
 
-    The per-round host sync in :meth:`MultiRaft.propose` (valid/base/
-    overflow materialized to numpy every call) is a fixed cost per
-    dispatch that is transport, not consensus.  Payload-less callers (benchmarks,
-    idle heartbeat trains, catch-up replication bursts) don't need
-    the per-round keying arrays, so the whole train runs device-side
-    with a single commit-delta readback.
+    The per-round host sync in :meth:`MultiRaft.propose` is a fixed
+    cost per dispatch that is transport, not consensus.  Payload-less
+    callers (benchmarks, idle heartbeat trains, catch-up replication
+    bursts) don't need the per-round keying arrays, so the whole train
+    runs device-side with a single read-back.
 
-    Returns ``(states', newly_committed_total, overflow, conflict)``.
+    Returns ``(states', pack)`` (:func:`_round_train`).
     """
-    def body(_, carry):
-        states, total, overflow, conflict = carry
-        states, newly, _valid, _base, o, c = _fused_round(
-            states, leader, n_new, drop, e)
-        return states, total + newly, overflow | o, conflict | c
-
-    g = leader.shape[0]
-    init = (states, jnp.zeros((g,), jnp.int32),
-            jnp.zeros((g,), bool), jnp.zeros((g,), bool))
-    return jax.lax.fori_loop(0, k, body, init)
+    m = len(states)
+    sels = [leader == s for s in range(m)]
+    return _round_train(states, sels, inp, drop, e, k,
+                        tuple(range(m)))
 
 
 @partial(jax.jit, static_argnames=("slot",))
@@ -400,6 +459,19 @@ class MultiRaft:
         self._no_drop = jnp.zeros((m, m, g), bool)
         self._placer = None   # set by shard(): parallel.mesh placer
         self._sh_drop = None  # set by shard(): for [M, M, G] masks
+        self._sh_rows = None  # set by shard(): for [K, G] rows
+        # what the host declared applied since the last round: rides
+        # the next round's input (mark_applied), None when nothing is
+        self._applied_due: np.ndarray | None = None
+        # the last round's pack as host arrays (_take_pack), and the
+        # (term, commit) arrays of the states that round returned:
+        # while they are still the engine's, last_terms / last_commit
+        # are the device's view (_pack_stands)
+        self.last_valid = np.zeros(g, bool)
+        self.last_base = np.zeros(g, np.int32)
+        self.last_terms = np.zeros(g, np.int32)
+        self.last_commit = np.zeros(g, np.int32)
+        self._pack_of: tuple = ()
 
     # -- intra-slice scale-out --------------------------------------------
 
@@ -432,6 +504,7 @@ class MultiRaft:
         self._placer = leading_placer(mesh)
         self._hot_sel = None  # placement changed: rebuild the mask
         self._sh_drop = NamedSharding(mesh, P(None, None, "g"))
+        self._sh_rows = NamedSharding(mesh, P(None, "g"))
 
     def _put_g(self, arr, dtype=None):
         """[G] host array → device, g-sharded when the state is."""
@@ -444,6 +517,42 @@ class MultiRaft:
         if self._sh_drop is not None:
             return jax.device_put(dense, self._sh_drop)
         return jnp.asarray(dense)
+
+    def _round_input(self, n_new: np.ndarray):
+        """The round's ONE put: ``[2, G]`` i32, the proposal counts
+        over the applied vector that was due (zeros when none is),
+        g-sharded on its trailing axis when the state is."""
+        inp = np.zeros((2, self.g), np.int32)
+        inp[0] = n_new
+        if self._applied_due is not None:
+            inp[1] = self._applied_due
+            self._applied_due = None
+        return jax.device_put(inp, self._sh_rows)
+
+    def _take_pack(self, stage: str, states, pack) -> np.ndarray:
+        """Adopt a round program's result: the states, and the pack
+        read back in ONE transfer and split into host arrays.
+        Returns the round's newly committed counts."""
+        self.states = list(states)
+        self._pack_of = tuple((st.term, st.commit) for st in states)
+        rows = dict(zip(PACK, readback(stage, pack)))
+        self.last_valid = rows["valid"].astype(bool)
+        self.last_base = rows["base"]
+        self.last_terms = rows["terms"]
+        self.last_commit = rows["commit"]
+        self.errors["overflow"] = rows["overflow"].astype(bool)
+        self.errors["conflict"] = rows["conflict"].astype(bool)
+        return rows["newly"]
+
+    def _pack_stands(self) -> bool:
+        """Whether every member's ``term`` and ``commit`` are still
+        the arrays the last round returned with its pack (arrays are
+        immutable: the same object is the same value).  Only those
+        two are kept, never a whole state: a compaction's stale
+        generation of logs must not stay alive for this."""
+        return len(self._pack_of) == len(self.states) and all(
+            st.term is t and st.commit is c
+            for st, (t, c) in zip(self.states, self._pack_of))
 
     def _recompute_hot(self) -> None:
         mx = int(self.leader.max(initial=-1))
@@ -479,7 +588,7 @@ class MultiRaft:
                 tuple(self.states), self._put_g(mask), dense,
                 slot=slot)
         self.states = list(states)
-        won_np = _ledger.fetch("multiraft.campaign", won)
+        won_np = readback("multiraft.campaign", won)
         self.leader = np.where(won_np, slot, self.leader).astype(np.int32)
         self._recompute_hot()
         if won_np.any():
@@ -487,7 +596,8 @@ class MultiRaft:
             # (Raft safety: committed entries survive elections), so a
             # deposed leader's payloads at those indices are garbage
             # the new term may overwrite — drop them.
-            winner_last = np.asarray(self.states[slot].last)
+            winner_last = readback("multiraft.campaign",
+                                   self.states[slot].last)
             for gi in np.nonzero(won_np)[0]:
                 p = self.payloads[gi]
                 cut = int(winner_last[gi])
@@ -515,38 +625,31 @@ class MultiRaft:
             self._put_drop(_drop_dense(drop, self.m, g))
         # the round's three parts, each a stage at the ledger's seam
         # (the co-hosted engine's mg.consensus_round tiles into them):
-        # dispatch up to the jitted call's return, wait the first
-        # read-back, which blocks until the device has run the round,
-        # fetch the remaining read-backs and the payload bookkeeping
-        _ledger.h2d("multiraft.round", n_new)
+        # dispatch up to the jitted call's return, one put in and one
+        # program; wait the round's ONE read-back, which blocks until
+        # the device has run the round; fetch the payload bookkeeping,
+        # host work alone
         with tracer.stage("mg.round.dispatch", cpu=False), \
                 _ledger.dispatch("multiraft.round"):
+            inp = self._round_input(n_new)
+            _ledger.h2d("multiraft.round", inp)
             if self._route_hot is not None:
                 hot = self._route_hot
-                states, newly, valid, base, overflow, conflict = \
-                    _fused_round_hot(
-                        tuple(self.states), self._hot_sel_dev(hot),
-                        self._put_g(n_new), dense, e=self.e,
-                        slot=hot)
+                states, pack = _fused_round_hot(
+                    tuple(self.states), self._hot_sel_dev(hot), inp,
+                    dense, e=self.e, slot=hot)
             else:
-                states, newly, valid, base, overflow, conflict = \
-                    _fused_round(
-                        tuple(self.states), self._put_g(self.leader),
-                        self._put_g(n_new), dense, e=self.e)
-        self.states = list(states)
-        # lazy device arrays, same as propose_rounds: consumers call
-        # .any()/np.asarray when (if) they actually look
-        self.errors["overflow"] = overflow
-        self.errors["conflict"] = conflict
+                states, pack = _fused_round(
+                    tuple(self.states), self._put_g(self.leader), inp,
+                    dense, e=self.e)
+        with tracer.stage("mg.round.wait", cpu=False):
+            newly = self._take_pack("multiraft.round", states, pack)
         # payloads recorded only for groups whose addressed member
         # really IS leader (a deposed member may linger in
         # self.leader), keyed from its pre-append last index; the
         # assignment arrays are kept for callers that key their own
         # bookkeeping (the multi-group server's wait registry)
-        with tracer.stage("mg.round.wait", cpu=False):
-            self.last_valid = _ledger.fetch("multiraft.round", valid)
         with tracer.stage("mg.round.fetch", cpu=False):
-            self.last_base = _ledger.fetch("multiraft.round", base)
             if data is not None:
                 # only the groups that took proposals, so a mapping
                 # of those answers as a list of G lists does
@@ -555,7 +658,7 @@ class MultiRaft:
                             data[gi][:int(n_new[gi])]):
                         self.payloads[gi][
                             int(self.last_base[gi]) + 1 + j] = blob
-            return _ledger.fetch("multiraft.round", newly)
+        return newly
 
     def propose_rounds(self, n_new: np.ndarray, rounds: int,
                        drop=None) -> np.ndarray:
@@ -572,28 +675,19 @@ class MultiRaft:
         g = self.g
         dense = self._no_drop if not drop else \
             self._put_drop(_drop_dense(drop, self.m, g))
-        _ledger.h2d("multiraft.train", np.asarray(n_new, np.int32))
         with _ledger.dispatch("multiraft.train"):
+            inp = self._round_input(np.asarray(n_new, np.int32))
+            _ledger.h2d("multiraft.train", inp)
             if self._route_hot is not None:
                 hot = self._route_hot
-                states, newly, overflow, conflict = \
-                    _fused_multi_round_hot(
-                        tuple(self.states), self._hot_sel_dev(hot),
-                        self._put_g(n_new, np.int32), dense,
-                        e=self.e, k=rounds, slot=hot)
+                states, pack = _fused_multi_round_hot(
+                    tuple(self.states), self._hot_sel_dev(hot), inp,
+                    dense, e=self.e, k=rounds, slot=hot)
             else:
-                states, newly, overflow, conflict = \
-                    _fused_multi_round(
-                        tuple(self.states), self._put_g(self.leader),
-                        self._put_g(n_new, np.int32), dense,
-                        e=self.e, k=rounds)
-        self.states = list(states)
-        # device arrays, materialized lazily by consumers (np.asarray
-        # / .any() work transparently) — two eager [G] gathers per
-        # dispatch were measurable serving overhead on the mesh
-        self.errors["overflow"] = overflow
-        self.errors["conflict"] = conflict
-        return _ledger.fetch("multiraft.train", newly)
+                states, pack = _fused_multi_round(
+                    tuple(self.states), self._put_g(self.leader), inp,
+                    dense, e=self.e, k=rounds)
+        return self._take_pack("multiraft.train", states, pack)
 
     def replicate(self, drop=None) -> np.ndarray:
         """One replication round for every group: leaders send their
@@ -645,13 +739,25 @@ class MultiRaft:
         """The host consumer declares it has applied entries up to
         ``upto[g]`` (clamped to each member's commit).  Compaction
         never slides past this point, so committed-but-unconsumed
-        payloads stay retrievable."""
-        upto = self._put_g(upto, np.int32)
-        for slot in range(self.m):
-            st = self.states[slot]
-            st = st._replace(applied=jnp.maximum(
-                st.applied, jnp.minimum(upto, st.commit)))
-            self.states[slot] = st
+        payloads stay retrievable.  Nothing is dispatched: the vector
+        rides the next round's input, which absorbs it before it
+        reads anything else (no program moves ``commit`` between), and
+        :meth:`compact`, which reads ``applied`` before that, puts it
+        on the device first."""
+        upto = np.array(upto, np.int32)       # the caller's may change
+        due = self._applied_due
+        self._applied_due = upto if due is None \
+            else np.maximum(due, upto)
+
+    def _flush_applied(self) -> None:
+        """Put the applied vector that is due on the device now, in
+        the eager form, for a reader of ``states[*].applied`` that
+        comes before the next round."""
+        if self._applied_due is None:
+            return
+        upto = self._put_g(self._applied_due)
+        self._applied_due = None
+        self.states = list(_take_applied(self.states, upto))
 
     def compact(self, upto: np.ndarray | None = None) -> None:
         """Compact every member's log at its applied index (the
@@ -662,18 +768,23 @@ class MultiRaft:
         the consumer declared applied.  Out-of-bounds lanes skip
         compaction (surfaced per-group in ``errors["compact_oob"]``,
         never batch-fatal)."""
-        oob = np.zeros(self.g, bool)
+        self._flush_applied()
+        if upto is not None:
+            upto = self._put_g(upto, np.int32)
+        oob = cut = None
         for slot in range(self.m):
             st = self.states[slot]
             idx = st.applied
             if upto is not None:
-                idx = jnp.minimum(idx, self._put_g(upto, np.int32))
+                idx = jnp.minimum(idx, upto)
             st, err = compact_batch(st, jnp.maximum(idx, st.offset))
-            oob |= np.asarray(err)
+            oob = err if oob is None else oob | err
+            cut = st.offset if cut is None \
+                else jnp.minimum(cut, st.offset)
             self.states[slot] = st
-        self.errors["compact_oob"] = oob
-        cut = np.min(np.stack(
-            [np.asarray(st.offset) for st in self.states]), axis=0)
+        oob, cut = readback("multiraft.compact", jnp.stack(
+            [oob.astype(jnp.int32), cut]))
+        self.errors["compact_oob"] = oob.astype(bool)
         for gi in range(self.g):
             p = self.payloads[gi]
             c = int(cut[gi])
@@ -687,20 +798,34 @@ class MultiRaft:
         for slot in range(self.m):
             st, elect, _beat = tick_batch(self.states[slot])
             self.states[slot] = st
-            fire = np.asarray(elect)
+            fire = readback("multiraft.tick", elect)
             if fire.any():
                 self.campaign(slot, fire, drop=drop)
 
     # -- views -----------------------------------------------------------
 
-    def _commit_vector(self) -> np.ndarray:
-        """Max commit across members per group (any member's commit
-        is authoritative once set)."""
-        return np.max(np.stack(
-            [np.asarray(st.commit) for st in self.states]), axis=0)
+    def _max_view(self, field: str, last: np.ndarray) -> np.ndarray:
+        """Max of ``field`` across members per group: the last
+        round's own answer while its states still stand, else taken
+        on the device and read back."""
+        if self._pack_stands():
+            return last
+        return readback("multiraft.view",
+                        _max_over(self.states, field))
 
     def commit_index(self) -> np.ndarray:
-        return self._commit_vector()
+        """Max commit across members per group (any member's commit
+        is authoritative once set)."""
+        return self._max_view("commit", self.last_commit)
+
+    def term_index(self) -> np.ndarray:
+        """Max term across members per group."""
+        return self._max_view("term", self.last_terms)
+
+    def members_mask(self) -> np.ndarray:
+        """[G, M] live-membership mask (every member holds the same:
+        a committed ConfChange flips all of them at once)."""
+        return readback("multiraft.view", self.states[0].members)
 
     def committed_payload(self, group: int, index: int) -> bytes | None:
         return self.payloads[group].get(index)

@@ -423,8 +423,7 @@ class MultiGroupServer:
         if won.any():
             base = mr.last_base
             valid = mr.last_valid
-            terms_now = np.max(np.stack(
-                [np.asarray(st.term) for st in mr.states]), axis=0)
+            terms_now = mr.last_terms
             for gi in np.nonzero(won & valid)[0]:
                 self.seq += 1
                 fences.append(Entry(
@@ -568,7 +567,7 @@ class MultiGroupServer:
     def members_of(self, gi: int) -> np.ndarray:
         """[M] live-membership mask of group ``gi`` (slot capacity M;
         quorum = live//2 + 1)."""
-        return np.asarray(self.mr.states[0].members)[gi]
+        return self.mr.members_mask()[gi]
 
     # -- RaftTimer --------------------------------------------------------
 
@@ -666,12 +665,12 @@ class MultiGroupServer:
                 with tracer.stage("mg.consensus_round"):
                     mr.propose(n_new, data=data)
                 with tracer.stage("mg.frontier_fetch", cpu=False):
+                    # host arrays all four: the round's one
+                    # read-back brought them (MultiRaft._take_pack)
                     valid = mr.last_valid
                     base = mr.last_base
-                    terms_now = np.max(np.stack(
-                        [np.asarray(st.term) for st in mr.states]),
-                        axis=0).astype(np.int32)
-                    commit = mr.commit_index()
+                    terms_now = mr.last_terms
+                    commit = mr.last_commit
                 with tracer.stage("mg.assign", cpu=False):
                     assigned: dict[tuple[int, int], _Pending] = {}
                     to_persist: list[Entry] = []
@@ -702,14 +701,10 @@ class MultiGroupServer:
                                      commit)
                 if mr.errors["overflow"].any():
                     # compaction AFTER absorb: mark_applied(
-                    # self.applied) inside _absorb_commits bounds it,
-                    # so committed-but-unapplied payloads are never
-                    # pruned.  The flag stays here, outside
-                    # mg.frontier_fetch: its read-back lets go of the
-                    # GIL for a millisecond right after the
-                    # acknowledgements, and read before the absorb
-                    # instead, a quarter of the rounds at 10k groups
-                    # ran idle (PERF.md section 6, PR 27)
+                    # self.applied) inside _absorb_commits bounds it
+                    # (compact() puts that vector on the device
+                    # first), so committed-but-unapplied payloads are
+                    # never pruned
                     mr.compact()
 
         # server stopping: promptly release EVERY waiter — the final
@@ -763,8 +758,8 @@ class MultiGroupServer:
         """Persist-then-apply: newly appended entries and the commit
         frontier go to the WAL (fsync) BEFORE any client ack — the
         Ready contract's ordering (node.go:41-60) at batch level.
-        ``commit``: the engine's commit index where the caller has
-        fetched it since its last round (mg.frontier_fetch)."""
+        ``terms_now`` / ``commit``: the engine's frontier where the
+        caller holds its last round's (mg.frontier_fetch)."""
         mr = self.mr
         if self._nospace:
             # applies and acks queue behind the held persist; the
@@ -777,11 +772,8 @@ class MultiGroupServer:
         if to_persist or newly.any():
             terms = np.zeros(self.g, np.int32)
             if newly.any():
-                if terms_now is None:
-                    terms_now = np.max(np.stack(
-                        [np.asarray(st.term) for st in mr.states]),
-                        axis=0).astype(np.int32)
-                terms = terms_now
+                terms = terms_now if terms_now is not None \
+                    else mr.term_index()
                 self.raft_term = max(self.raft_term,
                                      int(terms.max()))
             frontier = GroupEntry(
@@ -911,8 +903,7 @@ class MultiGroupServer:
         """Store snapshot + frontier → snap file; compact the device
         logs; cut the WAL (server.go:562-571 batched)."""
         mr = self.mr
-        terms = np.max(np.stack(
-            [np.asarray(st.term) for st in mr.states]), axis=0)
+        terms = mr.term_index()
         blob = json.dumps({
             "store": self.store.save().decode(),
             "frontier": [int(x) for x in self.applied],
@@ -921,8 +912,7 @@ class MultiGroupServer:
             "applied_total": self.raft_index,
             # per-group live-membership mask: conf changes below the
             # snapshot don't need their entries replayed
-            "members": np.asarray(self.mr.states[0].members)
-            .astype(int).tolist(),
+            "members": mr.members_mask().astype(int).tolist(),
         }).encode()
         with tracer.stage("mg.snapshot"):
             snap_seq = self.seq

@@ -275,13 +275,36 @@ def _width_classes(dlen_v: np.ndarray) -> np.ndarray:
         ).astype(np.int64))
 
 
+#: most bytes of padded rows in one shipment of the streaming lane:
+#: two default chunks, which is what a chunk's records of one width
+#: class pad out to at most (a record of a power-of-two class is
+#: longer than half its row)
+_TILE_BYTES = 2 * DEFAULT_CHUNK_BYTES
+
+
+def _tile_rows(w: int, chunk_bytes: int, byte_budget: int) -> int:
+    """Rows of width class ``w`` in one shipment of the streaming
+    lane: a power of two that follows from the width and the lane's
+    settings alone, never from how many records the chunk holds.
+    Every (rows, width) pair is a compiled program, and sized to the
+    chunk's count a WAL's short last chunk compiled three to five
+    programs that no WAL before it had needed — 2 to 4 s of a 10 s
+    restart (PERF.md section 6, PR 34).  Short of rows, a shipment
+    is padded with trivially-true links; a chunk with more makes
+    several."""
+    tile = min(2 * chunk_bytes, _TILE_BYTES, byte_budget)
+    rows = max(8, min(1 << 17, tile // w))
+    return max(1, min(1 << (rows.bit_length() - 1), byte_budget // w))
+
+
 def _dispatch_chunk_verify(blob, crcs, doff, dlen, prev, transport,
-                           byte_budget: int, ledger_stage: str):
+                           chunk_bytes: int, byte_budget: int,
+                           ledger_stage: str):
     """Pad + seed-inject one scanned chunk's records and *dispatch*
-    the device chain verify (one shipment per width class inside the
-    chunk).  Returns ``[(sel, n_real, handle), ...]`` for a later
-    blocking collect — the caller keeps scanning/shipping while the
-    device works."""
+    the device chain verify (a shipment per :func:`_tile_rows` rows
+    of each width class inside the chunk).  Returns ``[(sel, n_real,
+    handle), ...]`` for a later blocking collect — the caller keeps
+    scanning/shipping while the device works."""
     from ..ops.crc_device import inject_seeds
 
     stored = np.ascontiguousarray(crcs, np.uint32)
@@ -293,8 +316,7 @@ def _dispatch_chunk_verify(blob, crcs, doff, dlen, prev, transport,
     for w in np.unique(wcls):
         w = int(w)
         rows_idx = np.nonzero(wcls == w)[0]
-        rpc = max(1, min(1 << 17, byte_budget // w))
-        rpc = min(rpc, max(8, 1 << (rows_idx.size - 1).bit_length()))
+        rpc = _tile_rows(w, chunk_bytes, byte_budget)
         for lo in range(0, rows_idx.size, rpc):
             sel = rows_idx[lo:lo + rpc]
             pad = rpc - sel.size
@@ -456,7 +478,7 @@ def stream_scan_verify(blob: np.ndarray, *, seed: int = 0,
                 [np.asarray([head], np.uint32), crcs[:-1]])
             handles = _dispatch_chunk_verify(
                 blob, crcs, arrays[2], arrays[3], prev, transport,
-                byte_budget, ledger_stage)
+                chunk_bytes, byte_budget, ledger_stage)
             inflight.append((base, crcs, handles))
             prev_tail = int(crcs[-1])
             while len(inflight) >= depth:

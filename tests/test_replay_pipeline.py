@@ -271,6 +271,54 @@ def test_pipeline_fake_transport_catches_corruption(tmp_path):
                            transport=fake)
 
 
+class _ShapeTransport(_FakeTransport):
+    """Records the (rows, width) of every shipment."""
+
+    def __init__(self):
+        super().__init__()
+        self.shapes = set()
+
+    def ship(self, rows):
+        self.shapes.add(rows.shape)
+        return rows
+
+
+@pytest.mark.parametrize("chunk_bytes", [2048, 1 << 14])
+def test_shipment_shapes_do_not_follow_the_record_count(tmp_path,
+                                                        chunk_bytes):
+    """A width class ships in ONE (rows, width) shape whatever a
+    chunk holds: a WAL of 7 records and one of 150, short last chunks
+    included, hand the device the same shapes, so the second compiles
+    nothing the first did not (PR 34: a restart whose WAL ended in a
+    short chunk compiled 2-4 s of programs)."""
+    seen = []
+    for n in (7, 64, 150):
+        blob = _wal_blob(tmp_path / f"wal{n}", n_entries=n, cuts=(),
+                         sizes=[100] * n)
+        tr = _ShapeTransport()
+        got = stream_scan_verify(blob, route="stream",
+                                 chunk_bytes=chunk_bytes, transport=tr)
+        _assert_arrays_equal(native.wal_scan(blob), got)
+        seen.append(tr.shapes)
+    assert seen[0] == seen[1] == seen[2], seen
+    assert len({w for _r, w in seen[0]}) == len(seen[0])
+
+
+@pytest.mark.parametrize("w, chunk_bytes, budget, rows", [
+    (128, 4 << 20, 1 << 28, 1 << 16),     # 8 MiB of rows
+    (384, 4 << 20, 1 << 28, 1 << 14),
+    (131072, 4 << 20, 1 << 28, 64),       # a full chunk's frontier rows
+    (128, 1024, 1 << 28, 16),             # a small chunk: two of it
+    (131072, 1024, 1 << 28, 8),           # never under the floor of 8
+    (128, 1 << 30, 1 << 28, 1 << 16),     # nor over two default chunks
+    (1 << 18, 4 << 20, 1 << 17, 1),       # the byte budget still caps
+])
+def test_tile_rows(w, chunk_bytes, budget, rows):
+    from etcd_tpu.wal.replay_device import _tile_rows
+
+    assert _tile_rows(w, chunk_bytes, budget) == rows
+
+
 # -- 3. ledger plumbing -------------------------------------------------------
 
 

@@ -24,8 +24,12 @@ N_PUTS = 24
 PASS_CHILDREN = ("mg.pack", "mg.consensus_round", "mg.frontier_fetch",
                  "mg.assign", "mg.persist", "mg.apply", "mg.mark_applied")
 ROUND_PARTS = ("mg.round.dispatch", "mg.round.wait", "mg.round.fetch")
+#: the stages of a pass whose bodies PR 34 changed (one packed
+#: read-back, ``applied`` riding the next round): the names stay
+PACKED_PASS = (*ROUND_PARTS, "mg.frontier_fetch", "mg.mark_applied",
+               "mg.consensus_round", "mg.pass")
 SERVING = ("mg.pass", "mg.drain_wait", *PASS_CHILDREN, *ROUND_PARTS,
-           "mg.queue_wait", "mg.commit_wait", "fd.parse",
+           "mg.readback", "mg.queue_wait", "mg.commit_wait", "fd.parse",
            "fd.worker_wait", "fd.do.put", "fd.do.get",
            "fd.read_inline", "fd.respond_wait")
 RESTART = ("restart.snapshot_load", "replay.device", "replay.matrix",
@@ -126,6 +130,17 @@ def test_serving_stage_or_wait_has_a_sample(served, stage):
 def test_round_parts_are_counted_with_the_round(served, part):
     g = served["grew"]
     assert g[part][0] == g["mg.consensus_round"][0] >= 1
+
+
+@pytest.mark.parametrize("stage", PACKED_PASS)
+def test_packed_pass_records_each_stage_once_a_pass(served, stage):
+    """Every write of the fixture is a pass of its own that commits:
+    each of the seven names has one sample a pass, and the pass made
+    one read-back (``mg.readback``), the round's."""
+    g = served["grew"]
+    assert g[stage][0] == g["mg.pass"][0] >= N_PUTS, (stage, g)
+    assert g[stage][1] > 0.0
+    assert g["mg.readback"][0] == g["mg.round.wait"][0]
 
 
 def test_round_parts_tile_the_round(served):
@@ -240,9 +255,10 @@ class _SlowArray:
 
 
 def test_devledger_bills_the_wait_for_valid_to_the_round(monkeypatch):
-    """``valid`` is the first read-back after the jitted call: its
-    wait is block time of ``multiraft.round``, the wall of
-    ``mg.round.wait`` and host-blocked ``device`` seconds of it."""
+    """The pack (``valid`` its first row) is the round's one
+    read-back: its wait is block time of ``multiraft.round``, the wall
+    of ``mg.round.wait`` and host-blocked ``device`` seconds of it,
+    and one sample of ``mg.readback``."""
     from etcd_tpu.raft import multiraft
 
     mr = multiraft.MultiRaft(8, 3, 32)
@@ -250,9 +266,8 @@ def test_devledger_bills_the_wait_for_valid_to_the_round(monkeypatch):
     real = multiraft._fused_round_hot
 
     def slow_round(*a, **kw):
-        states, newly, valid, base, overflow, conflict = real(*a, **kw)
-        return (states, newly, _SlowArray(valid, 0.05), base, overflow,
-                conflict)
+        states, pack = real(*a, **kw)
+        return states, _SlowArray(pack, 0.05)
 
     monkeypatch.setattr(multiraft, "_fused_round_hot", slow_round)
     block = metrics.registry.counter(
@@ -261,6 +276,7 @@ def test_devledger_bills_the_wait_for_valid_to_the_round(monkeypatch):
         "etcd_stage_seconds", stage="mg.round.wait", kind="device")
     b0, d0, w0 = block.get(), dev.sum, wall().get("mg.round.wait",
                                                   (0, 0.0))
+    r0 = wall().get("mg.readback", (0, 0.0))
     with tracer.stage("outer.round"):
         mr.propose(np.ones(8, np.int32))
     assert mr.last_valid.all()
@@ -268,6 +284,8 @@ def test_devledger_bills_the_wait_for_valid_to_the_round(monkeypatch):
     assert dev.sum - d0 >= 0.05
     w1 = wall()["mg.round.wait"]
     assert w1[0] == w0[0] + 1 and w1[1] - w0[1] >= 0.05
+    r1 = wall()["mg.readback"]
+    assert r1[0] == r0[0] + 1 and r1[1] - r0[1] >= 0.05
     # and the enclosing stage's device column holds its children's
     outer = metrics.registry.histogram(
         "etcd_stage_seconds", stage="outer.round", kind="device")

@@ -404,7 +404,7 @@ def _gathers(jaxpr, out: list) -> list:
 def _round_args(g: int, m: int, cap: int):
     st = init_groups(g, m, cap)
     return (tuple(st for _ in range(m)), jnp.zeros((g,), jnp.int32),
-            jnp.zeros((g,), jnp.int32), jnp.zeros((m, m, g), bool))
+            jnp.zeros((2, g), jnp.int32), jnp.zeros((m, m, g), bool))
 
 
 @pytest.mark.parametrize("program", ["hot", "general", "append",
@@ -416,15 +416,16 @@ def test_no_program_gathers_a_window_element_by_element(monkeypatch,
     lookups, one element a group; a window is read with selects."""
     monkeypatch.setenv("ETCD_APPEND_WRITE", "scatter")
     g, m, cap, e = 256, 3, 128, 8
-    states, leader, n_new, drop = _round_args(g, m, cap)
+    states, leader, inp, drop = _round_args(g, m, cap)
+    n_new = inp[0]
     if program == "hot":
         jaxpr = jax.make_jaxpr(
             lambda *a: multiraft._fused_round_hot(*a, e=e, slot=0))(
-                states, leader == 0, n_new, drop)
+                states, leader == 0, inp, drop)
     elif program == "general":
         jaxpr = jax.make_jaxpr(
             lambda *a: multiraft._fused_round(*a, e=e))(
-                states, leader, n_new, drop)
+                states, leader, inp, drop)
     elif program == "append":
         jaxpr = jax.make_jaxpr(
             lambda *a: batched._maybe_append_jit(
