@@ -131,6 +131,12 @@ class AppendPipeline:
     def inflight(self, peer: int) -> int:
         return len(self._peers[peer].inflight)
 
+    def inflight_stripe(self, peer: int, stripe: int) -> int:
+        """Frames of one stripe in the peer's window: each will ack
+        (or fail) and re-pump the peer."""
+        return sum(m.stripe == stripe
+                   for m in self._peers[peer].inflight.values())
+
     def inflight_entries(self, peer: int) -> int:
         """Entries (not frames) in the peer's window — how much the
         multi-group fusion amortizes each frame's fixed cost."""

@@ -502,7 +502,7 @@ class DistServer:
         # _prev_lead (refreshed each round), hint mirrors the round
         # loop's fetch, membership refreshes on conf change/install
         self._hint_np = np.full(g, -1, np.int64)
-        self._read_nudge_t = 0.0
+        self._read_nudge_t: dict[int, float] = {}  # by stripe
         self._wait_expire_at = 0.0  # wait-point sweep cadence gate
         # namespace -> group cache: group_of is a sha1 per call and
         # the read lane routes tens of thousands of keys/s over a
@@ -1482,11 +1482,14 @@ class DistServer:
         pending read whose registration a completed quorum round (or
         a valid lease) now covers.  Rides the ack-absorb and round
         paths, so confirmation piggybacks on frames that were going
-        out anyway."""
+        out anyway.  A sweep that had reads pending files its own
+        time as ``dist.read_release`` and, for each read it released,
+        the read's wait since it was queued as ``dist.read_confirm``."""
         if not self._reads.pending:
             return
+        t_sweep = time.monotonic()
         if now is None:
-            now = time.monotonic()
+            now = t_sweep
         basis = self.lease.basis(self._members_np,
                                  self._nmembers_np, now)
         released = self._reads.release(
@@ -1500,18 +1503,31 @@ class DistServer:
                 sum(pr.n for pr, _path, _rd in released))
             for pr, path, rd in released:
                 pr.ch.close((path, rd))
+        t_end = time.monotonic()
+        for pr, _path, _rd in released:
+            # the waiter's wake-up is not in it: etcd_read_rtt_seconds
+            # runs on to the serve
+            tracer.record_wait("dist.read_confirm", t_end - pr.t_reg)
+        tracer.record_wait("dist.read_release", t_end - t_sweep)
 
-    def _nudge_reads(self, now: float) -> None:
-        """A read registered without lease cover (call with
-        self.lock held): arm one out-of-cadence heartbeat per
-        stripe (see _pump_peer) and poke the round loop so the
-        confirmation round leaves promptly instead of at the next
-        tick boundary.  The poke dedups at 1 ms so a single-read
-        burst during a leaderless window can't flood the queue with
-        wakes (each registered read would otherwise add one)."""
-        if now - self._read_nudge_t > 0.001:
-            self._queue.put(None)  # drain treats None as a bare wake
-        self._read_nudge_t = now
+    def _nudge_reads(self, now: float, groups) -> None:
+        """Reads of ``groups`` registered without lease cover (call
+        with self.lock held): arm one out-of-cadence heartbeat for
+        each of their stripes (see _pump_peer) and, to a peer that
+        has no frame of the stripe in flight, send it NOW, on the
+        caller's thread.  Where one is in flight its ack re-pumps
+        the peer, and that frame is later than every read
+        registered meanwhile: the reads of a whole round trip ride
+        one confirmation.  (The nudge used to wake the round thread
+        through its queue, one bare wake a read deduplicated at
+        1 ms: each cost a whole idle iteration under self.lock, and
+        the wakes of 16 readers stood in front of every write.)"""
+        for stripe in {gi % self._n_stripes for gi in groups}:
+            self._read_nudge_t[stripe] = now
+            for peer in range(self.m):
+                if peer != self.slot and not self.pipe.inflight_stripe(
+                        peer, stripe):
+                    self._pump_peer(peer)
 
     def _await_read(self, ch: Chan, timeout: float | None,
                     path_hint: str, t0: float):
@@ -1556,6 +1572,8 @@ class DistServer:
         ch = None
         path = "lease"
         with self.lock:
+            tracer.record_wait("dist.read_lock",
+                               time.monotonic() - t0)
             if self.done.is_set():
                 raise ServerStoppedError()
             led = bool(self._prev_lead[gi])
@@ -1564,7 +1582,7 @@ class DistServer:
                     ch = Chan()
                     self._reads.register(gi, t0,
                                          int(self.applied[gi]), ch)
-                    self._nudge_reads(t0)
+                    self._nudge_reads(t0, (gi,))
             else:
                 leader = int(self._hint_np[gi])
         if not led:
@@ -1661,7 +1679,7 @@ class DistServer:
             ch = Chan()
             self._reads.register(gi, t0, int(self.applied[gi]), ch,
                                  kind="rd")
-            self._nudge_reads(t0)
+            self._nudge_reads(t0, (gi,))
         return int(self._await_read(ch, timeout, "read_index",
                                     t0)[1])
 
@@ -1755,7 +1773,7 @@ class DistServer:
                 if group_chans:
                     self._read_release(now)
                     if self._reads.pending:
-                        self._nudge_reads(now)
+                        self._nudge_reads(now, group_chans)
         if fast:
             self._count_read("lease", "ok", n=len(fast))
             # batch-granular RTT sample: every read in the batch
@@ -2477,9 +2495,18 @@ class DistServer:
                     # out-of-cadence heartbeat per stripe: its ack
                     # is the quorum round the queued reads piggyback
                     # on (last >= nudge time means this stripe
-                    # already sent its post-registration frame)
+                    # already sent its post-registration frame).
+                    # While a frame of the stripe is in flight the
+                    # nudge waits for its ack, whose re-pump sends
+                    # ONE frame for every read registered meanwhile
+                    # (a frame a nudge filled the window, and a read
+                    # then waited behind eight [G]-wide frames at
+                    # the follower); the cadence is never held back
                     due = (now - last >= self._hb_interval
-                           or last < self._read_nudge_t)
+                           or (last < self._read_nudge_t.get(stripe,
+                                                             0.0)
+                               and not self.pipe.inflight_stripe(
+                                   peer, stripe)))
                     if not (adv or due):
                         break
                 meta = self.pipe.register(
@@ -2657,9 +2684,6 @@ class DistServer:
             self.flight.record("frame", t=t1, dir="ack", peer=peer,
                                seq=resp.seq)
             self._traced_send.pop((peer, resp.seq), None)
-        with tracer.stage("dist.absorb"), \
-                _ledger.dispatch("dist.absorb"):
-            mr.handle_append_resp(resp)
         active = np.asarray(resp.active)
         ok = np.asarray(resp.ok)
         # lease / ReadIndex evidence (PR 7): count only active & OK
@@ -2672,6 +2696,18 @@ class DistServer:
         # cur-but-rejected lanes (probe catch-up) don't renew —
         # conservative: the quorum's healthy members carry the basis.
         self.lease.note_ack(peer, meta.t0, active & ok)
+        # the ack may have advanced the quorum basis past pending
+        # reads' registration times — the batched release sweep
+        # rides the ack path, not a timer, and comes FIRST: the
+        # evidence is the response's own fields, so a confirmed
+        # read does not wait for the engine's absorb, the apply and
+        # the re-pump below (a lane the response deposes is not in
+        # ``active & ok``, and the sweep gates on the round's view
+        # of leadership as it always did)
+        self._read_release()
+        with tracer.stage("dist.absorb"), \
+                _ledger.dispatch("dist.absorb"):
+            mr.handle_append_resp(resp)
         if (active & ~ok).any():
             # follower found a gap (dropped or out-of-order frame):
             # next_ was repaired from its commit hint; collapse to
@@ -2689,10 +2725,6 @@ class DistServer:
         with tracer.stage("dist.apply"):
             self._apply_committed(self._assigned)
         self._pump_peer(peer)
-        # the ack may have advanced the quorum basis past pending
-        # reads' registration times — the batched release sweep
-        # rides the ack path, not a timer
-        self._read_release()
 
     def _campaign(self, mask: np.ndarray) -> None:
         """Batched election round-trip for the fired lanes."""

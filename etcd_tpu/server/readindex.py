@@ -44,6 +44,7 @@ current term — leader-completeness gating, raft thesis §6.4) and
 
 from __future__ import annotations
 
+import time
 from collections import deque
 from heapq import heapify, heappop, heappush
 
@@ -118,10 +119,11 @@ class PendingRead:
     was a stage-table line), so release sweeps weight their batch
     metric by ``n``, not the queue length."""
 
-    __slots__ = ("t0", "required", "ch", "kind", "n")
+    __slots__ = ("t0", "t_reg", "required", "ch", "kind", "n")
 
     def __init__(self, t0: float, required: int, ch, kind: str):
-        self.t0 = t0            # registration time (monotonic)
+        self.t0 = t0            # arrival (monotonic): the basis passes it
+        self.t_reg = time.monotonic()  # queued: dist.read_confirm's start
         self.required = required  # leader applied at registration
         self.ch = ch            # utils.wait.Chan
         self.kind = kind        # "read" | "rd" (follower RPC)

@@ -195,16 +195,20 @@ def test_cli_serves_three_members_with_three_wals_and_restarts(tmp_path):
         again.stop()
 
 
-def test_the_quorum_is_real(tmp_path):
+@pytest.mark.parametrize("lease_ticks", [30, 0],
+                         ids=["lease", "lease-off"])
+def test_the_quorum_is_real(tmp_path, lease_ticks):
     """Members built and started by the CLI's own functions: with one
-    follower stopped a write is still acknowledged, with both stopped
-    none is, whatever the leader holds itself."""
+    follower stopped a write is still acknowledged and a default GET
+    still answered, with both stopped no write is, whatever the
+    leader holds itself — and with the lease off (``--dist-lease-ticks
+    0``) no read either: it times out, it is not served."""
     from etcd_tpu.server.server import gen_id
 
     g = 8
     servers = cli.local_dist_members(
         str(tmp_path), 3, name="q", g=g, cap=64,
-        election=60, lease_ticks=30, storage_backend="tpu")
+        election=60, lease_ticks=lease_ticks, storage_backend="tpu")
     assert [os.path.basename(s.data_dir) for s in servers] == [
         "slot0", "slot1", "slot2"]
     stopped = []
@@ -213,6 +217,11 @@ def test_the_quorum_is_real(tmp_path):
         return servers[0].do(Request(
             method="PUT", id=gen_id(), path=f"/t{i}/cfg", val=f"v{i}"),
             timeout=timeout)
+
+    def get(i: int, timeout: float):
+        return servers[0].do(Request(
+            method="GET", id=gen_id(), path=f"/t{i}/cfg"),
+            timeout=timeout).event.node.value
 
     try:
         cli.start_dist_members(servers)
@@ -226,8 +235,13 @@ def test_the_quorum_is_real(tmp_path):
         stopped.append(servers[2])
         for i in range(g, 2 * g):     # 2 of 3 copies: acknowledged
             assert put(i, 5.0).event.node.value == f"v{i}"
+            assert get(i, 5.0) == f"v{i}"   # ... and confirmed
         assert servers[1].stop()
         stopped.append(servers[1])
+        if not lease_ticks:
+            # no quorum answers and there is no lease: fail closed
+            with pytest.raises(TimeoutError):
+                get(0, 1.0)
         for i in range(2 * g, 2 * g + 3):
             with pytest.raises(TimeoutError):
                 put(i, 0.5)           # the server's request timeout

@@ -422,3 +422,123 @@ def test_stats_reads_by_path_split():
     with pytest.raises(KeyError):
         s.inc_read_path("typo_path")
     assert Stats.from_dict(d).reads_by_path["lease"] == 4
+
+
+# -- the lease off (--dist-lease-ticks 0): every read a quorum round ---------
+
+G_OFF = 64
+N_OPS = 120
+READ_WAITS = ("dist.read_lock", "dist.read_confirm", "dist.read_release")
+
+
+def _wall() -> dict[str, int]:
+    """``{stage: count}`` of ``etcd_stage_seconds{kind=wall}``
+    (``tests/test_stage_coverage.py``'s form)."""
+    fam = _obs.registry.snapshot(light=True).get(
+        "etcd_stage_seconds", {"samples": []})
+    return {c["labels"]["stage"]: c["count"] for c in fam["samples"]
+            if c["labels"]["kind"] == "wall"}
+
+
+def _lease_serves() -> float:
+    fam = _obs.registry.snapshot(light=True)["etcd_read_serve_total"]
+    return sum(c["value"] for c in fam["samples"]
+               if c["labels"]["path"] == "lease")
+
+
+@pytest.fixture(scope="module")
+def lease_off(tmp_path_factory):
+    """A 64-group cluster of three with ``lease_ticks=0`` and, once a
+    member, a seeded sequence of PUTs through slot 0 and default GETs
+    through that member, each answer kept beside what a dict holds."""
+    import random
+
+    servers, _ = make_dist_cluster(tmp_path_factory.mktemp("leaseoff"),
+                                   g=G_OFF, lease_ticks=0)
+    try:
+        bootstrap_dist_leader(servers)
+        lease0, wall0 = _lease_serves(), _wall()
+        seen: dict[int, list] = {}
+        gets: dict[int, int] = {}
+        for through in (0, 1):
+            rng = random.Random(2_200_000_935 + through)
+            model: dict[str, str] = {}
+            seen[through], gets[through] = [], 0
+            for n in range(N_OPS):
+                key = f"/t{rng.randrange(24):05d}/cfg{through}"
+                if rng.random() < 0.4:
+                    model[key] = f"{through}.{n}"
+                    put(servers[0], key, model[key])
+                    continue
+                gets[through] += 1
+                try:
+                    got = get(servers[through], key,
+                              timeout=10.0).event.node.value
+                except EtcdError:
+                    got = None             # key not found
+                seen[through].append((key, got, model.get(key)))
+        wall1 = _wall()
+        yield {"servers": servers, "seen": seen, "gets": gets,
+               "lease_serves": _lease_serves() - lease0,
+               "grown": {k: wall1.get(k, 0) - wall0.get(k, 0)
+                         for k in READ_WAITS}}
+    finally:
+        for s in servers:
+            s.stop()
+
+
+@pytest.mark.parametrize("through", [0, 1], ids=["leader", "follower"])
+def test_lease_off_reads_agree_with_a_dict(lease_off, through):
+    seen = lease_off["seen"][through]
+    assert len(seen) > N_OPS // 3
+    assert [(k, got) for k, got, _ in seen] == [
+        (k, want) for k, _, want in seen]
+
+
+def test_lease_off_serves_no_read_by_lease(lease_off):
+    assert lease_off["lease_serves"] == 0
+    assert all(s._lease_s == 0 for s in lease_off["servers"])
+
+
+@pytest.mark.parametrize("wait", READ_WAITS)
+def test_lease_off_records_the_read_s_waits(lease_off, wait):
+    """Every default GET files its wait for the member's lock (the
+    follower's too); every registered read (the leader's own and the
+    follower's read-index call) its wait for the confirmation; every
+    sweep that released one its own time.  A wait is one wall sample:
+    no cpu column, no span."""
+    n = lease_off["grown"][wait]
+    if wait == "dist.read_release":
+        assert n >= 1
+    else:
+        assert n == sum(lease_off["gets"].values())
+    fam = _obs.registry.snapshot(light=True)["etcd_stage_seconds"]
+    assert {c["labels"]["kind"] for c in fam["samples"]
+            if c["labels"]["stage"] == wait} == {"wall"}
+
+
+def test_lease_off_read_fails_closed_with_both_links_cut(lease_off):
+    """No quorum answers: the GET times out, is counted ``timeout``
+    and is never served — not from the lease (there is none), not from
+    local state."""
+    from etcd_tpu.utils import faults
+
+    leader = lease_off["servers"][0]
+    put(leader, "/t00001/closed", "v1")
+    assert get(leader, "/t00001/closed",
+               timeout=10.0).event.node.value == "v1"
+    ok0 = _ctr("read_index", "ok")
+    out0, lease0 = _ctr("read_index", "timeout"), _lease_serves()
+    faults.FAULTS.configure("peerlink.send[s0->*]=drop()")
+    try:
+        with pytest.raises(TimeoutError):
+            get(leader, "/t00001/closed", timeout=1.0)
+    finally:
+        faults.FAULTS.configure("")
+    assert _ctr("read_index", "timeout") == out0 + 1
+    assert _ctr("read_index", "ok") == ok0
+    assert _lease_serves() == lease0
+    # healed: the same read confirms again
+    wait_for(lambda: get(leader, "/t00001/closed", timeout=5.0)
+             .event.node.value == "v1", timeout=30.0,
+             msg="a confirmed read after the links heal")
