@@ -47,26 +47,53 @@ _MASK32 = 0xFFFFFFFF
 # -- host-side constant construction ----------------------------------------
 
 
+def _byte_tables(m: np.ndarray) -> np.ndarray:
+    """[4, 256] uint32: ``t[j][b] = m @ (b << 8j)`` for a ``[32, 32]``
+    GF(2) operator ``m`` — evaluates ``m @ x`` over a whole uint32
+    array with four table lookups (:func:`_apply_byte_tables`)."""
+    cols = gf2.from_bits(m.T).reshape(4, 1, 8)  # image of unit bit 8j+k
+    byte_bits = gf2.to_bits(np.arange(256))[:, :8].astype(bool)
+    return np.bitwise_xor.reduce(
+        np.where(byte_bits[None], cols, np.uint32(0)), axis=2)
+
+
+def _apply_byte_tables(t: np.ndarray, x: np.ndarray) -> np.ndarray:
+    return (t[0, x & 0xFF] ^ t[1, (x >> 8) & 0xFF]
+            ^ t[2, (x >> 16) & 0xFF] ^ t[3, x >> 24])
+
+
+@functools.lru_cache(maxsize=None)
+def _packed_contributions(k: int) -> np.ndarray:
+    """``contribution_matrix(2**k)`` with each row packed into one
+    uint32 (bit j = column j).  A row depends only on its byte's
+    distance from the RIGHT end, so the left half of a buffer twice as
+    wide is the right half pushed through ``2**(k-1)`` zero bytes:
+    ``C(2n) = [Z^n C(n) ; C(n)]`` — k doublings, each four table
+    lookups over the half."""
+    if k == 0:
+        # the state after one byte with only bit b set, from zero
+        return _host.TABLE[1 << np.arange(8)].astype(np.uint32)
+    half = _packed_contributions(k - 1)
+    pushed = _apply_byte_tables(_byte_tables(gf2._POWERS[k - 1]), half)
+    return np.concatenate([pushed, half])
+
+
 @functools.lru_cache(maxsize=16)
 def contribution_matrix(length: int) -> np.ndarray:
     """``[8*length, 32]`` int8 matrix C: bits(row) @ C == raw CRC.
 
     Row ``8*i + k`` is the raw-CRC contribution of bit ``k`` (LSB
     first) of byte ``i`` (byte 0 = leftmost / most-padded position).
-    Built by walking positions right-to-left with an accumulated
-    zero-byte operator, so construction is O(L) 32x32 GF(2) matmuls.
+    ``C(L)`` is the last ``8L`` rows of ``C(n)`` for any ``n >= L``:
+    the suffix of the power of two above, built by doubling on packed
+    rows (:func:`_packed_contributions`, shared by every width) and
+    expanded to bits once.  Cached and read-only.
     """
-    # T8[:, k] = bits of TABLE[1 << k]: the state after one byte with
-    # only bit k set, from a zero state.
-    t8 = np.zeros((32, 8), dtype=np.uint8)
-    for k in range(8):
-        t8[:, k] = gf2.to_bits(np.uint32(_host.TABLE[1 << k]))
-    c = np.zeros((8 * length, 32), dtype=np.int8)
-    acc = gf2.identity()  # Z^(L-1-i) as i walks right-to-left
-    for i in range(length - 1, -1, -1):
-        block = gf2.matmul(acc, t8)  # [32, 8]
-        c[8 * i:8 * i + 8, :] = block.T
-        acc = gf2.matmul(gf2.Z1, acc)
+    words = _packed_contributions(max(length - 1, 0).bit_length())
+    words = words[words.size - 8 * length:].astype("<u4", copy=False)
+    c = np.unpackbits(words.view(np.uint8), bitorder="little").view(
+        np.int8).reshape(8 * length, 32)
+    c.setflags(write=False)
     return c
 
 
@@ -149,9 +176,9 @@ def raw_crc_batch(buf, use_pallas: bool | None = None,
     ``buf`` is ``[N, L]`` uint8 with each record's bytes occupying the
     *rightmost* ``len`` columns and zeros elsewhere.  ``c`` is the
     uploaded ``contribution_matrix(L)`` where the caller has it (the
-    replay times its build and upload as a stage of its own); it is
-    built on the host once per width and uploaded on every call, 32
-    MiB for the 131072-byte class.
+    replay times its build and upload as a stage of its own); without
+    it the cached host matrix is uploaded on every call, 32 MiB for
+    the 131072-byte class.
     """
     buf = jnp.asarray(buf, dtype=jnp.uint8)
     if c is None:
@@ -252,14 +279,8 @@ def chain_verify_device(seed: int, stored, raw, lens,
 
 @functools.lru_cache(maxsize=1)
 def _z4inv_tables() -> np.ndarray:
-    """[4, 256] uint32: t[k][b] = Z4^-1 @ (b << 8k) — evaluates
-    Z4^-1 @ x with 4 byte-table lookups."""
-    z4inv = gf2.inverse(gf2.zero_operator(4))
-    t = np.empty((4, 256), np.uint32)
-    for k in range(4):
-        for b in range(256):
-            t[k, b] = gf2.matvec(z4inv, b << (8 * k))
-    return t
+    """[4, 256] uint32 byte tables of Z4^-1 (:func:`_byte_tables`)."""
+    return _byte_tables(gf2.inverse(gf2.zero_operator(4)))
 
 
 def inject_seeds(rows: np.ndarray, lens, prev) -> np.ndarray:
@@ -282,8 +303,7 @@ def inject_seeds(rows: np.ndarray, lens, prev) -> np.ndarray:
                          f"{int(lens.max())} + 4 > width {w}")
     t = _z4inv_tables()
     x = np.asarray(prev, np.uint32) ^ np.uint32(_MASK32)
-    y = (t[0, x & 0xFF] ^ t[1, (x >> 8) & 0xFF]
-         ^ t[2, (x >> 16) & 0xFF] ^ t[3, (x >> 24) & 0xFF])
+    y = _apply_byte_tables(t, x)
     cols = (w - lens - 4)[:, None] + np.arange(4)
     vals = (y[:, None] >> (8 * np.arange(4, dtype=np.uint32))
             ).astype(np.uint8)

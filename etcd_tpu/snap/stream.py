@@ -27,11 +27,13 @@ snapshot analog of PR 3's streaming replay lane:
   (0.06 ms a 256 KiB chunk); the GF(2) seed-stitched device form
   (ops/crc_device.inject_seeds → one raw-CRC matmul + compare) is
   there for a caller that names it, and is not the default even on
-  an accelerator: it builds ``contribution_matrix(chunk + 4)`` in
-  Python (≈ 11 s for a 256 KiB chunk, a width of its own for the
-  tail chunk) on the interpreter the member serves with, so on the
-  v5e a follower's 1.3 MB pull had not ended after 80 s and the
-  cluster ran at 40 % meanwhile (PERF.md, PR 30).
+  an accelerator: for a digest the host takes in 0.06 ms it uploads
+  ``contribution_matrix(chunk + 4)`` on every call (64 MiB for a
+  256 KiB chunk) and compiles a program a width (the tail chunk has
+  one of its own), on the interpreter the member serves with.  It
+  has not been timed on the chip since the matrix is built by
+  doubling (PERF.md, PR 36); the pull PR 30 timed there waited for
+  a matrix built in a Python loop.
 
 Nothing here persists partial state: the assembled blob exists only
 in memory until the caller's install commits, so a receiver crash

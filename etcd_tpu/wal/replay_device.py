@@ -183,8 +183,11 @@ def _accelerator_absent() -> bool:
 
 def _raw_crc_rows(rows):
     """``raw_crc_batch(rows)`` with the build and upload of the rows'
-    contribution matrix as the replay's stage ``replay.matrix``: a
-    width's first build is seconds of host work at the wide classes."""
+    contribution matrix as the replay's stage ``replay.matrix``.  The
+    host matrix is cached (``contribution_matrix``); every shipment
+    uploads it anew — 9 ms for the 32 MiB of the 131072-byte class on
+    a v5e's host, behind ``jnp.asarray``'s return — so the serving
+    process keeps no copy on the device (PERF.md section 6, PR 36)."""
     import jax.numpy as jnp
 
     from ..ops.crc_device import contribution_matrix, raw_crc_batch

@@ -94,9 +94,11 @@ def test_a_follower_lane_cuts_at_its_applied_index(cluster):
 
 
 def test_the_snapshot_stream_verifies_on_the_host_by_default():
-    """The device form builds ``contribution_matrix(chunk + 4)`` in
-    Python on the serving interpreter (module docstring of
-    ``snap/stream.py``): a caller has to name it."""
+    """The device form uploads ``contribution_matrix(chunk + 4)``
+    (64 MiB for a 256 KiB chunk) on every call and compiles a program
+    a width, on the serving interpreter, for a digest the host takes
+    in 0.06 ms (module docstring of ``snap/stream.py``): a caller has
+    to name it."""
     assert ChunkVerifier().route == "host"
     meta = {"n_chunks": 0, "size": 0, "chunk_bytes": 4, "crcs": [],
             "id": "x"}

@@ -6,6 +6,8 @@ value the device computes must agree bit-for-bit with the sequential
 host digest, and every corruption must be detected.
 """
 
+import time
+
 import numpy as np
 import pytest
 
@@ -52,6 +54,67 @@ def test_pallas_interpret_parity(records):
     c = np.asarray(crc_device.contribution_matrix(buf.shape[1]))
     dev = np.asarray(raw_crc_pallas(buf, c, interpret=True))
     assert np.array_equal(dev, host)
+
+
+# T8[:, k] = bits of TABLE[1 << k]: the state after one byte with only
+# bit k set, from a zero state.
+_T8 = gf2.to_bits(crc32c.TABLE[1 << np.arange(8)]).T
+
+
+def _contribution_matrix_by_walking(length: int) -> np.ndarray:
+    """The oracle: the construction the product used until PR 36,
+    position by position from the right with an accumulated zero-byte
+    operator — two 32x32 GF(2) matmuls a byte."""
+    t8 = _T8
+    c = np.zeros((8 * length, 32), dtype=np.int8)
+    acc = gf2.identity()  # Z^(L-1-i) as i walks right-to-left
+    for i in range(length - 1, -1, -1):
+        block = gf2.matmul(acc, t8)  # [32, 8]
+        c[8 * i:8 * i + 8, :] = block.T
+        acc = gf2.matmul(gf2.Z1, acc)
+    return c
+
+
+@pytest.mark.parametrize("length", [1, 2, 3, 7, 8, 9, 127, 128, 129, 384,
+                                    512, 1000, 2048, 4100, 16384])
+def test_contribution_matrix_equals_the_walked_oracle(length):
+    c = crc_device.contribution_matrix(length)
+    want = _contribution_matrix_by_walking(length)
+    assert c.shape == want.shape == (8 * length, 32)
+    assert c.dtype == want.dtype == np.int8
+    assert np.array_equal(c, want)
+
+
+def test_contribution_matrix_is_a_read_only_suffix_and_quick_when_wide():
+    # C(L) is the last 8L rows of C(n) for a power of two n above L
+    for length, n in ((3, 4), (100, 128), (384, 512), (513, 1024),
+                      (4100, 8192), (4100, 16384)):
+        wide = crc_device.contribution_matrix(n)
+        assert np.array_equal(crc_device.contribution_matrix(length),
+                              wide[8 * (n - length):]), (length, n)
+    # cached and shared between callers: nobody may write to it
+    c = crc_device.contribution_matrix(512)
+    assert c is crc_device.contribution_matrix(512)
+    assert not c.flags.writeable
+    with pytest.raises(ValueError):
+        c[0, 0] = 1
+    # the replay's widest class: the walked loop needs ~6 s for it
+
+    def build_from_nothing() -> float:
+        crc_device.contribution_matrix.cache_clear()
+        crc_device._packed_contributions.cache_clear()
+        t0 = time.perf_counter()
+        crc_device.contribution_matrix(131072)
+        return time.perf_counter() - t0
+
+    # (the best of three: a neighbour's burst may slow one)
+    assert min(build_from_nothing() for _ in range(3)) < 2.0
+    big = crc_device.contribution_matrix(131072)
+    assert big.shape == (8 * 131072, 32) and big.dtype == np.int8
+    assert not big.flags.writeable
+    assert np.array_equal(big[-8:], _T8.T)  # the rightmost byte: Z^0
+    assert np.array_equal(
+        big[:8], gf2.matmul(gf2.zero_operator(131071), _T8).T)
 
 
 def test_shift_crc_matches_gf2(records):

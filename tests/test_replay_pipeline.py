@@ -304,6 +304,47 @@ def test_shipment_shapes_do_not_follow_the_record_count(tmp_path,
     assert len({w for _r, w in seen[0]}) == len(seen[0])
 
 
+class _CountingTransport(DeviceTransport):
+    """The real transport, with every shipment's width and stored
+    CRCs written down."""
+
+    def __init__(self):
+        self.shipments = []  # (width, stored)
+
+    def verify(self, shipped, stored):
+        self.shipments.append((shipped.shape[1], stored.copy()))
+        return super().verify(shipped, stored)
+
+
+def test_stream_verifies_every_width_class_from_one_matrix_build(
+        tmp_path):
+    """Records of three width classes (384, 512 and 4096), the widest
+    in three shipments or more: each class's contribution matrix is a
+    suffix of one doubling (ops/crc_device.py), and the suffix rule
+    has to hold through the CRC primitive's own path, not in numpy
+    alone — the replay verifies, and refuses a flipped bit in a record
+    of the widest class's SECOND shipment by that record's number."""
+    sizes = [(300, 450, 3000)[i % 3] for i in range(120)]
+    blob = _wal_blob(tmp_path / "wal", n_entries=120, cuts=(),
+                     sizes=sizes)
+    full = native.wal_scan(blob)
+    tr = _CountingTransport()
+    got = stream_scan_verify(blob, route="stream", chunk_bytes=1 << 15,
+                             transport=tr)
+    _assert_arrays_equal(full, got)
+    # (the metadata and crc records make a fourth class, 128)
+    assert {w for w, _ in tr.shipments} >= {384, 512, 4096}
+    wide = [st for w, st in tr.shipments if w == 4096]
+    assert len(wide) >= 3
+    _t, crcs, doff, dlen, *_ = full
+    victim = int(np.nonzero(crcs == wide[1][0])[0][0])
+    assert dlen[victim] > 2048
+    bad = blob.copy()
+    bad[int(doff[victim]) + int(dlen[victim]) - 3] ^= 0x01
+    with pytest.raises(CRCMismatchError, match=f"at record {victim} "):
+        stream_scan_verify(bad, route="stream", chunk_bytes=1 << 15)
+
+
 @pytest.mark.parametrize("w, chunk_bytes, budget, rows", [
     (128, 4 << 20, 1 << 28, 1 << 16),     # 8 MiB of rows
     (384, 4 << 20, 1 << 28, 1 << 14),
