@@ -127,29 +127,44 @@ def build_parser() -> argparse.ArgumentParser:
                         "--listen-client-urls serves slot 0; "
                         "exclusive with --dist-slot/--dist-peers "
                         "(0 = off)")
+    p.add_argument("--dist-local-link-delay-ms", default="",
+                   metavar="A-B:MS[,...]",
+                   help="With --dist-local-cluster: the ONE-WAY delay "
+                        "in milliseconds of the peer link between "
+                        "slots A and B, a pair each (e.g. "
+                        "0-1:10,0-2:100,1-2:100: slots 0 and 1 a "
+                        "20 ms round trip apart, slot 2 200 ms from "
+                        "both); a pair not named has none. Every "
+                        "peer frame and response between the two is "
+                        "held that long by a delay line that does "
+                        "not serialise the link (a window of frames "
+                        "crosses in one delay); no jitter, loss or "
+                        "bandwidth limit")
     p.add_argument("--dist-mesh-devices", type=int, default=0,
                    help="Shard this host's group batch over its first "
                         "N local devices (intra-host tier composed "
                         "under the cross-host tier; --cohosted-groups "
                         "must divide by the mesh's group axis; 0 = "
                         "single device)")
-    # default 60 ticks (3s at the 0.05s tick): wide enough for every
+    # default 60 ticks (6s at the 0.1s tick): wide enough for every
     # supported host count's stratified bands and the jit-compile
     # first round; the timeout-bands lint checker guards this default
     # against the members default, and start_dist re-checks it
     # against the actual --dist-peers count (the DistMember clamp
     # would silently stretch a too-small value)
     p.add_argument("--dist-election-ticks", type=int, default=60,
-                   help="Election timeout in ticks for --dist-slot "
-                        "mode; must be >= the number of --dist-peers "
-                        "hosts so per-slot election bands stay "
-                        "disjoint")
+                   help="Election timeout in ticks of 0.1 s for the "
+                        "dist modes (default 60 = 6 s; a heartbeat "
+                        "goes every tick); must be >= the number of "
+                        "--dist-peers hosts so per-slot election "
+                        "bands stay disjoint")
     # lease band (PR 7): the lease-band lint rule guards this
     # default against --dist-election-ticks (lease < election -
     # drift), and start_dist re-checks the actual values the same
     # way DistServer will
     p.add_argument("--dist-lease-ticks", type=int, default=30,
-                   help="Leader-lease length in ticks for "
+                   help="Leader-lease length in ticks of 0.1 s "
+                        "(default 30 = 3 s) for "
                         "linearizable reads (must be < "
                         "--dist-election-ticks minus the clock-"
                         "drift margin; 0 disables the lease — "
@@ -265,6 +280,31 @@ def bind_loopback(n: int) -> list:
     return socks
 
 
+def parse_link_delays(spec: str, m: int) -> dict[tuple[int, int], float]:
+    """``--dist-local-link-delay-ms``'s ``A-B:MS[,...]`` as unordered
+    slot pair -> one-way delay in SECONDS, for a cluster of ``m``
+    slots.  ValueError says what is wrong with a pair."""
+    out: dict[tuple[int, int], float] = {}
+    for part in filter(None, (x.strip() for x in spec.split(","))):
+        try:
+            pair, ms = part.split(":")
+            a, b = sorted(int(x) for x in pair.split("-"))
+            delay = float(ms)
+        except ValueError:
+            raise ValueError(
+                f"{part!r} is not A-B:MS (two slots and a delay in "
+                f"milliseconds)") from None
+        if a == b or a < 0 or b >= m:
+            raise ValueError(
+                f"{part!r} names no pair of the slots 0..{m - 1}")
+        if not 0 <= delay < float("inf"):
+            raise ValueError(f"{part!r}: a delay is >= 0 ms")
+        if (a, b) in out:
+            raise ValueError(f"{part!r} states slots {a}-{b} twice")
+        out[a, b] = delay / 1000.0
+    return out
+
+
 def dist_member(data_dir: str, slot: int, peers: list[str], **kw):
     """Member ``slot`` of the cluster ``peers`` names, on a data
     directory of its own: the one call of the ``DistServer``
@@ -277,6 +317,7 @@ def dist_member(data_dir: str, slot: int, peers: list[str], **kw):
 
 def local_dist_members(root: str, peers: list[str] | int, *,
                        name: str | None = None, mesh_of=None,
+                       link_delays: dict | None = None,
                        **kw) -> list:
     """Every member slot of a cluster in THIS process, not started:
     member i keeps ``<root>/slot<i>`` with its own WAL, snapshots and
@@ -284,7 +325,10 @@ def local_dist_members(root: str, peers: list[str] | int, *,
     in for the hosts or their network.  ``peers`` is the slot-indexed
     URL list, or the number of members: then each gets a loopback
     port bound here and keeps the socket.  ``name`` and ``mesh_of``
-    give each member its own; ``kw`` is every member's."""
+    give each member its own; ``kw`` is every member's.
+    ``link_delays`` (:func:`parse_link_delays`) states a one-way
+    delay a pair of slots: each member gets its row, and its peer
+    links hold every frame and response that long."""
     socks = None
     if isinstance(peers, int):
         socks = bind_loopback(peers)
@@ -298,6 +342,10 @@ def local_dist_members(root: str, peers: list[str] | int, *,
             own["mesh"] = mesh_of(i)
         if socks is not None:
             own["peer_sock"] = socks[i]
+        if link_delays:
+            own["link_delay_s"] = {
+                a + b - i: d for (a, b), d in link_delays.items()
+                if i in (a, b)}
         members.append(dist_member(os.path.join(root, f"slot{i}"), i,
                                    peers, **own))
     return members
@@ -363,6 +411,19 @@ def start_dist(args, explicit: set[str]) -> int:
                       "slot-indexed URLs and --dist-slot within range")
             return 1
         n_peers = len(peers)
+    link_delays = None
+    if args.dist_local_link_delay_ms:
+        if not local:
+            log.error("--dist-local-link-delay-ms places the members "
+                      "of a --dist-local-cluster: between processes "
+                      "the network is the delay")
+            return 1
+        try:
+            link_delays = parse_link_delays(
+                args.dist_local_link_delay_ms, local)
+        except ValueError as e:
+            log.error("--dist-local-link-delay-ms: %s", e)
+            return 1
     if args.dist_election_ticks < n_peers:
         # the distmember election>=m clamp made mechanical at the
         # config surface: refuse rather than silently stretching the
@@ -428,7 +489,8 @@ def start_dist(args, explicit: set[str]) -> int:
             # listener gets a free loopback port, bound here and kept
             servers = local_dist_members(
                 data_dir, local, name=args.name,
-                mesh_of=lambda slot: mesh, **kw)
+                mesh_of=lambda slot: mesh, link_delays=link_delays,
+                **kw)
         else:
             servers = [dist_member(
                 data_dir, args.dist_slot, peers,

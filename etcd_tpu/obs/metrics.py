@@ -125,6 +125,36 @@ _DEFS = (
         "(PR 14): entries-per-frame is this over "
         "etcd_dist_pipeline_inflight.", labels=("peer",)),
     MetricDef(
+        "etcd_dist_inflight_at_send", "histogram",
+        "Frames already in the peer's in-flight window when an "
+        "ENTRY frame joins it (PR 38): 0 where every "
+        "acknowledgement is back before the next frame is due "
+        "(loopback), 1 or more over a link longer than a leader "
+        "pass.", labels=("peer",), buckets=SIZE_BUCKETS),
+    MetricDef(
+        "etcd_dist_thin_frame_holds_total", "counter",
+        "Times _pump_peer held an entry frame back because the "
+        "peer's window was busy and the frame carried fewer than "
+        "the minimum entries (the anti-fragmentation rule), counted "
+        "at every pump that holds; the held entries leave with the "
+        "re-pump that finds the window free, or a heartbeat "
+        "interval after the hold began, whichever first.",
+        labels=("peer",)),
+    MetricDef(
+        "etcd_dist_commit_advance_acks_total", "counter",
+        "Acknowledgements after whose absorb a lane's commit stood "
+        "past what was applied: the peer whose answer closed the "
+        "quorum (a round whose own fsync closed it counts for "
+        "nobody).", labels=("peer",)),
+    MetricDef(
+        "etcd_dist_peer_lag_entries", "histogram",
+        "Entries of the led lanes the peer has not acknowledged "
+        "(last - match, summed), sampled once a leader round that "
+        "appended, when the leader's own fsync has landed: the "
+        "round's own entries for a peer that keeps up, those of "
+        "the link's round trip more for one that trails.",
+        labels=("peer",), buckets=SIZE_BUCKETS),
+    MetricDef(
         "etcd_client_wire_requests_total", "counter",
         "Batch client requests by negotiated wire format (PR 14 "
         "binary client protocol; json is the compatibility "

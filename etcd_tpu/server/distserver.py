@@ -84,7 +84,7 @@ from ..wire.distmsg import (
 from ..wire.requests import Info, Request
 from .distpipe import AppendPipeline
 from .multigroup import TICK_INTERVAL, group_of
-from .peerlink import KeepAlivePool, PipeChannel
+from .peerlink import KeepAlivePool, PipeChannel, hold as link_hold
 from .readindex import (
     PATH_SERIALIZABLE,
     LeaseClock,
@@ -172,9 +172,20 @@ class DistServer:
                  coalesce_bytes: int = 1 << 20,
                  snap_keep: int | None = None,
                  lease_ticks: int | None = None,
-                 peer_sock=None):
+                 peer_sock=None,
+                 link_delay_s: dict[int, float] | None = None):
         self.slot = slot
         self.g, self.m = g, len(peer_urls)
+        # peer slot -> one-way delay of the link to it, in seconds
+        # (--dist-local-link-delay-ms): every channel, pool and pull
+        # of this member takes its peer's (peerlink's delay line)
+        self._link_delay = {int(p): float(d) for p, d
+                            in (link_delay_s or {}).items() if d}
+        if any(d < 0 or not 0 <= p < self.m or p == slot
+               for p, d in self._link_delay.items()):
+            raise ValueError(
+                f"link_delay_s={link_delay_s}: a delay is >= 0 and "
+                f"names another slot of 0..{self.m - 1}")
         # live member slots (< m leaves spare slots for runtime
         # AddMember; the extra peer URLs name the joinable hosts)
         self.live = self.m if live is None else live
@@ -310,7 +321,8 @@ class DistServer:
             max_workers=max(1, self.m - 1),
             thread_name_prefix=f"dist{slot}-xchg")
         self._pool = KeepAlivePool(timeout=post_timeout,
-                                   ssl_context=self._peer_ssl_cli)
+                                   ssl_context=self._peer_ssl_cli,
+                                   delays=self._link_delay)
         # read-index fetches ride their OWN keep-alive pool: the
         # leader's /mraft/readindex handler may lawfully hold the
         # request for up to 5s awaiting quorum confirmation
@@ -320,7 +332,8 @@ class DistServer:
         # no_leader, and tear down the pooled socket
         self._ri_pool = KeepAlivePool(
             timeout=max(6.0, 3.0 * post_timeout),
-            ssl_context=self._peer_ssl_cli)
+            ssl_context=self._peer_ssl_cli,
+            delays=self._link_delay)
 
         # Windowed append pipeline (PR 5): per-peer (epoch, seq)
         # tagged in-flight frames over striped pipelined connections;
@@ -347,6 +360,10 @@ class DistServer:
         # per peer, the stripe that goes first at the next pump: the
         # one after the stripe that last sent entries (_pump_peer)
         self._stripe_turn = {p: 0 for p in range(self.m) if p != slot}
+        # (peer, stripe) -> when _pump_peer began to hold that
+        # stripe's thin entry frame back: the hold ends with the
+        # window's next free moment or a heartbeat interval on
+        self._thin_since: dict[tuple[int, int], float] = {}
         # per-peer [G] commit vector last shipped (empty-frame dedup:
         # heartbeats go out on commit movement or cadence, not every
         # loop iteration)
@@ -456,6 +473,29 @@ class DistServer:
             p: _obs.registry.gauge(
                 "etcd_dist_pipeline_inflight_entries", peer=str(p))
             for p in range(self.m) if p != slot}
+        # what a link's length changes, a peer (PR 38): the window's
+        # depth when an entry frame joins it, the anti-fragmentation
+        # holds of _pump_peer, whose acknowledgement closed a quorum,
+        # how far the peer trails, and its round trip under a name
+        # of its own beside dist.peer_rtt
+        peers = [p for p in range(self.m) if p != slot]
+        self._m_inflight_at_send = {
+            p: _obs.registry.histogram(
+                "etcd_dist_inflight_at_send", peer=str(p))
+            for p in peers}
+        self._m_thin_holds = {
+            p: _obs.registry.counter(
+                "etcd_dist_thin_frame_holds_total", peer=str(p))
+            for p in peers}
+        self._m_commit_acks = {
+            p: _obs.registry.counter(
+                "etcd_dist_commit_advance_acks_total", peer=str(p))
+            for p in peers}
+        self._m_peer_lag = {
+            p: _obs.registry.histogram(
+                "etcd_dist_peer_lag_entries", peer=str(p))
+            for p in peers}
+        self._rtt_stage = {p: f"dist.peer_rtt.s{p}" for p in peers}
         # PR 14: answer batch endpoints in the binary client framing
         # (wire/clientmsg.py) when the request advertises it via
         # Accept.  ETCD_WIRE_BINARY=0 simulates a JSON-only server —
@@ -2363,7 +2403,16 @@ class DistServer:
                 if recs:
                     # fsync landed: NOW this host's copy joins the
                     # quorum
-                    mr.ack_self(np.asarray(mr.state.last))
+                    last = np.asarray(mr.state.last)
+                    mr.ack_self(last)
+                    # how far each follower trails, once a round
+                    # that appended: entries of the led lanes it
+                    # has not acknowledged (one [G, M] read-back)
+                    behind = np.where(
+                        lead[:, None],
+                        last[:, None] - np.asarray(mr.state.match), 0)
+                    for peer, hist in self._m_peer_lag.items():
+                        hist.observe(int(behind[:, peer].sum()))
                     if self._trace_live and new_keys:
                         now_f = time.monotonic()
                         for key in new_keys:
@@ -2414,10 +2463,11 @@ class DistServer:
                     self._on_pipe_resp(_p, seq, status, body),
                 on_fail=lambda seqs, reason, _p=peer:
                     self._on_pipe_fail(_p, seqs, reason),
-                on_sent=lambda seq, _p=peer:
-                    self._on_pipe_sent(_p, seq),
+                on_sent=lambda seq, t, _p=peer:
+                    self._on_pipe_sent(_p, seq, t),
                 name=f"{self.slot}to{peer}",
-                fault_ctx=(f"s{self.slot}", f"s{peer}"))
+                fault_ctx=(f"s{self.slot}", f"s{peer}"),
+                delay=self._link_delay.get(peer, 0.0))
             self._channels[peer] = chan
         return chan
 
@@ -2480,9 +2530,20 @@ class DistServer:
                     # regardless of entry count, so while the pipe is
                     # already busy, thin frames are pure overhead —
                     # hold the window until the frame is full enough
-                    # (the in-flight ack re-pumps, so nothing
-                    # starves; an idle pipe always sends immediately)
-                    break
+                    # (the in-flight ack re-pumps; an idle pipe
+                    # always sends immediately) — but for no longer
+                    # than a heartbeat interval (PR 38): over a link
+                    # that long some frame is ALWAYS in flight, an
+                    # empty one if no other, every ack's re-pump
+                    # found the window busy, and the peer got no
+                    # entry until the expire sweep, 8 s on.  Past the
+                    # interval the window is what carries the link
+                    since = self._thin_since.setdefault(
+                        (peer, stripe), now)
+                    if now - since < self._hb_interval:
+                        self._m_thin_holds[peer].inc()
+                        break
+                self._thin_since.pop((peer, stripe), None)
                 if not has_ents:
                     # pure heartbeat / commit / need_snap frame:
                     # dedup on cadence and commit movement
@@ -2509,6 +2570,9 @@ class DistServer:
                                    peer, stripe)))
                     if not (adv or due):
                         break
+                if has_ents:
+                    self._m_inflight_at_send[peer].observe(
+                        self.pipe.inflight(peer))
                 meta = self.pipe.register(
                     peer, t0=now, nbytes=0, has_ents=has_ents,
                     stripe=stripe, n_ents=int(n_ents.sum()))
@@ -2564,14 +2628,17 @@ class DistServer:
                                        cause="caught_up")
         self._set_inflight(peer)
 
-    def _on_pipe_sent(self, peer: int, seq: int) -> None:
+    def _on_pipe_sent(self, peer: int, seq: int, t: float) -> None:
         """Channel writer callback: the frame's bytes just hit the
-        socket.  Record the flight send event for traced frames —
-        this is the accurate send edge of the stitcher's symmetric
-        (send, recv, resp, ack) clock-alignment quads (stamping at
-        register time would fold channel queue wait into the
-        network hop).  dict.pop is GIL-atomic; no lock needed."""
-        self.pipe.mark_sent(peer, seq, time.monotonic())
+        socket, and ``t`` is where its link began (that moment, or
+        over a delayed link its hand-over to the channel: the hold
+        is the link's outbound half).  Record the flight send event
+        for traced frames — this is the accurate send edge of the
+        stitcher's symmetric (send, recv, resp, ack) clock-alignment
+        quads (stamping at register time would fold channel queue
+        wait into the network hop).  dict.pop is GIL-atomic; no lock
+        needed."""
+        self.pipe.mark_sent(peer, seq, t)
         traces = self._traced_send.pop((peer, seq), None)
         if traces is not None:
             self.flight.record("frame", dir="send", peer=peer,
@@ -2673,10 +2740,14 @@ class DistServer:
         rtt = t1 - meta.t0
         self._m_send_rtt.observe(rtt)
         if meta.t_sent and meta.has_ents:
-            # socket write to the response read: the wire both ways
-            # and the follower's handle_frame, of a frame that
-            # carries entries (not a heartbeat or a commit advance)
+            # socket write (over a delayed link: the hand-over to
+            # its line) to the response's delivery: the wire both
+            # ways and the follower's handle_frame, of a frame that
+            # carries entries (not a heartbeat or a commit advance);
+            # over all peers, and under the peer's own name
             tracer.record_wait("dist.peer_rtt", t1 - meta.t_sent)
+            tracer.record_wait(self._rtt_stage[peer],
+                               t1 - meta.t_sent)
         self.leader_stats.observe(self._member_id(peer), rtt)
         if meta.traced:
             # the ack edge of the clock-alignment quad (t1 was
@@ -2707,7 +2778,11 @@ class DistServer:
         self._read_release()
         with tracer.stage("dist.absorb"), \
                 _ledger.dispatch("dist.absorb"):
-            mr.handle_append_resp(resp)
+            commit = mr.handle_append_resp(resp)
+        if (commit > self.applied).any():
+            # every commit is applied under the lock that made it,
+            # so this acknowledgement closed a quorum: which peer's
+            self._m_commit_acks[peer].inc()
         if (active & ~ok).any():
             # follower found a gap (dropped or out-of-order frame):
             # next_ was repaired from its commit hint; collapse to
@@ -3180,6 +3255,14 @@ class DistServer:
         log.info("dist[%d]: snapshot pull failed on every donor; "
                  "retrying in %.2fs", self.slot, delay)
 
+    def _link_hold(self, peer: int) -> None:
+        """One crossing of the delayed link to ``peer`` by a
+        synchronous call that rides neither a channel nor a pool
+        (the snapshot pull's two fetches)."""
+        d = self._link_delay.get(peer)
+        if d:
+            link_hold(time.monotonic(), d)
+
     def _fetch_snap_meta(self, h: int) -> dict | None:
         """Meta pin fetch.  NOT on the shared keep-alive pool: the
         donor serializes + CRC-chains its whole store before
@@ -3199,11 +3282,13 @@ class DistServer:
         # exists to fix
         hint_s = self._donor_size_hint.get(h, 0) / (1 << 20)
         try:
+            self._link_hold(h)
             with urllib.request.urlopen(
                     req,
                     timeout=max(30.0, 10 * self.post_timeout) + hint_s,
                     context=self._peer_ssl_cli) as resp:
                 body = resp.read()
+            self._link_hold(h)
         except (urllib.error.URLError, OSError):
             return None  # unreachable donor
         try:
@@ -3219,11 +3304,13 @@ class DistServer:
         """Cheap pre-pin dominance probe (GET, no pin, no store
         serialization on the donor)."""
         try:
+            self._link_hold(h)
             with urllib.request.urlopen(
                     self.peer_urls[h] + SNAP_FRONTIER_PATH,
                     timeout=max(2.0, self.post_timeout),
                     context=self._peer_ssl_cli) as resp:
                 d = json.loads(resp.read().decode())
+            self._link_hold(h)
             # remember the donor's size hint for the meta-fetch
             # timeout (absent on peers without a durable snapshot)
             self._donor_size_hint[h] = int(d.get("approx_bytes", 0))
@@ -3254,7 +3341,8 @@ class DistServer:
             on_reject=lambda k: self.flight.record(
                 "snap_install", outcome="chunk_reject", chunk=k,
                 donor=h),
-            name=f"snap{self.slot}from{h}")
+            name=f"snap{self.slot}from{h}",
+            delay=self._link_delay.get(h, 0.0))
         try:
             return puller.run()
         finally:

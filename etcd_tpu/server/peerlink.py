@@ -8,6 +8,17 @@ frame itself at intra-DC latencies (the distserver keep-alive cache
 proved this in PR 2; this module is that cache promoted to a shared
 abstraction, plus the pipelining the lockstep round could not use).
 
+Both take a stated one-way link delay (PR 38, the members of a
+``--dist-local-cluster`` placed a distance apart): a DELAY LINE, not
+a slower link.  Every frame and every response is held until ITS OWN
+due time, the stamp at which it was handed over (or read off the
+socket) plus the delay; what is behind it is due later by no more
+than it was handed over later, so a window of N frames crosses in one
+delay, not in N (which is what the ``peerlink.send=delay()`` failpoint
+gives: one sleep a frame on the stripe's one writer).  The line only
+postpones a socket write and a callback: it drops, reorders and
+duplicates nothing, and with no delay it is one ``if``.
+
 Delivery contract (both forms): AT-LEAST-ONCE.  A retry or a
 reconnect cannot tell "the peer closed the idle socket before my
 bytes arrived" from "the peer processed the POST and the response was
@@ -25,13 +36,36 @@ import logging
 import queue
 import socket
 import threading
+import time
 from collections import deque
 from urllib.parse import urlparse
 
 from ..utils import faults as _faults
 from ..utils.backoff import Backoff
+from ..utils.trace import tracer
 
 log = logging.getLogger(__name__)
+
+
+def hold(t_in: float, delay: float,
+         wake: threading.Event | None = None) -> bool:
+    """One message's stay on a delay line: wait until ``t_in +
+    delay`` on the monotonic clock (not ``delay`` from now: whatever
+    the message already waited in a queue counts), then file the stay
+    as ``dist.link_hold`` and what it overshot the stated delay by as
+    ``dist.link_overshoot`` (a sleeper under one interpreter wakes up
+    to a switch interval late).  False when ``wake`` was set first:
+    the line was closed under the message."""
+    left = t_in + delay - time.monotonic()
+    if left > 0:
+        if wake is None:
+            time.sleep(left)
+        elif wake.wait(left):
+            return False
+    held = time.monotonic() - t_in
+    tracer.record_wait("dist.link_hold", held)
+    tracer.record_wait("dist.link_overshoot", held - delay)
+    return True
 
 
 class KeepAlivePool:
@@ -47,12 +81,19 @@ class KeepAlivePool:
     caller parked meanwhile.  A changed ``url`` for a cached key
     (runtime membership swap, a test's network cut) drops the stale
     connection instead of short-circuiting the new route.
+
+    ``delays`` (optional) maps a key to its link's one-way delay in
+    seconds: a POST to that key is held for it before the request
+    and again after the response (the call is synchronous, so the
+    two holds are exact), and is otherwise the same call.
     """
 
     def __init__(self, timeout: float = 1.0, ssl_context=None,
                  keep_statuses: tuple[int, ...] = (200, 204),
-                 on_reconnect=None):
+                 on_reconnect=None,
+                 delays: dict[object, float] | None = None):
         self.timeout = timeout
+        self._delays = delays or None
         self.ssl_context = ssl_context
         self.keep_statuses = keep_statuses
         self._conns: dict[object, tuple[str, object]] = {}
@@ -73,6 +114,16 @@ class KeepAlivePool:
         """POST ``payload`` to ``url + path``; returns
         ``(status, body)`` or None when both attempts failed (a
         dropped message, by contract)."""
+        d = self._delays.get(key) if self._delays else None
+        if not d:
+            return self._post(key, url, path, payload)
+        hold(time.monotonic(), d)
+        out = self._post(key, url, path, payload)
+        hold(time.monotonic(), d)
+        return out
+
+    def _post(self, key, url: str, path: str,
+              payload) -> tuple[int, bytes] | None:
         u = urlparse(url)
         with self._lock:
             held_url, conn = self._conns.pop(key, (None, None))
@@ -167,7 +218,7 @@ class _Stripe:
     back in order and FIFO-matched to their seq tags."""
 
     __slots__ = ("sock", "rf", "pending", "cond", "gen", "dead", "q",
-                 "backoff")
+                 "backoff", "held")
 
     def __init__(self):
         self.sock = None
@@ -186,6 +237,9 @@ class _Stripe:
         # cadence.
         self.backoff = Backoff(base=0.05, cap=5.0, site="peerlink",
                                first_zero=True)
+        # responses on the way back over a delayed link (the
+        # channel's delay line): None without a delay
+        self.held: queue.Queue | None = None
 
 
 class PipeChannel:
@@ -215,12 +269,25 @@ class PipeChannel:
     back to probe-and-resend, so at-least-once redelivery is the
     worst case, never silent loss.
 
-    ``on_sent(seq)`` (optional) fires on the writer thread right
+    ``on_sent(seq, t)`` (optional) fires on the writer thread right
     after the frame's bytes hit the socket — the accurate send edge
     the trace stitcher's clock alignment wants (the caller registers
     the frame BEFORE queueing it, but the writer may drain later
     under load; stamping at registration would fold queue wait into
-    the network hop).
+    the network hop).  ``t`` is that moment on the monotonic clock,
+    or, over a delayed link, the frame's hand-over to ``send``: the
+    link begins where the line does.
+
+    ``delay`` is the link's one-way delay in seconds (the module
+    docstring's delay line).  A frame handed to ``send`` at *t* is
+    written to the socket no earlier than *t + delay*: the stripe's
+    writer waits for the frame's own due time, so frames handed over
+    back to back all leave at about *t + delay*, in order.  A
+    response is stamped when the reader has read it and handed to one
+    more thread a stripe, which calls ``on_resp`` at that stamp +
+    ``delay``, in order; the reader never sleeps.  ``close()`` fails
+    what either half holds like what is queued.  0: no stamp, no
+    queue, no thread.
 
     ``fault_ctx=(src, dst)`` (optional) names the link for the
     ``peerlink.send`` failpoint (utils/faults): ``drop`` loses the
@@ -234,8 +301,12 @@ class PipeChannel:
                  timeout: float = 1.0, read_timeout: float | None = None,
                  ssl_context=None, on_resp=None, on_fail=None,
                  on_sent=None, name: str = "",
-                 fault_ctx: tuple[str, str] | None = None):
+                 fault_ctx: tuple[str, str] | None = None,
+                 delay: float = 0.0):
+        if delay < 0:
+            raise ValueError(f"link delay {delay} s is negative")
         self.url = url
+        self._delay = delay
         u = urlparse(url)
         self._host, self._port = u.hostname, u.port
         self._tls = u.scheme == "https"
@@ -262,15 +333,22 @@ class PipeChannel:
                 target=self._reader, args=(st,), daemon=True,
                 name=f"pipe-{name}-r{i}")
             self._threads += [w, r]
-            w.start()
-            r.start()
+            if delay:
+                st.held = queue.Queue()
+                self._threads.append(threading.Thread(
+                    target=self._deliverer, args=(st,), daemon=True,
+                    name=f"pipe-{name}-d{i}"))
+        for t in self._threads:
+            t.start()
 
     # -- caller side ------------------------------------------------------
 
     def send(self, seq: int, payload, stripe: int = 0) -> None:
         """Enqueue one tagged request on stripe ``stripe``
         (non-blocking; the window is the caller's responsibility)."""
-        self._stripes[stripe % self.stripes].q.put((seq, payload))
+        t_in = time.monotonic() if self._delay else 0.0
+        self._stripes[stripe % self.stripes].q.put(
+            (seq, payload, t_in))
 
     def queued(self) -> int:
         return sum(st.q.qsize() for st in self._stripes)
@@ -287,16 +365,25 @@ class PipeChannel:
             # permanently (found as a post-partition-heal wedge: the
             # rebuilt channel's predecessor swallowed one probe
             # frame and the peer never heard the new term)
-            leftover = []
-            while True:
-                try:
-                    item = st.q.get_nowait()
-                except queue.Empty:
-                    break
-                if item is not None:
-                    leftover.append(item[0])
-            if leftover:
-                self._on_fail(leftover, "closed")
+            self._fail_queued(st.q)
+            if st.held is not None:
+                # responses read and still on their way back: the
+                # callback they were for will never come
+                self._fail_queued(st.held)
+
+    def _fail_queued(self, q: queue.Queue) -> None:
+        """Fail every frame still in ``q`` as closed (items lead with
+        their seq): no silent loss."""
+        leftover = []
+        while True:
+            try:
+                item = q.get_nowait()
+            except queue.Empty:
+                break
+            if item is not None:
+                leftover.append(item[0])
+        if leftover:
+            self._on_fail(leftover, "closed")
 
     # -- internals --------------------------------------------------------
 
@@ -357,7 +444,7 @@ class PipeChannel:
                 # guarantee is ours to keep — fail it, don't drop it
                 self._on_fail([item[0]], "closed")
                 return
-            seq, payload = item
+            seq, payload, t_in = item
             # peerlink.send failpoint (PR 10): silent loss / byte
             # corruption / injected send error, per [src->dst]
             try:
@@ -374,6 +461,11 @@ class PipeChannel:
                 continue
             if act == _faults.CORRUPT:
                 payload = _faults.flip_byte(payload)
+            if t_in and not hold(t_in, self._delay, self._closed):
+                # closed under a frame on the line: ours to fail,
+                # like the dequeue that close() raced (above)
+                self._on_fail([seq], "closed")
+                return
             if st.dead:
                 # reconnect pacing (shared jittered backoff): one
                 # free immediate retry after a healthy stretch, then
@@ -417,7 +509,7 @@ class PipeChannel:
                 self._teardown(st, "reconnect")
                 continue
             if self._on_sent is not None:
-                self._on_sent(seq)
+                self._on_sent(seq, t_in or time.monotonic())
 
     def _reader(self, st: _Stripe) -> None:
         while not self._closed.is_set():
@@ -433,6 +525,7 @@ class PipeChannel:
             except (OSError, ValueError, ConnectionError):
                 self._teardown(st, "reconnect", gen=gen)
                 continue
+            t_read = time.monotonic() if st.held is not None else 0.0
             # a real response arrived: the link is healthy — re-arm
             # the writer's reconnect pacing from zero
             st.backoff.reset()
@@ -448,8 +541,29 @@ class PipeChannel:
                 # successor on the same address, the keep-alive
                 # cache's close-on-error rule applied to the pipe)
                 self._teardown(st, "reconnect", gen=gen)
-            if seq is not None:
+            if seq is None:
+                continue
+            if st.held is None:
                 self._on_resp(seq, status, body)
+            else:
+                st.held.put((seq, status, body, t_read))
+                if self._closed.is_set():
+                    # close() may have drained the line before this
+                    self._fail_queued(st.held)
+
+    def _deliverer(self, st: _Stripe) -> None:
+        """The way back over a delayed link: each response to
+        ``on_resp`` at its read stamp + the delay, in the order the
+        reader read them."""
+        while not self._closed.is_set():
+            try:
+                seq, status, body, t_read = st.held.get(timeout=0.5)
+            except queue.Empty:
+                continue
+            if not hold(t_read, self._delay, self._closed):
+                self._on_fail([seq], "closed")
+                return
+            self._on_resp(seq, status, body)
 
 
-__all__ = ["KeepAlivePool", "PipeChannel"]
+__all__ = ["KeepAlivePool", "PipeChannel", "hold"]

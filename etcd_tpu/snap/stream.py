@@ -255,7 +255,8 @@ class ChunkPuller:
                  verifier: ChunkVerifier | None = None,
                  max_rejects: int = 8, deadline_s: float = 300.0,
                  stall_s: float = 20.0, abort=None,
-                 on_reject=None, name: str = "snapstream"):
+                 on_reject=None, name: str = "snapstream",
+                 delay: float = 0.0):
         from ..server.peerlink import PipeChannel
 
         self.meta = meta
@@ -285,7 +286,7 @@ class ChunkPuller:
                 self._events.put(("resp", seq, status, body)),
             on_fail=lambda seqs, reason:
                 self._events.put(("fail", seqs, reason)),
-            name=name)
+            name=name, delay=delay)
 
     def close(self) -> None:
         self._chan.close()

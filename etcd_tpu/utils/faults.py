@@ -96,7 +96,10 @@ FAULT_CATALOG: dict[str, str] = {
     "peerlink.send": (
         "outbound peer frame, per [src->dst]: channel writer + "
         "synchronous keep-alive POSTs (drop = silent loss — only "
-        "the expire sweep recovers)"),
+        "the expire sweep recovers; delay() sleeps on the stripe's "
+        "one writer, so it is one frame a delay, a bandwidth cut "
+        "and not a distance: --dist-local-link-delay-ms is the "
+        "link that carries a window at once)"),
     "peerlink.recv": (
         "inbound peer traffic, per [src->dst]: pushed frames at the "
         "handler AND ack/vote responses at the receiving client — "
