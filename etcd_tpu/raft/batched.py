@@ -38,34 +38,6 @@ import jax.numpy as jnp
 from ..ops.quorum import commit_index_batch
 
 
-def _append_write_mode() -> str:
-    """``scatter`` | ``dense`` — how maybe_append writes the incoming
-    window (see the comment at its use site).  Read at trace time, so
-    the choice is baked into each compiled program; the env override
-    serves the parity tests and on-hardware races."""
-    import os
-
-    mode = os.environ.get("ETCD_APPEND_WRITE")
-    if mode:
-        if mode not in ("scatter", "dense"):
-            # a typo must fail loudly, not measure some other form
-            # under the wrong label (same convention as
-            # crc_variants.parse_variant)
-            raise ValueError(
-                f"ETCD_APPEND_WRITE={mode!r}: want scatter|dense")
-        return mode
-    # Chosen from the platform the program is traced for.  On the
-    # v5e the dense form's [G, cap] take_along_axis is a 10M-element
-    # gather per follower exchange at config-4 size (G=10k, cap=1024):
-    # 85 ms each, a 0.53 s fused round against 0.12 s for scatter —
-    # past the 0.5 s client request timeout, so no HTTP write could
-    # be acknowledged (chip run, PR 21).  On XLA-CPU the scatter form
-    # MEASURED 2x slower for the whole serving round (config5 @100k
-    # groups: 89 -> 177 ms/round — XLA lowers the .at[].set to a
-    # non-aliased copy+scatter).
-    return "scatter" if jax.default_backend() == "tpu" else "dense"
-
-
 FOLLOWER, CANDIDATE, LEADER = 0, 1, 2
 
 
@@ -147,6 +119,12 @@ def term_at(log_term, offset, last, idx):
     return t[:, 0] if squeeze else t
 
 
+def _block_width(cap: int, e: int) -> int | None:
+    """The narrowest blocks that tile a row of ``cap`` slots and hold
+    a run of ``e``; None where ``e > cap``."""
+    return next((w for w in range(e, cap + 1) if cap % w == 0), None)
+
+
 def term_window(log_term, offset, last, start, e: int):
     """Terms of entries ``start .. start+e-1`` per group, [G, e]:
     :func:`term_at` of ``start[:, None] + arange(e)``, bit for bit,
@@ -166,8 +144,7 @@ def term_window(log_term, offset, last, start, e: int):
     ``term_at``; above ``last`` the mask says so.
     """
     g, cap = log_term.shape
-    # the narrowest blocks that tile the row and hold a run
-    w = next((w for w in range(e, cap + 1) if cap % w == 0), None)
+    w = _block_width(cap, e)
     if w is None:
         # e > cap: the caller's shape is no window of this log
         return term_at(log_term, offset, last,
@@ -190,6 +167,61 @@ def term_window(log_term, offset, last, start, e: int):
         return jnp.where(start[:, None] + j <= last[:, None], t, 0)
 
 
+def append_window(log_term, offset, start, ent_terms, write):
+    """``log_term`` [G, cap] with the slot of entry ``start + j`` set
+    to ``ent_terms[:, j]`` wherever ``write[:, j]``: the write twin of
+    :func:`term_window`, with selects alone.  A scatter of the E
+    entries a group is, on the TPU, a sort of the G x E indices and a
+    fusion over the whole log, once an exchange (9.5 of the 14.2 ms
+    the device worked a round at 10k groups x cap 1024: ledger, PR
+    38), and a gather of the row from the window as slow (85 ms an
+    exchange: chip run, PR 21).  So the run is shifted into a pair of
+    blocks by its offset into the first one, a few static shifts, and
+    each block of the row selects the pair's first half, its second
+    half or itself: one dense pass over the log.  A select a block
+    took 9.1 ms for the bare hot round where one ``where`` over the
+    ``[G, cap/w, w]`` view took 9.5 and the scatter 16.8 (10k x 5,
+    cap 1024, E 32; chip runs, PR 39): XLA materialises the view's
+    broadcast halves.
+
+    A slot below 0 or at ``cap`` and past it lies in no block of the
+    row and is dropped, as the scatter's ``mode="drop"`` dropped it.
+    """
+    g, cap = log_term.shape
+    e = ent_terms.shape[1]
+    w = _block_width(cap, e)
+    slot0 = start - offset
+    if w is None:
+        # e > cap: the caller's shape is no window of this log; slot s
+        # takes entry s - slot0
+        r = jnp.arange(cap, dtype=jnp.int32)[None, :] - slot0[:, None]
+        rc = jnp.clip(r, 0, e - 1)
+        put = (r >= 0) & (r < e) & jnp.take_along_axis(write, rc, axis=1)
+        return jnp.where(put, jnp.take_along_axis(ent_terms, rc, axis=1),
+                         log_term)
+    with jax.named_scope("append_window"):
+        b = slot0 // w                  # floor: "block -1" below slot 0
+        d = slot0 - b * w               # where the run starts in it
+        # pair[:, c] is slot b * w + c: the run at columns d .. d+e-1,
+        # shifted there bit by bit of d (d < w, and d + e <= 2w)
+        pair = jnp.pad(ent_terms, ((0, 0), (0, 2 * w - e)))
+        put = jnp.pad(write, ((0, 0), (0, 2 * w - e)))
+        for bit in range((w - 1).bit_length()):
+            s = 1 << bit
+            on = ((d & s) != 0)[:, None]
+            pair = jnp.where(on, jnp.pad(pair, ((0, 0), (s, 0)))[:, :2 * w],
+                             pair)
+            put = jnp.where(on, jnp.pad(put, ((0, 0), (s, 0)))[:, :2 * w],
+                            put)
+        first, second = (slice(0, w), slice(w, 2 * w))
+        return jnp.concatenate([
+            jnp.where((b == k)[:, None] & put[:, first], pair[:, first],
+                      jnp.where((b == k - 1)[:, None] & put[:, second],
+                                pair[:, second],
+                                log_term[:, k * w:(k + 1) * w]))
+            for k in range(cap // w)], axis=1)
+
+
 def match_term(log_term, offset, last, idx, term):
     """Batched ``RaftLog.match_term`` — NB a term-0 entry at a valid
     index cannot be distinguished from absence, exactly like the
@@ -205,9 +237,9 @@ def is_up_to_date(log_term, offset, last, cand_idx, cand_term):
     return (cand_term > lt) | ((cand_term == lt) & (cand_idx >= last))
 
 
+@jax.jit
 def maybe_append(state: GroupState, prev_idx, prev_term, ent_terms,
-                 n_ents, leader_commit, active=None,
-                 write_mode: str | None = None):
+                 n_ents, leader_commit, active=None):
     """Follower replication step, batched ``RaftLog.maybe_append``
     (log.go:49-69): term-match at prev, conflict scan, truncating
     append, commit advance.
@@ -215,13 +247,7 @@ def maybe_append(state: GroupState, prev_idx, prev_term, ent_terms,
     ``ent_terms`` [G, E] terms of incoming entries (entry j has index
     prev_idx + 1 + j), ``n_ents`` [G] how many are real, ``active``
     [G] bool mask of groups actually receiving an append (inactive
-    groups pass through unchanged).  ``write_mode`` pins the window-
-    write form (scatter|dense); default resolves from
-    ETCD_APPEND_WRITE / the backend at call (or outer-trace) time —
-    the mode is a STATIC jit argument, so each form compiles its own
-    program and flipping the knob between calls takes effect (an
-    env read inside the traced body would be baked into the first
-    compile forever).
+    groups pass through unchanged).
 
     Returns ``(state', ok, err_conflict, err_overflow)``:
     ``ok`` = the append was accepted (msgAppResp success);
@@ -231,15 +257,6 @@ def maybe_append(state: GroupState, prev_idx, prev_term, ent_terms,
     untouched and respond with a reject — one hot or corrupted group
     never poisons the batch.
     """
-    mode = write_mode or _append_write_mode()
-    return _maybe_append_jit(state, prev_idx, prev_term, ent_terms,
-                             n_ents, leader_commit, active,
-                             write_mode=mode)
-
-
-@partial(jax.jit, static_argnames=("write_mode",))
-def _maybe_append_jit(state, prev_idx, prev_term, ent_terms, n_ents,
-                      leader_commit, active, write_mode):
     g, cap = state.log_term.shape
     e = ent_terms.shape[1]
     if active is None:
@@ -266,35 +283,9 @@ def _maybe_append_jit(state, prev_idx, prev_term, ent_terms, n_ents,
 
     # truncating append: slots in [prev_idx+1, lastnewi] take the
     # incoming terms (identical values where already matching, new
-    # values from the conflict point on).  Two equivalent device
-    # forms (tests pin them to each other):
-    #
-    # - "scatter": write ONLY the E incoming slots.  E is 4-8 while
-    #   cap is 32-64, and the dense form's full [G, cap] read+write
-    #   per follower exchange was the serving round's dominant
-    #   memory traffic at 100k groups (round-5 profile).
-    # - "dense": one masked full-window where() — contiguous and
-    #   layout-friendly where gathers/scatters are expensive.
-    #
-    # Default: by platform — scatter on the TPU, dense on XLA-CPU
-    # (see _append_write_mode for both measurements);
-    # ETCD_APPEND_WRITE={scatter,dense} overrides for racing.
-    if write_mode == "scatter":
-        rel = e_idx - state.offset[:, None]    # cap slot of entry j
-        writej = ok[:, None] & valid_e & (rel >= 0) & (rel < cap)
-        cols = jnp.where(writej, rel, cap)     # cap = dropped
-        gidx = jnp.arange(g, dtype=jnp.int32)[:, None]
-        log_term = state.log_term.at[gidx, cols].set(
-            ent_terms, mode="drop")
-    else:
-        cap_idx = state.offset[:, None] + \
-            jnp.arange(cap, dtype=jnp.int32)
-        j = cap_idx - (prev_idx[:, None] + 1)
-        write = ok[:, None] & (j >= 0) & (j < n_ents[:, None])
-        incoming = jnp.take_along_axis(
-            ent_terms, jnp.clip(j, 0, e - 1), axis=1)
-        log_term = jnp.where(write, incoming, state.log_term)
-
+    # values from the conflict point on)
+    log_term = append_window(state.log_term, state.offset, prev_idx + 1,
+                             ent_terms, ok[:, None] & valid_e)
     last = jnp.where(ok & conflict, lastnewi, state.last)
     tocommit = jnp.minimum(leader_commit, lastnewi)
     commit = jnp.where(ok & (tocommit > state.commit), tocommit,

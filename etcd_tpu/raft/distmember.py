@@ -46,13 +46,12 @@ from .batched import (
     LEADER,
     CANDIDATE,
     GroupState,
-    _append_write_mode,
-    _maybe_append_jit,
     apply_conf_change as conf_change_batch,
     compact as compact_batch,
     grant_vote,
     init_groups,
     leader_append,
+    maybe_append,
     maybe_commit,
     progress_optimistic,
     progress_probe,
@@ -105,10 +104,10 @@ def _absorb_resp(state: GroupState, peer, term, ok, acked, hint,
     return maybe_commit(state)
 
 
-@partial(jax.jit, static_argnames=("write_mode",))
+@jax.jit
 def _handle_append_fused(state: GroupState, sender_v, term, prev_idx,
                          prev_term, ent_terms, n_ents, commit, active,
-                         need_snap, write_mode):
+                         need_snap):
     """The WHOLE follower-side msgApp step as ONE device dispatch:
     higher-term adoption, leadership + election-timer reset,
     maybe_append, and the response arrays packed into a single [G, 7]
@@ -125,9 +124,8 @@ def _handle_append_fused(state: GroupState, sender_v, term, prev_idx,
         lead=jnp.where(cur, sender_v, st.lead),
         elapsed=jnp.where(cur, 0, st.elapsed))
     do = cur & ~need_snap
-    st, ok, e_conf, e_over = _maybe_append_jit(
-        st, prev_idx, prev_term, ent_terms, n_ents, commit,
-        do, write_mode=write_mode)
+    st, ok, e_conf, e_over = maybe_append(
+        st, prev_idx, prev_term, ent_terms, n_ents, commit, do)
     need = need_snap & cur
     commit_i = st.commit.astype(jnp.int32)
     acked = jnp.where(need, commit_i,
@@ -490,8 +488,7 @@ class DistMember:
             self._put(b.prev_idx), self._put(b.prev_term),
             self._put(b.ent_terms), self._put(b.n_ents),
             self._put(b.commit), self._put(b.active),
-            self._put(b.need_snap),
-            write_mode=_append_write_mode())
+            self._put(b.need_snap))
         self.state = st
         p = np.asarray(packed)
         ok_np = p[:, 0].astype(bool)

@@ -21,6 +21,8 @@ from etcd_tpu.raft.batched import (
 from etcd_tpu.raft.log import LogError, RaftLog
 from etcd_tpu.wire import Entry
 
+from test_term_window import WRITES, parent_maybe_append
+
 G, M, CAP, E = 32, 5, 64, 8
 
 
@@ -230,13 +232,9 @@ def test_grant_vote_up_to_date():
         np.asarray(st2.vote)[grant], 1)
 
 
-def test_maybe_append_scatter_dense_equivalence():
-    """The two window-write forms (write_mode=scatter|dense) must
-    produce identical state — the knob exists for on-hardware
-    racing, never for semantics.  write_mode is a STATIC jit arg,
-    so each mode compiles (and runs) its own program — an env-only
-    knob read inside the traced body would make this test compare
-    the first-compiled program with itself."""
+def test_maybe_append_is_the_scatter_and_gather_forms():
+    """The block write leaves the state both of the parent's window
+    writes left (the scatter the TPU ran, the gather XLA-CPU ran)."""
     rng = np.random.default_rng(9)
     for trial in range(4):
         _, st = _mk_logs(rng)
@@ -246,18 +244,18 @@ def test_maybe_append_scatter_dense_equivalence():
         ent_terms = np.sort(
             rng.integers(1, 5, size=(G, E)).astype(np.int32), axis=1)
         leader_commit = rng.integers(0, 30, size=G).astype(np.int32)
-        outs = {}
-        for mode in ("dense", "scatter"):
-            st2, ok, errc, erro = batched.maybe_append(
-                st, jnp.asarray(prev_idx), jnp.asarray(prev_term),
+        args = (st, jnp.asarray(prev_idx), jnp.asarray(prev_term),
                 jnp.asarray(ent_terms), jnp.asarray(n_ents),
-                jnp.asarray(leader_commit), write_mode=mode)
-            outs[mode] = (np.asarray(st2.log_term),
-                          np.asarray(st2.last),
-                          np.asarray(st2.commit), np.asarray(ok),
-                          np.asarray(errc), np.asarray(erro))
-        # non-vacuity: the scatter branch must actually write —
-        # accepted lanes with real entries exist in every trial
-        assert (outs["scatter"][3] & (n_ents > 0)).any(), trial
-        for a, b in zip(outs["dense"], outs["scatter"]):
-            np.testing.assert_array_equal(a, b, err_msg=str(trial))
+                jnp.asarray(leader_commit))
+        st2, ok, errc, erro = batched.maybe_append(*args)
+        got = (st2.log_term, st2.last, st2.commit, ok, errc, erro)
+        # non-vacuity: accepted lanes with real entries exist in
+        # every trial
+        assert (np.asarray(ok) & (n_ents > 0)).any(), trial
+        for reference in WRITES:
+            st3, *flags = parent_maybe_append(*args, reference=reference)
+            want = (st3.log_term, st3.last, st3.commit, *flags)
+            for a, b in zip(got, want, strict=True):
+                np.testing.assert_array_equal(
+                    np.asarray(a), np.asarray(b),
+                    err_msg=f"{trial} {reference}")

@@ -1,10 +1,13 @@
 """``batched.term_window``: a round's E-entry log window read as one
 contiguous run a group is ``term_at`` of the same indices, bit for
-bit; the rounds built on it give the scalar core's logs; and the
-program traced for the TPU reads no window of the ``[G, cap]`` log
-through a gather."""
+bit; ``batched.append_window``, its write twin, is the scatter and
+the gather forms it replaced, bit for bit; the rounds built on them
+give the scalar core's logs; and no program reads a window of the
+``[G, cap]`` log through a gather or writes one through a scatter."""
 
 from __future__ import annotations
+
+from functools import partial
 
 import numpy as np
 import pytest
@@ -13,7 +16,13 @@ import jax
 import jax.numpy as jnp
 
 from etcd_tpu.raft import batched, distmember, multiraft
-from etcd_tpu.raft.batched import init_groups, term_at, term_window
+from etcd_tpu.raft.batched import (
+    append_window,
+    init_groups,
+    match_term,
+    term_at,
+    term_window,
+)
 from etcd_tpu.raft.core import MSG_HUP, MSG_PROP
 from etcd_tpu.raft.multiraft import MultiRaft
 from etcd_tpu.wire import Entry
@@ -28,6 +37,75 @@ def gather_window(log_term, offset, last, start, e):
     """The parent's form: ``term_at`` over the run's indices."""
     return term_at(log_term, offset, last,
                    start[:, None] + jnp.arange(e, dtype=jnp.int32))
+
+
+def scatter_write(log_term, offset, start, ent_terms, write):
+    """The parent's TPU form of the window write: ``.at[].set`` of the
+    E slots, a slot outside the row dropped."""
+    g, cap = log_term.shape
+    e = ent_terms.shape[1]
+    rel = start[:, None] + jnp.arange(e, dtype=jnp.int32) - \
+        offset[:, None]
+    cols = jnp.where(write & (rel >= 0) & (rel < cap), rel, cap)
+    gidx = jnp.arange(g, dtype=jnp.int32)[:, None]
+    return log_term.at[gidx, cols].set(ent_terms, mode="drop")
+
+
+def gather_write(log_term, offset, start, ent_terms, write):
+    """The parent's XLA-CPU form: every slot of the row gathers the
+    entry it would hold, and a masked ``where`` keeps it."""
+    cap = log_term.shape[1]
+    e = ent_terms.shape[1]
+    j = offset[:, None] + jnp.arange(cap, dtype=jnp.int32) - \
+        start[:, None]
+    jc = jnp.clip(j, 0, e - 1)
+    put = (j >= 0) & (j < e) & jnp.take_along_axis(write, jc, axis=1)
+    return jnp.where(put, jnp.take_along_axis(ent_terms, jc, axis=1),
+                     log_term)
+
+
+WRITES = {"scatter": scatter_write, "gather": gather_write}
+
+
+@partial(jax.jit, static_argnames=("reference",))
+def parent_maybe_append(state, prev_idx, prev_term, ent_terms, n_ents,
+                        leader_commit, active=None, *, reference):
+    """The parent's ``maybe_append``: the conflict scan over
+    ``term_at``'s gather, the window written in the ``reference``
+    form (``scatter`` | ``gather``)."""
+    g, cap = state.log_term.shape
+    e = ent_terms.shape[1]
+    if active is None:
+        active = jnp.ones((g,), bool)
+    ok = active & match_term(state.log_term, state.offset, state.last,
+                             prev_idx, prev_term)
+    e_idx = prev_idx[:, None] + 1 + jnp.arange(e, dtype=jnp.int32)
+    existing = gather_window(state.log_term, state.offset, state.last,
+                             prev_idx + 1, e)
+    valid_e = jnp.arange(e) < n_ents[:, None]
+    mismatch = valid_e & ((e_idx > state.last[:, None]) |
+                          (existing != ent_terms))
+    conflict = mismatch.any(axis=1)
+    ci = prev_idx + 1 + jnp.argmax(mismatch, axis=1)
+    lastnewi = prev_idx + n_ents
+    err_conflict = ok & conflict & (ci <= state.commit)
+    err_overflow = ok & (lastnewi - state.offset >= cap)
+    ok = ok & ~(err_conflict | err_overflow)
+    log_term = WRITES[reference](state.log_term, state.offset,
+                                prev_idx + 1, ent_terms,
+                                ok[:, None] & valid_e)
+    last = jnp.where(ok & conflict, lastnewi, state.last)
+    tocommit = jnp.minimum(leader_commit, lastnewi)
+    commit = jnp.where(ok & (tocommit > state.commit), tocommit,
+                       state.commit)
+    return state._replace(log_term=log_term, last=last,
+                          commit=commit), ok, err_conflict, err_overflow
+
+
+def assert_same(got, want) -> None:
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want), strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def random_logs(rng, n: int, cap: int, offset_kind: str):
@@ -103,6 +181,49 @@ def test_window_longer_than_the_row(e):
         np.asarray(gather_window(lt, off, la, start, e)))
 
 
+def random_writes(cap: int, e: int, run: int | None = None):
+    """Runs of ``e`` random terms under random write masks, for every
+    start in :func:`every_start` of a run of ``run`` (default ``e``):
+    ``(log_term, offset, start, ent_terms, write)``."""
+    rng = np.random.default_rng(cap * 10 + e)
+    log, offset, last = random_logs(rng, 6 if cap > 64 else 12, cap,
+                                    "some")
+    lt, off, _la, start = every_start(log, offset, last, run or e)
+    n = start.shape[0]
+    ents = jnp.asarray(rng.integers(1000, 2000, (n, e)), jnp.int32)
+    write = jnp.asarray(rng.random((n, e)) < 0.7)
+    return lt, off, start, ents, write
+
+
+@pytest.mark.parametrize("reference", sorted(WRITES))
+@pytest.mark.parametrize("e", ES)
+@pytest.mark.parametrize("cap", CAPS)
+def test_window_write_is_the_parents_for_every_start(cap, e, reference):
+    """Every start from below the offset to past the end of the row:
+    the block write leaves the row the scatter and the gather forms
+    leave, slots outside it dropped."""
+    args = random_writes(cap, e)
+    got = np.asarray(jax.jit(append_window)(*args))
+    want = np.asarray(WRITES[reference](*args))
+    np.testing.assert_array_equal(got, want)
+    # not vacuous: runs that begin below the offset and runs that end
+    # past the row both wrote something
+    lt, off, start = (np.asarray(x) for x in args[:3])
+    wrote = (want != lt).any(axis=1)
+    assert wrote[start < off].any() and wrote[start - off > cap - e].any()
+    assert (~wrote).any()
+
+
+@pytest.mark.parametrize("cap,e,run", [(48, 5, 5), (24, 1, 1), (30, 7, 7),
+                                       (32, 33, 4), (32, 40, 4)])
+def test_window_write_at_odd_widths(cap, e, run):
+    """Blocks whose width is no power of two (6, 1 and 10 wide), and
+    windows longer than the row (no block holds them)."""
+    args = random_writes(cap, e, run)
+    np.testing.assert_array_equal(np.asarray(append_window(*args)),
+                                  np.asarray(scatter_write(*args)))
+
+
 # -- the callers ----------------------------------------------------------
 
 
@@ -129,28 +250,21 @@ def _edge_logs(rng, g: int, cap: int, e: int):
     return st, prev_idx, prev_term, ent_terms, n_ents
 
 
-@pytest.mark.parametrize("mode", ["scatter", "dense"])
+@pytest.mark.parametrize("reference", sorted(WRITES))
 @pytest.mark.parametrize("cap,e", [(32, 4), (32, 8), (64, 8),
-                                   (64, 32)])
-def test_maybe_append_with_window_is_the_gather_form(
-        monkeypatch, cap, e, mode):
-    """``maybe_append``'s conflict scan over the window, at the ends
-    of the row, in both write forms: the state and the flags of the
-    parent's program (the same function with the gather)."""
+                                   (64, 32), (1024, 32)])
+def test_maybe_append_with_window_is_the_gather_form(cap, e, reference):
+    """``maybe_append`` at the ends of the row: the state and the flags
+    of the parent's program (the window read by a gather, written in
+    either of the parent's forms)."""
     rng = np.random.default_rng(cap + e)
     st, prev_idx, prev_term, ent_terms, n_ents = _edge_logs(
         rng, 64, cap, e)
     args = (st, jnp.asarray(prev_idx), jnp.asarray(prev_term),
             jnp.asarray(ent_terms), jnp.asarray(n_ents),
             st.last + 3)
-    got = batched.maybe_append(*args, write_mode=mode)
-    monkeypatch.setattr(batched, "term_window", gather_window)
-    jax.clear_caches()
-    want = batched.maybe_append(*args, write_mode=mode)
-    jax.clear_caches()
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    got = batched.maybe_append(*args)
+    assert_same(got, parent_maybe_append(*args, reference=reference))
     ok, _conf, over = (np.asarray(x) for x in got[1:])
     assert ok.any() and (~ok).any() and over.any()
 
@@ -160,13 +274,104 @@ def test_maybe_append_forms_agree_at_the_ends_of_the_row(cap, e):
     rng = np.random.default_rng(7 * cap + e)
     st, prev_idx, prev_term, ent_terms, n_ents = _edge_logs(
         rng, 64, cap, e)
-    outs = [batched.maybe_append(
-        st, jnp.asarray(prev_idx), jnp.asarray(prev_term),
-        jnp.asarray(ent_terms), jnp.asarray(n_ents), st.last + 3,
-        write_mode=mode) for mode in ("scatter", "dense")]
-    for a, b in zip(jax.tree_util.tree_leaves(outs[0]),
-                    jax.tree_util.tree_leaves(outs[1])):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    args = (st, jnp.asarray(prev_idx), jnp.asarray(prev_term),
+            jnp.asarray(ent_terms), jnp.asarray(n_ents), st.last + 3)
+    got = batched.maybe_append(*args)
+    for reference in WRITES:
+        assert_same(got, parent_maybe_append(*args, reference=reference))
+
+
+def _case_lanes(case: str, cap: int, e: int):
+    """Eight follower lanes aimed at one edge of the window write:
+    ``(state, prev_idx, prev_term, ent_terms, n_ents, leader_commit)``
+    and the lanes that must come out ``ok``, in conflict below the
+    commit and overflowing."""
+    g = 8
+    r = np.arange(g)
+    offset = np.full(g, 5)
+    fill = {"straddle": 2 * e + 3, "below_offset": e,
+            "past_cap": cap - 1, "conflict_below_commit": 3 * e,
+            "overflow": cap - 2}[case]
+    last = offset + fill
+    log = np.tile(np.where(np.arange(cap) <= fill, 2, 0), (g, 1))
+    log[:, 0] = 1
+    commit = offset.copy()
+    n_ents = np.full(g, e)
+    none, every = np.zeros(g, bool), np.ones(g, bool)
+    ok, conf, over = every, none, none
+    if case == "straddle":
+        # a run that crosses a block edge, at each offset into a block
+        prev_idx = last - e + r % e
+    elif case == "below_offset":
+        # prev at the compaction slot is verifiable, below it is not
+        prev_idx = offset - r % 3
+        ok = r % 3 == 0
+    elif case == "past_cap":
+        # runs that end at the last slot of the row, the rest of the
+        # window past it
+        prev_idx = offset + cap - 2 - r % e
+        n_ents = r % e + 1
+    elif case == "conflict_below_commit":
+        commit = last - 1
+        prev_idx = commit - 1 - r % 3
+        ok, conf = none, every
+    else:                               # overflow: the run passes cap
+        prev_idx = last.copy()
+        ok, over = none, every
+    st = init_groups(g, 3, cap)._replace(
+        log_term=jnp.asarray(log, jnp.int32),
+        offset=jnp.asarray(offset, jnp.int32),
+        last=jnp.asarray(last, jnp.int32),
+        commit=jnp.asarray(commit, jnp.int32))
+    prev_idx = jnp.asarray(prev_idx, jnp.int32)
+    prev_term = term_at(st.log_term, st.offset, st.last, prev_idx)
+    args = (st, prev_idx, prev_term, jnp.full((g, e), 3, jnp.int32),
+            jnp.asarray(n_ents, jnp.int32), st.last + e)
+    return args, (ok, conf, over)
+
+
+@pytest.mark.parametrize("reference", sorted(WRITES))
+@pytest.mark.parametrize("case", ["straddle", "below_offset",
+                                  "past_cap", "conflict_below_commit",
+                                  "overflow"])
+@pytest.mark.parametrize("cap,e", [(64, 8), (1024, 32)])
+def test_maybe_append_at_each_edge_is_the_parents(cap, e, case,
+                                                  reference):
+    args, flags = _case_lanes(case, cap, e)
+    got = batched.maybe_append(*args)
+    assert_same(got, parent_maybe_append(*args, reference=reference))
+    # every lane took the path its case names, and accepted lanes
+    # wrote their run
+    for name, a, b in zip(("ok", "conflict", "overflow"), got[1:],
+                          flags, strict=True):
+        np.testing.assert_array_equal(np.asarray(a), b, err_msg=name)
+    wrote = (np.asarray(got[0].log_term) == 3).any(axis=1)
+    np.testing.assert_array_equal(wrote, flags[0])
+
+
+@pytest.mark.parametrize("reference", sorted(WRITES))
+def test_handle_append_is_the_parents(monkeypatch, reference):
+    """``DistMember``'s fused follower step: the packed response and
+    the state of the parent's program."""
+    rng = np.random.default_rng(11)
+    g, cap, e = 64, 64, 8
+    st, prev_idx, prev_term, ent_terms, n_ents = _edge_logs(
+        rng, g, cap, e)
+    term = np.asarray(st.term) + rng.integers(0, 2, g).astype(np.int32)
+    args = (st, jnp.full((g,), 1, jnp.int32), jnp.asarray(term),
+            jnp.asarray(prev_idx), jnp.asarray(prev_term),
+            jnp.asarray(ent_terms), jnp.asarray(n_ents), st.last + 3,
+            jnp.asarray(rng.random(g) < 0.9),
+            jnp.asarray(rng.random(g) < 0.1))
+    got = distmember._handle_append_fused(*args)
+    monkeypatch.setattr(distmember, "maybe_append",
+                        partial(parent_maybe_append, reference=reference))
+    jax.clear_caches()
+    want = distmember._handle_append_fused(*args)
+    jax.clear_caches()
+    assert_same(got, want)
+    packed = np.asarray(got[1])
+    assert packed[:, 0].any() and (packed[:, 0] == 0).any()
 
 
 def _drive(mr: MultiRaft, seed: int, rounds: int) -> list:
@@ -384,20 +589,21 @@ def test_round_matches_scalar_core_for_a_lagging_follower(cap, e):
 # -- what the TPU's compiler is handed ------------------------------------
 
 
-def _gathers(jaxpr, out: list) -> list:
-    """``(operand shape, indices shape, slice sizes)`` of every
-    gather in the jaxpr and the jaxprs under it."""
+def _indexed(jaxpr, out: list) -> list:
+    """``(primitive, operand shape, indices shape, slice sizes)`` of
+    every gather and scatter in the jaxpr and the jaxprs under it."""
     for eq in jaxpr.eqns:
-        if eq.primitive.name == "gather":
-            out.append((eq.invars[0].aval.shape,
+        name = eq.primitive.name
+        if name == "gather" or name.startswith("scatter"):
+            out.append((name, eq.invars[0].aval.shape,
                         eq.invars[1].aval.shape,
-                        tuple(eq.params["slice_sizes"])))
+                        tuple(eq.params.get("slice_sizes", ()))))
         for v in eq.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else [v]):
                 if hasattr(sub, "jaxpr"):
-                    _gathers(sub.jaxpr, out)
+                    _indexed(sub.jaxpr, out)
                 elif hasattr(sub, "eqns"):
-                    _gathers(sub, out)
+                    _indexed(sub, out)
     return out
 
 
@@ -408,13 +614,11 @@ def _round_args(g: int, m: int, cap: int):
 
 
 @pytest.mark.parametrize("program", ["hot", "general", "append",
-                                     "build_append"])
-def test_no_program_gathers_a_window_element_by_element(monkeypatch,
-                                                        program):
-    """Traced in the TPU's form (the scatter append): the only
-    gathers whose operand is the ``[G, cap]`` log are the single-index
-    lookups, one element a group; a window is read with selects."""
-    monkeypatch.setenv("ETCD_APPEND_WRITE", "scatter")
+                                     "build_append", "handle_append"])
+def test_no_program_gathers_a_window_element_by_element(program):
+    """The only gathers whose operand is the ``[G, cap]`` log are the
+    single-index lookups, one element a group; a window is read with
+    selects, and no scatter writes into the log at all."""
     g, m, cap, e = 256, 3, 128, 8
     states, leader, inp, drop = _round_args(g, m, cap)
     n_new = inp[0]
@@ -427,16 +631,22 @@ def test_no_program_gathers_a_window_element_by_element(monkeypatch,
             lambda *a: multiraft._fused_round(*a, e=e))(
                 states, leader, inp, drop)
     elif program == "append":
-        jaxpr = jax.make_jaxpr(
-            lambda *a: batched._maybe_append_jit(
-                *a, None, write_mode="scatter"))(
-                    states[0], n_new, n_new,
-                    jnp.zeros((g, e), jnp.int32), n_new, n_new)
-    else:
+        jaxpr = jax.make_jaxpr(batched.maybe_append)(
+            states[0], n_new, n_new, jnp.zeros((g, e), jnp.int32),
+            n_new, n_new)
+    elif program == "build_append":
         jaxpr = jax.make_jaxpr(
             lambda *a: distmember._build_append_fused(
                 *a, peer=1, e=e))(states[0], leader == 0)
-    gathers = _gathers(jaxpr.jaxpr, [])
+    else:
+        jaxpr = jax.make_jaxpr(distmember._handle_append_fused)(
+            states[0], n_new, n_new, n_new, n_new,
+            jnp.zeros((g, e), jnp.int32), n_new, n_new, leader == 0,
+            leader == 0)
+    indexed = _indexed(jaxpr.jaxpr, [])
+    assert not [x for x in indexed
+                if x[0] != "gather" and x[1] == (g, cap)], indexed
+    gathers = [x[1:] for x in indexed if x[0] == "gather"]
     over_log = [x for x in gathers if x[0] == (g, cap)]
     for _operand, indices, sizes in over_log:
         assert int(np.prod(indices[1:-1])) == 1, over_log
