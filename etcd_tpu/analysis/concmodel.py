@@ -29,11 +29,8 @@ import ast
 import re
 import threading
 
-from .engine import dotted_name
+from .engine import LOCK_CTORS, dotted_name
 from .purity import _decorator_root
-
-_LOCK_CTORS = {"Lock", "RLock", "Condition", "Semaphore",
-               "BoundedSemaphore"}
 
 #: blocking calls by dotted-name (module function form)
 _BLOCKING_DOTTED = {
@@ -596,7 +593,7 @@ class ConcurrencyModel:
                         and isinstance(node.value, ast.Call):
                     ctor = dotted_name(
                         node.value.func).split(".")[-1]
-                    if ctor in _LOCK_CTORS:
+                    if ctor in LOCK_CTORS:
                         self.module_locks[
                             (rel, node.targets[0].id)] = ctor
             for scope, cnode in self._iter_classes(mi.tree, ""):
@@ -667,7 +664,7 @@ class ConcurrencyModel:
                     if isinstance(value, ast.Call):
                         ctor = dotted_name(
                             value.func).split(".")[-1]
-                        if ctor in _LOCK_CTORS:
+                        if ctor in LOCK_CTORS:
                             cm.locks.add(attr)
                             continue
                         qb = _queue_ctor_bound(value)

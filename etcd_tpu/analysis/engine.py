@@ -327,6 +327,12 @@ def run_checkers(root: str, checkers,
 
 # -- small shared AST helpers -------------------------------------------------
 
+#: the constructors whose result a checker treats as a lock (the last
+#: name of the call): the standard library's, and ``utils/trace.py``'s
+#: ``TimedRLock``, an ``RLock`` that times its waits and holds
+LOCK_CTORS = frozenset({"Lock", "RLock", "Condition", "Semaphore",
+                        "BoundedSemaphore", "TimedRLock"})
+
 
 def dotted_name(node: ast.AST) -> str:
     """``a.b.c`` for Name/Attribute chains, "" otherwise."""

@@ -28,16 +28,13 @@ from __future__ import annotations
 import ast
 import os
 
-from .engine import Checker, Finding, dotted_name
-
-_LOCK_CTORS = {"Lock", "RLock", "Condition", "Semaphore",
-               "BoundedSemaphore"}
+from .engine import LOCK_CTORS, Checker, Finding, dotted_name
 
 
 def _is_lock_ctor(node: ast.AST) -> bool:
     if not isinstance(node, ast.Call):
         return False
-    return dotted_name(node.func).split(".")[-1] in _LOCK_CTORS
+    return dotted_name(node.func).split(".")[-1] in LOCK_CTORS
 
 
 def _self_attr(node: ast.AST) -> str | None:

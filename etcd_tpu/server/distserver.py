@@ -66,7 +66,7 @@ from ..utils import faults as _faults
 from ..utils.backoff import Backoff
 from .frontdoor import LISTEN_BACKLOG
 from ..utils.errors import EtcdError, EtcdNoSpace
-from ..utils.trace import tracer
+from ..utils.trace import TimedRLock, lock_role, tracer
 from ..utils.wait import Chan, Wait
 from ..wal import WAL, exist as wal_exist
 from ..wire import Entry, GroupEntry, HardState, Snapshot
@@ -242,7 +242,13 @@ class DistServer:
         self.store.fanout.start()
         self.w = Wait()
         self.done = threading.Event()
-        self.lock = threading.RLock()
+        # who waits for the member's lock and who holds it, by the
+        # role its entry point names (lock_role): the round thread of
+        # a leader, a channel reader with an acknowledgement, a
+        # peer's frame, a default GET (whose wait is dist.read_lock)
+        self.lock = TimedRLock(
+            "dist", wait=("round", "ack", "frame"),
+            handoff=("round", "ack", "read"), annotate=("round",))
         # serving seams the v2 HTTP layer mounts against (api/http.py
         # reads do/index/term/store/stats/cluster_store — the same
         # surface EtcdServer and MultiGroupServer expose)
@@ -1095,6 +1101,7 @@ class DistServer:
 
     # -- peer RPC (HTTP handler entry points) -----------------------------
 
+    @lock_role("frame")
     def handle_frame(self, data: bytes) -> bytes:
         """POST /mraft: one batched consensus frame in, the response
         frame out.  Everything this host learned is durable before
@@ -1125,7 +1132,7 @@ class DistServer:
             self.flight.record(
                 "frame", t=t_recv, dir="recv", src=msg.sender,
                 seq=msg.seq, traces=[[t[2], t[3]] for t in traced])
-        with self.lock, tracer.span("dist.handle_frame"):
+        with self.lock:
             if self.done.is_set():
                 # stop() closes the WAL under this lock with done
                 # already set — refuse the frame BEFORE mutating
@@ -1598,6 +1605,7 @@ class DistServer:
                 "leadership lost before the read confirmed")
         return x
 
+    @lock_role("read")
     def _linz_read(self, r: Request,
                    timeout: float | None) -> Response:
         """Default-consistency GET: linearizable without touching
@@ -1977,8 +1985,10 @@ class DistServer:
             # is no stage).  It is dist.pass where the leader round
             # proposed entries (to the end of that round's apply),
             # else filed as dist.heartbeat: the idle iteration
-            # (heartbeat and commit frames, frontier, apply)
-            with self._leader_stage("dist.pass") as it:
+            # (heartbeat and commit frames, frontier, apply).  Its
+            # takes of self.lock are the lock's role "round"
+            with self._leader_stage("dist.pass") as it, \
+                    lock_role(None if it is None else "round"):
                 now = time.monotonic()
                 if now >= next_sync:
                     # TTL expiry must be REPLICATED, not leader-local: a
@@ -2677,11 +2687,13 @@ class DistServer:
             self._on_pipe_fail(peer, [seq], "reconnect")
             return
         t1 = time.monotonic()
-        with self.lock:
-            if self.done.is_set():
-                return
-            self._absorb_ack(peer, resp, t1)
+        with lock_role("ack", since=t1):
+            with self.lock:
+                if self.done.is_set():
+                    return
+                self._absorb_ack(peer, resp, t1)
 
+    @lock_role("ack")
     def _on_pipe_fail(self, peer: int, seqs: list, reason: str) -> None:
         """Channel failure callback: these frames will never ack.
         Roll the peer back to probing from its confirmed match point

@@ -23,11 +23,14 @@ a ``jax.profiler.TraceAnnotation`` of the same name, so a profiler
 session shows the program's stages as host events on the clock of the
 device's operations; ``tracer.record_wait`` is the light record: one
 wall sample under the same family, for a request's wait measured from
-stamps the request carries and for work done once per request.
+stamps the request carries and for work done once per request.  Since
+PR 40 a ``TimedRLock`` files its own waits, holds and hand-overs that
+way, by the role (``lock_role``) the taking thread's entry point names.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import os
@@ -283,6 +286,122 @@ class Tracer:
 #: process-wide default tracer — servers and replay paths record here
 #: (into the default obs registry, so spans surface on /metrics too)
 tracer = Tracer(_metrics.registry)
+
+
+#: this thread's role for a TimedRLock: ``(role, since, outer)``, the
+#: role an entry point set, the stamp its wait counts from (None: the
+#: moment before the acquire) and the role it shadows
+_role_tls = threading.local()
+
+
+class lock_role(contextlib.ContextDecorator):
+    """What this thread takes a :class:`TimedRLock` for, for the length
+    of a ``with`` block or of a decorated call.  ``since`` is a
+    ``time.monotonic()`` stamp the wait counts from (the moment a
+    response was read), else the moment before the acquire.  The
+    instance keeps no state, so one may decorate a method that many
+    threads run at once."""
+
+    def __init__(self, role: str | None, since: float | None = None):
+        self.role = role
+        self.since = since
+
+    def __enter__(self):
+        _role_tls.cur = (self.role, self.since,
+                         getattr(_role_tls, "cur", None))
+        return self
+
+    def __exit__(self, *exc):
+        _role_tls.cur = _role_tls.cur[2]
+        return False
+
+
+class TimedRLock:
+    """A ``threading.RLock`` whose outermost acquisition and release by
+    a thread are timed under the thread's :class:`lock_role` and filed
+    with :meth:`Tracer.record_wait`; re-entry files nothing, nor does a
+    thread whose role is in none of ``wait``, ``handoff``, ``annotate``:
+
+    - ``<name>.lock_wait.<role>``, roles in ``wait``: ``since`` (or the
+      moment before the acquire) to the lock held, every acquisition;
+    - ``<name>.lock_hold.<role>``, every role: held to the release;
+    - ``<name>.lock_handoff``, roles in ``handoff``, contended only:
+      the previous holder's release to this acquire's return, what a
+      hand-over costs beyond the work it waited for.
+
+    A contended acquisition by a role in ``annotate`` is a
+    ``TraceAnnotation`` of its wait's name around the blocking acquire:
+    the thread that dispatches the device's work names the idle gaps
+    its waits leave (other roles' waits overlap, as ``record_wait``'s
+    do).  Uncontended: one ``acquire(False)`` and two clock reads."""
+
+    def __init__(self, name: str, wait: tuple[str, ...] = (),
+                 handoff: tuple[str, ...] = (),
+                 annotate: tuple[str, ...] = (),
+                 recorder: Tracer | None = None):
+        self._lock = threading.RLock()
+        self._tracer = recorder if recorder is not None else tracer
+        # role -> (wait stage, hold stage, hand-over stage, annotation)
+        self._specs = {
+            r: (f"{name}.lock_wait.{r}" if r in wait else None,
+                f"{name}.lock_hold.{r}",
+                f"{name}.lock_handoff" if r in handoff else None,
+                f"{name}.lock_wait.{r}" if r in annotate else None)
+            for r in {*wait, *handoff, *annotate}}
+        # written by the owner alone, under the lock
+        self._owner: int | None = None
+        self._depth = 0
+        self._hold: str | None = None
+        self._t_held = 0.0
+        self._released = time.monotonic()
+
+    def acquire(self) -> bool:
+        me = threading.get_ident()
+        if self._owner == me:
+            self._depth += 1
+            return True
+        cur = getattr(_role_tls, "cur", None)
+        spec = self._specs.get(cur[0]) if cur is not None else None
+        if spec is None:
+            self._lock.acquire()
+            self._owner, self._depth, self._hold = me, 1, None
+            return True
+        wait, hold, handoff, ann_name = spec
+        t0 = time.monotonic() if cur[1] is None else cur[1]
+        if self._lock.acquire(False):
+            t = time.monotonic()
+        else:
+            ann = _annotation(ann_name) if ann_name else None
+            self._lock.acquire()
+            t = time.monotonic()
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            if handoff:
+                self._tracer.record_wait(handoff, t - self._released)
+        self._owner, self._depth, self._hold, self._t_held = (
+            me, 1, hold, t)
+        if wait:
+            self._tracer.record_wait(wait, t - t0)
+        return True
+
+    def release(self) -> None:
+        if self._owner != threading.get_ident():
+            raise RuntimeError("cannot release un-acquired lock")
+        if self._depth > 1:
+            self._depth -= 1
+            return
+        hold, t_held = self._hold, self._t_held
+        t = self._released = time.monotonic()
+        self._owner, self._depth = None, 0
+        self._lock.release()
+        if hold is not None:
+            self._tracer.record_wait(hold, t - t_held)
+
+    __enter__ = acquire
+
+    def __exit__(self, *exc) -> None:
+        self.release()
+
 
 _profiling = False
 

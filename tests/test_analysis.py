@@ -85,6 +85,31 @@ def test_baseline_entries_are_justified_and_live():
         f"prune with scripts/lint --baseline): {sorted(stale)}")
 
 
+def test_both_lock_models_see_the_dist_tier_s_timed_lock():
+    """``DistServer.lock`` is a ``TimedRLock`` (PR 40): the one set of
+    lock constructors makes it a lock to the lock-discipline checker
+    and to the concurrency model, with each of its ``with`` sites."""
+    import ast
+
+    from etcd_tpu.analysis.concmodel import concurrency_model
+    from etcd_tpu.analysis.engine import LOCK_CTORS, AnalysisContext
+    from etcd_tpu.analysis.locks import _scan_class
+
+    assert {"RLock", "TimedRLock"} <= LOCK_CTORS
+    rel = "etcd_tpu/server/distserver.py"
+    with open(os.path.join(REPO, rel)) as f:
+        src = f.read()
+    node = next(n for n in ast.parse(src).body
+                if isinstance(n, ast.ClassDef) and n.name == "DistServer")
+    ci = _scan_class(rel, node)
+    assert "lock" in ci.locks and "lock" not in ci.attr_types
+    sites = sum(1 for acq in ci.acquires.values()
+                for lock, _, _ in acq if lock == "lock")
+    assert sites == src.count("with self.lock:") == 29
+    model = concurrency_model(REPO, AnalysisContext(REPO))
+    assert "lock" in model.classes["DistServer"].locks
+
+
 # -- 2. tracer-purity fires on seeded violations ------------------------------
 
 
