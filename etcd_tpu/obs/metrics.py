@@ -147,6 +147,13 @@ _DEFS = (
         "quorum (a round whose own fsync closed it counts for "
         "nobody).", labels=("peer",)),
     MetricDef(
+        "etcd_dist_acks_per_absorb", "histogram",
+        "Responses one batched absorb of the leader took, observed "
+        "once a drain of the queued acknowledgements that absorbed "
+        "any: those that reached the lock while another thread held "
+        "it ride the same dispatch, apply and re-pump.",
+        buckets=SIZE_BUCKETS),
+    MetricDef(
         "etcd_dist_peer_lag_entries", "histogram",
         "Entries of the led lanes the peer has not acknowledged "
         "(last - match, summed), sampled once a leader round that "

@@ -77,6 +77,16 @@ def _adopt_term(state: GroupState, msg_term, lead, active):
         lead=jnp.where(higher, lead, state.lead))
 
 
+def _absorb_step(state: GroupState, peer_v, term, ok, acked, hint,
+                 active):
+    """The leader's step for one response, ``peer_v`` [G] the sender's
+    slot on every lane (``_absorb_resp`` has the why)."""
+    state = _adopt_term(state, term, jnp.full_like(term, -1), active)
+    state = progress_update(state, peer_v, acked, active=active & ok)
+    state = progress_repair(state, peer_v, hint, active=active & ~ok)
+    return maybe_commit(state)
+
+
 @jax.jit
 def _absorb_resp(state: GroupState, peer, term, ok, acked, hint,
                  active):
@@ -95,13 +105,30 @@ def _absorb_resp(state: GroupState, peer, term, ok, acked, hint,
     reject forever) and the min pins next_ there.  Found by the chaos
     drill as a one-lane permanent replication wedge that survived
     restarts of every host."""
-    state = _adopt_term(state, term, jnp.full_like(term, -1), active)
     g, _m = state.match.shape
-    peer_v = jnp.full((g,), peer, jnp.int32)
-    state = progress_update(state, peer_v, acked,
-                            active=active & ok)
-    state = progress_repair(state, peer_v, hint, active=active & ~ok)
-    return maybe_commit(state)
+    return _absorb_step(state, jnp.full((g,), peer, jnp.int32), term,
+                        ok, acked, hint, active)
+
+
+@jax.jit
+def _absorb_resps(state: GroupState, rows):
+    """``_absorb_step`` for each of K responses in order, ONE dispatch:
+    ``rows`` [G, K, 6] i32 holds a response a column of the K axis
+    (sender | term | ok | acked | hint | active, as in ``AppendResp``).
+    A row whose sender is -1 is padding, and the scan passes the state
+    through it untouched.  Returns the state and the [K + 1, G] commit
+    vectors: before the first row, then after each."""
+    def one(st, row):
+        st = jax.lax.cond(
+            row[0, 0] >= 0,
+            lambda s: _absorb_step(s, row[:, 0], row[:, 1],
+                                   row[:, 2] != 0, row[:, 3], row[:, 4],
+                                   row[:, 5] != 0),
+            lambda s: s, st)
+        return st, st.commit
+
+    out, commits = jax.lax.scan(one, state, jnp.swapaxes(rows, 0, 1))
+    return out, jnp.concatenate([state.commit[None], commits])
 
 
 @jax.jit
@@ -241,13 +268,18 @@ class DistMember:
 
     def __init__(self, g: int, m: int, slot: int, cap: int,
                  election: int = 10, max_batch_ents: int = 8,
-                 seed: int | None = None, live: int | None = None):
+                 seed: int | None = None, live: int | None = None,
+                 ack_rows: int | None = None):
         # (election is in ticks; the server layer's tick_interval
         # scales it to wall time — raft.go:611-617 randomization;
         # ``live`` < m leaves spare member slots for runtime
-        # AddMember, batched state being static-shaped)
+        # AddMember, batched state being static-shaped; ``ack_rows``
+        # is how many responses one absorb dispatch takes, the most
+        # that can be in flight: the pipelined server's window depth
+        # times its peers)
         self.g, self.m, self.slot, self.cap = g, m, slot, cap
         self.e = max_batch_ents
+        self.k = ack_rows or max(1, m - 1)
         # the stratified election bands (_draw_timeouts) carve m
         # disjoint width->=1 bands out of [election, 2*election);
         # with election < m that is impossible — w clamps to 1 and
@@ -466,12 +498,43 @@ class DistMember:
     def handle_append_resp(self, r: AppendResp) -> np.ndarray:
         """Absorb a peer's batched response; returns the [G] commit
         vector after quorum advance."""
-        before = np.asarray(self.state.commit)
         self.state = _absorb_resp(
             self.state, r.sender, self._put(r.term),
             self._put(r.ok), self._put(r.acked),
             self._put(r.hint), self._put(r.active))
         return np.asarray(self.state.commit)
+
+    def handle_append_resps(self, resps: list[AppendResp]) -> np.ndarray:
+        """Absorb responses in order, as :meth:`handle_append_resp`
+        absorbs each: ONE put, dispatch and read-back for every ``k``
+        of them (a shorter run is padded).  Returns the [n + 1, G]
+        commit vectors: row 0 before the first response, row i after
+        response i."""
+        out = []
+        for c in range(0, len(resps), self.k):
+            chunk = resps[c:c + self.k]
+            rows = self._resp_rows(chunk)
+            self.state, commits = _absorb_resps(self.state,
+                                                self._put(rows))
+            out.append(np.asarray(commits)[(1 if out else 0):
+                                           len(chunk) + 1])
+        return np.concatenate(out)
+
+    def _resp_rows(self, resps: list[AppendResp]) -> np.ndarray:
+        """``_absorb_resps``' [G, k, 6] input: the responses, then
+        padding rows (sender -1)."""
+        rows = np.zeros((self.g, self.k, 6), np.int32)
+        rows[:, len(resps):, 0] = -1
+        for i, r in enumerate(resps):
+            rows[:, i] = np.stack([
+                np.full(self.g, r.sender), r.term, r.ok, r.acked,
+                r.hint, r.active], axis=1)
+        return rows
+
+    def prepare_absorb(self) -> None:
+        """Compile the batched absorb ahead of the first response: an
+        all-padding run, whose result is the state as it is."""
+        _absorb_resps(self.state, self._put(self._resp_rows([])))
 
     # -- follower path ----------------------------------------------------
 

@@ -351,6 +351,10 @@ class DistServer:
                 f"pipeline_depth={pipeline_depth} must be >= 1 "
                 f"(1 == lockstep-equivalent window)")
         self.pipe = AppendPipeline(self.m, slot, pipeline_depth)
+        # (peer, response, t1) a channel reader queued before taking
+        # self.lock; whoever holds the lock next absorbs every queued
+        # one in one batch (_drain_acks)
+        self._acks: deque = deque()
         # the second striped connection parallelizes socket I/O and
         # follower-side processing ACROSS CORES; on a single-core
         # host it only fragments the [G]-wide frames (two half-frames
@@ -497,6 +501,8 @@ class DistServer:
             p: _obs.registry.counter(
                 "etcd_dist_commit_advance_acks_total", peer=str(p))
             for p in peers}
+        self._m_acks_per_absorb = _obs.registry.histogram(
+            "etcd_dist_acks_per_absorb")
         self._m_peer_lag = {
             p: _obs.registry.histogram(
                 "etcd_dist_peer_lag_entries", peer=str(p))
@@ -616,7 +622,8 @@ class DistServer:
         self.mr = DistMember(g, self.m, slot, cap,
                              election=election,
                              max_batch_ents=max_batch_ents, seed=slot,
-                             live=self.live)
+                             live=self.live,
+                             ack_rows=pipeline_depth * (self.m - 1))
         # fresh = brand-new data dir (callers gate bootstrap-only
         # actions like the slot-0 mass campaign on this, NOT on
         # is_leader() — leadership is volatile and always empty
@@ -2174,8 +2181,9 @@ class DistServer:
         exchange → absorb → commit, server.go:247-323) decomposed:
         the synchronous ``_exchange`` barrier is gone — append frames
         are enqueued on the per-peer pipelined channels and their
-        acks absorb OUT of band (``_absorb_ack``, on the channel
-        reader threads) as they arrive, recomputing quorum commit per
+        acks absorb OUT of band (``_drain_acks``, on the channel
+        reader threads, or here where they queued during this
+        round's hold) as they arrive, recomputing quorum commit per
         ack, so a slow follower no longer gates the fast pair and
         this stage never blocks on the network.  Durability overlap:
         the frames leave BEFORE the local WAL fsync runs, and the
@@ -2285,6 +2293,7 @@ class DistServer:
                 # must not read as "stale for ages"
                 self._lead_since = np.where(won, now_m,
                                             self._lead_since)
+                mr.prepare_absorb()
                 now_w = time.time()
                 terms = mr.terms()
                 for gi in np.nonzero(won)[0]:
@@ -2440,6 +2449,9 @@ class DistServer:
                     # the frontier record is an optimization —
                     # losing it costs replay time, never acked data
                     self._enter_nospace("frontier persist")
+            # the acknowledgements that queued during this hold: one
+            # absorb and one apply with the self-ack above
+            self._drain_acks()
             with tracer.stage("dist.apply"):
                 self._apply_committed(self._assigned)
             # read maintenance: drop waiters whose callers timed out
@@ -2687,11 +2699,14 @@ class DistServer:
             self._on_pipe_fail(peer, [seq], "reconnect")
             return
         t1 = time.monotonic()
+        # queued before the lock: a holder that drains meanwhile
+        # takes it along, and this take may then find nothing left
+        self._acks.append((peer, resp, t1))
         with lock_role("ack", since=t1):
             with self.lock:
                 if self.done.is_set():
                     return
-                self._absorb_ack(peer, resp, t1)
+                self._drain_acks()
 
     @lock_role("ack")
     def _on_pipe_fail(self, peer: int, seqs: list, reason: str) -> None:
@@ -2707,6 +2722,8 @@ class DistServer:
             # registration must not leak in the stamp dict
             self._traced_send.pop((peer, seq), None)
         with self.lock:
+            # an acknowledgement read before this failure lands first
+            self._drain_acks()
             was = self.pipe.mode(peer)
             popped = self.pipe.fail(peer, seqs)
             if not popped:
@@ -2722,33 +2739,112 @@ class DistServer:
             self.mr.probe_reset(peer)
             self._set_inflight(peer)
 
-    def _absorb_ack(self, peer: int, resp: AppendResp,
-                    t1: float) -> None:
-        """Match + absorb one pipelined ack (call with lock held):
-        monotone match/next update, quorum commit recomputed NOW (not
-        at the next round), apply + client acks, then refill the
-        peer's window."""
+    def _drain_acks(self) -> None:
+        """Absorb every queued pipelined ack in one batch (call with
+        lock held): each ack's frame matched and its lease evidence
+        noted in arrival order, ONE release sweep, ONE absorb dispatch
+        for all (monotone match/next update, quorum commit recomputed
+        after each response, not at the next round), ONE apply + the
+        client acks, then each acknowledging peer's window refilled
+        once.  The acks that queued while another thread held the
+        lock ride this take."""
+        acks = self._acks
+        if not acks:
+            return
         mr = self.mr
+        resps: list[AppendResp] = []
+        peers: list[int | None] = []  # None: a stale ack's step-down
+        terms = None
+        while acks:
+            peer, resp, t1 = acks.popleft()
+            if not self._note_ack(peer, resp, t1):
+                if terms is None:
+                    terms = mr.terms()
+                higher = np.asarray(resp.term) > terms
+                if higher.any():
+                    # an ack from a previous reign may still carry the
+                    # higher term that deposed us — the step-down must
+                    # not be lost, but its progress content (acked/ok/
+                    # hint) must not touch the OTHER lanes' state
+                    # (those indexes may have been truncated since; a
+                    # full active mask would reject-repair next_ on
+                    # every still-led lane).  Absorb a copy neutered
+                    # to the higher-term lanes only.  (Terms read
+                    # before the batch: a lane an earlier row of it
+                    # deposes is a follower by this row, which then
+                    # leaves it as it is.)
+                    resps.append(AppendResp(
+                        sender=resp.sender, term=resp.term,
+                        ok=np.zeros(self.g, bool), acked=resp.acked,
+                        hint=resp.hint,
+                        active=np.asarray(resp.active) & higher))
+                    peers.append(None)
+                continue
+            resps.append(resp)
+            peers.append(peer)
+        # the acks may have advanced the quorum basis past pending
+        # reads' registration times — the batched release sweep rides
+        # the ack path, not a timer, and comes FIRST: the evidence is
+        # the responses' own fields, so a confirmed read does not wait
+        # for the engine's absorb, the apply and the re-pump below (a
+        # lane a response deposes is not in ``active & ok``, and the
+        # sweep gates on the round's view of leadership as it always
+        # did)
+        self._read_release()
+        if not resps:
+            return
+        self._m_acks_per_absorb.observe(len(resps))
+        with tracer.stage("dist.absorb"), \
+                _ledger.dispatch("dist.absorb"):
+            commits = mr.handle_append_resps(resps)
+        # every commit is applied under the lock that made it, so the
+        # first response after whose step a lane's commit stood past
+        # what was applied (and past what the round's own self-ack
+        # committed before this drain) closed a quorum: which peer's
+        floor = np.maximum(self.applied, commits[0])
+        for i, peer in enumerate(peers):
+            if (commits[i + 1] > floor).any():
+                if peer is not None:
+                    self._m_commit_acks[peer].inc()
+                break
+        acked: list[int] = []
+        for resp, peer in zip(resps, peers):
+            if peer is None:
+                continue
+            active = np.asarray(resp.active)
+            ok = np.asarray(resp.ok)
+            if (active & ~ok).any():
+                # follower found a gap (dropped or out-of-order
+                # frame): next_ was repaired from its commit hint;
+                # collapse to PROBE so exactly one catch-up frame
+                # goes out
+                if self.pipe.note_reject(peer):
+                    self.flight.record("pipe_mode", peer=peer,
+                                       mode="probe", cause="reject")
+                _obs.registry.counter("etcd_dist_frame_resend_total",
+                                      reason="reject").inc()
+            elif (active & ok).any():
+                if self.pipe.note_ok(peer):
+                    self.flight.record("pipe_mode", peer=peer,
+                                       mode="replicate")
+            if peer not in acked:
+                acked.append(peer)
+        with tracer.stage("dist.apply"):
+            self._apply_committed(self._assigned)
+        for peer in acked:
+            self._pump_peer(peer)
+
+    def _note_ack(self, peer: int, resp: AppendResp,
+                  t1: float) -> bool:
+        """Match one pipelined ack to its frame and note what it says
+        before the absorb (call with lock held): the round trip and
+        the lease / ReadIndex evidence.  False for an ack of no frame
+        in flight (stale, duplicate, an older reign's)."""
         disp, meta = self.pipe.ack(peer, resp.seq, resp.epoch)
         if disp != "ok":
             _obs.registry.counter("etcd_dist_frame_resend_total",
                                   reason=disp).inc()
-            higher = np.asarray(resp.term) > mr.terms()
-            if higher.any():
-                # an ack from a previous reign may still carry the
-                # higher term that deposed us — the step-down must
-                # not be lost, but its progress content (acked/ok/
-                # hint) must not touch the OTHER lanes' state (those
-                # indexes may have been truncated since; a full
-                # active mask would reject-repair next_ on every
-                # still-led lane).  Absorb a copy neutered to the
-                # higher-term lanes only.
-                mr.handle_append_resp(AppendResp(
-                    sender=resp.sender, term=resp.term,
-                    ok=np.zeros(self.g, bool), acked=resp.acked,
-                    hint=resp.hint,
-                    active=np.asarray(resp.active) & higher))
-            return
+            return False
         rtt = t1 - meta.t0
         self._m_send_rtt.observe(rtt)
         if meta.t_sent and meta.has_ents:
@@ -2779,39 +2875,7 @@ class DistServer:
         # cur-but-rejected lanes (probe catch-up) don't renew —
         # conservative: the quorum's healthy members carry the basis.
         self.lease.note_ack(peer, meta.t0, active & ok)
-        # the ack may have advanced the quorum basis past pending
-        # reads' registration times — the batched release sweep
-        # rides the ack path, not a timer, and comes FIRST: the
-        # evidence is the response's own fields, so a confirmed
-        # read does not wait for the engine's absorb, the apply and
-        # the re-pump below (a lane the response deposes is not in
-        # ``active & ok``, and the sweep gates on the round's view
-        # of leadership as it always did)
-        self._read_release()
-        with tracer.stage("dist.absorb"), \
-                _ledger.dispatch("dist.absorb"):
-            commit = mr.handle_append_resp(resp)
-        if (commit > self.applied).any():
-            # every commit is applied under the lock that made it,
-            # so this acknowledgement closed a quorum: which peer's
-            self._m_commit_acks[peer].inc()
-        if (active & ~ok).any():
-            # follower found a gap (dropped or out-of-order frame):
-            # next_ was repaired from its commit hint; collapse to
-            # PROBE so exactly one catch-up frame goes out
-            if self.pipe.note_reject(peer):
-                self.flight.record("pipe_mode", peer=peer,
-                                   mode="probe", cause="reject")
-            _obs.registry.counter("etcd_dist_frame_resend_total",
-                                  reason="reject").inc()
-        elif (active & ok).any():
-            if self.pipe.note_ok(peer):
-                self.flight.record("pipe_mode", peer=peer,
-                                   mode="replicate")
-        self._set_inflight(peer)
-        with tracer.stage("dist.apply"):
-            self._apply_committed(self._assigned)
-        self._pump_peer(peer)
+        return True
 
     def _campaign(self, mask: np.ndarray) -> None:
         """Batched election round-trip for the fired lanes."""

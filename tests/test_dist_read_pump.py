@@ -119,13 +119,13 @@ def test_the_release_sweep_comes_before_the_engine_s_absorb(cluster):
     ch = register(leader, 0)
     frame = net.sent_to(1)[-1]
     seen = []
-    absorb = leader.mr.handle_append_resp
+    absorb = leader.mr.handle_append_resps
 
-    def watched(resp):
+    def watched(resps):
         seen.append(leader._reads.pending)
-        return absorb(resp)
+        return absorb(resps)
 
-    leader.mr.handle_append_resp = watched
+    leader.mr.handle_append_resps = watched
     deliver(net, frame)
     assert seen == [0]                 # released, then absorbed
     assert closed(ch) is not None
