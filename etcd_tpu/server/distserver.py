@@ -128,6 +128,42 @@ class FrameDropped(Exception):
     frame never arrived."""
 
 
+#: the most peers a member splits its lanes over two striped
+#: connections for (``DistServer._n_stripes``); past it, one
+STRIPED_PEERS_MAX = 2
+
+#: leader rounds whose first and quorum-closing acknowledgements are
+#: still awaited (``DistServer._ack_rounds``): the oldest is forgotten
+#: past this many, unfiled
+ACK_ROUNDS_KEPT = 64
+
+
+class _AckRound:
+    """A leader round whose own fsync landed, awaiting its followers:
+    when its frames were handed over, and per follower the appended
+    lanes (with their ``last``) that follower has not yet acknowledged
+    up to ``last``.  Host arrays only."""
+
+    __slots__ = ("t0", "lanes", "last", "left", "covered")
+
+    def __init__(self, t0: float, lanes: np.ndarray, last: np.ndarray):
+        self.t0, self.lanes, self.last = t0, lanes, last
+        self.left: dict[int, tuple[np.ndarray, np.ndarray]] = {}
+        self.covered = 0
+
+    def cover(self, peer: int, ok: np.ndarray,
+              acked: np.ndarray) -> bool:
+        """Fold in one response of ``peer`` (``ok``: its ``active &
+        ok``); True once that peer has acknowledged every appended
+        lane up to the round's ``last``, and only that once."""
+        lanes, last = self.left.get(peer, (self.lanes, self.last))
+        if not lanes.size:
+            return False
+        keep = ~(ok[lanes] & (acked[lanes] >= last))
+        self.left[peer] = (lanes[keep], last[keep])
+        return not keep.any()
+
+
 class _Pending:
     __slots__ = ("req", "data", "id", "retries", "group", "trace",
                  "t_put", "t_pop")
@@ -360,9 +396,17 @@ class DistServer:
         # host it only fragments the [G]-wide frames (two half-frames
         # cost two full engine dispatches + two fsyncs at the
         # follower — measured 2526/s vs 3813/s on the loopback
-        # bench), so striping gates on real parallelism being there
+        # bench), so striping gates on real parallelism being there.
+        # It is the followers that stripes run in parallel; the leader
+        # builds a frame, absorbs its response and keeps a heartbeat
+        # and a commit cadence a stripe a peer, under its one lock.
+        # Past two peers that doubled work is the leader's alone: five
+        # members on two stripes sent 20 frames a round where one
+        # stripe sends 5, the lock was never free and writes timed out
+        # into re-sends (3 ops/s against 66 on one TPU v5e host)
         self._n_stripes = (2 if pipeline_depth > 4
-                           and (os.cpu_count() or 1) > 1 else 1)
+                           and (os.cpu_count() or 1) > 1
+                           and self.m - 1 <= STRIPED_PEERS_MAX else 1)
         self._stripe_masks = [
             (np.arange(g) % self._n_stripes) == s
             for s in range(self._n_stripes)]
@@ -508,6 +552,12 @@ class DistServer:
                 "etcd_dist_peer_lag_entries", peer=str(p))
             for p in peers}
         self._rtt_stage = {p: f"dist.peer_rtt.s{p}" for p in peers}
+        # the quorum's order statistic, a round: the rounds that
+        # appended and whose own fsync landed, until the follower that
+        # closes their quorum answers (dist.first_ack, dist.quorum_ack
+        # in _drain_acks); guarded by self.lock
+        self._ack_rounds: deque[_AckRound] = deque(maxlen=ACK_ROUNDS_KEPT)
+        self._quorum_followers = self.live // 2
         # PR 14: answer batch endpoints in the binary client framing
         # (wire/clientmsg.py) when the request advertises it via
         # Accept.  ETCD_WIRE_BINARY=0 simulates a JSON-only server —
@@ -2248,6 +2298,7 @@ class DistServer:
                 # late acks read stale_epoch
                 dropped = self.pipe.bump_epoch()
                 self._traced_send.clear()  # old reign's send stamps
+                self._ack_rounds.clear()   # ... and its rounds
                 if dropped:
                     _obs.registry.counter(
                         "etcd_dist_frame_resend_total",
@@ -2339,6 +2390,7 @@ class DistServer:
                 sum(len(q) for q in self._requeue))
             new_keys: list[tuple[int, int]] = []
             recs: list[Entry] = []
+            appended = None
             if n_new.any():
                 with tracer.stage("dist.propose"), \
                         _ledger.dispatch("dist.propose"):
@@ -2374,9 +2426,9 @@ class DistServer:
                             self.flight.span(
                                 p.trace, self.slot, "append",
                                 group=gi, gindex=key[1])
-                recs = self._entry_records(
-                    [gi for gi in range(self.g)
-                     if items[gi] and valid[gi]], base, items)
+                appended = np.flatnonzero(valid)
+                recs = self._entry_records(appended.tolist(), base,
+                                           items)
             elif not lead.any():
                 return False
 
@@ -2391,6 +2443,7 @@ class DistServer:
             # frames FIRST (the fsync/network overlap): the channel
             # writer threads ship them — and the followers append +
             # fsync — while our own WAL fsync below is still running
+            t_pump = time.monotonic()
             with tracer.stage("dist.build_append"), \
                     _ledger.dispatch("dist.build_append"):
                 self._pump_all()
@@ -2424,6 +2477,9 @@ class DistServer:
                     # quorum
                     last = np.asarray(mr.state.last)
                     mr.ack_self(last)
+                    if self._quorum_followers and appended.size:
+                        self._ack_rounds.append(_AckRound(
+                            t_pump, appended, last[appended]))
                     # how far each follower trails, once a round
                     # that appended: entries of the led lanes it
                     # has not acknowledged (one [G, M] read-back)
@@ -2782,6 +2838,8 @@ class DistServer:
                 continue
             resps.append(resp)
             peers.append(peer)
+            if self._ack_rounds:
+                self._note_quorum(peer, resp, t1)
         # the acks may have advanced the quorum basis past pending
         # reads' registration times — the batched release sweep rides
         # the ack path, not a timer, and comes FIRST: the evidence is
@@ -2833,6 +2891,30 @@ class DistServer:
             self._apply_committed(self._assigned)
         for peer in acked:
             self._pump_peer(peer)
+
+    def _note_quorum(self, peer: int, resp: AppendResp,
+                     t1: float) -> None:
+        """The quorum's order statistic, from one matched response
+        read at ``t1`` (call with lock held): the first follower to
+        acknowledge all of a noted round files ``dist.first_ack``, the
+        one that closes its quorum (the ``live // 2``-th: with the
+        leader's own copy, a majority) files ``dist.quorum_ack``, both
+        from the round's hand-over of its frames, and the round is
+        forgotten.  Host arrays only."""
+        ok = np.asarray(resp.active) & np.asarray(resp.ok)
+        acked = np.asarray(resp.acked)
+        closed = []
+        for rnd in self._ack_rounds:
+            if not rnd.cover(peer, ok, acked):
+                continue
+            rnd.covered += 1
+            if rnd.covered == 1:
+                tracer.record_wait("dist.first_ack", t1 - rnd.t0)
+            if rnd.covered == self._quorum_followers:
+                tracer.record_wait("dist.quorum_ack", t1 - rnd.t0)
+                closed.append(rnd)
+        for rnd in closed:
+            self._ack_rounds.remove(rnd)
 
     def _note_ack(self, peer: int, resp: AppendResp,
                   t1: float) -> bool:
