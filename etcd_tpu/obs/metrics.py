@@ -89,6 +89,12 @@ _DEFS = (
         "requeued and new together (0 on an idle pass): the pack's "
         "cost follows this, not G.", buckets=SIZE_BUCKETS),
     MetricDef(
+        "etcd_round_donated_total", "counter",
+        "Co-hosted rounds (MultiRaft.propose) whose input member "
+        "states the program consumed in place: the donated tuple's "
+        "log was deleted once the call returned, so the round "
+        "allocated its pack and no new state buffer."),
+    MetricDef(
         "etcd_election_campaigns_total", "counter",
         "Per-group election campaign lanes fired."),
     MetricDef(

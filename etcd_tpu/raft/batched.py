@@ -78,16 +78,22 @@ def init_groups(g: int, m: int, cap: int, election: int = 10,
     members (default all) — the rest are addable later via
     :func:`apply_conf_change` (grow-the-cluster bootstrap).
     """
-    zi = jnp.zeros((g,), jnp.int32)
     live = m if live is None else live
     members = jnp.tile(jnp.arange(m) < live, (g, 1))
+
+    def full(v):
+        # every field its own buffer: a program that donates the
+        # state can donate a buffer once a call, never in two fields
+        return jnp.full((g,), v, jnp.int32)
+
     return GroupState(
-        term=zi, vote=zi - 1, role=zi + FOLLOWER, lead=zi - 1,
-        commit=zi, applied=zi,
-        log_term=jnp.zeros((g, cap), jnp.int32), offset=zi, last=zi,
+        term=full(0), vote=full(-1), role=full(FOLLOWER), lead=full(-1),
+        commit=full(0), applied=full(0),
+        log_term=jnp.zeros((g, cap), jnp.int32), offset=full(0),
+        last=full(0),
         match=jnp.zeros((g, m), jnp.int32),
         next_=jnp.ones((g, m), jnp.int32),
-        nmembers=zi + live, elapsed=zi, timeout=zi + election,
+        nmembers=full(live), elapsed=full(0), timeout=full(election),
         members=members,
     )
 

@@ -29,15 +29,17 @@ device owns index/term/commit math, the host owns opaque blobs.
 
 from __future__ import annotations
 
+import threading
 import time
 from collections.abc import Mapping, Sequence
-from functools import partial
+from functools import partial, wraps
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
+from ..obs import metrics as _obs
 from ..obs.devledger import ledger as _ledger
 from ..utils.trace import tracer
 from .batched import (
@@ -59,6 +61,11 @@ from .batched import (
     term_window,
     tick as tick_batch,
 )
+
+
+#: served rounds whose input states the program consumed in place
+#: (donated: the old tuple's log is deleted once the call returns)
+_M_DONATED = _obs.registry.counter("etcd_round_donated_total")
 
 
 def _drop_dense(drop, m: int, g: int) -> np.ndarray:
@@ -267,7 +274,7 @@ def _pack(rows):
     return jnp.stack([rows[k].astype(jnp.int32) for k in PACK])
 
 
-@partial(jax.jit, static_argnames=("e",))
+@partial(jax.jit, static_argnames=("e",), donate_argnums=0)
 def _fused_round(states, leader, inp, drop, e):
     """One full propose→replicate→respond→commit round, on device.
 
@@ -278,7 +285,12 @@ def _fused_round(states, leader, inp, drop, e):
     ``drop``: [M, M, G] bool per-edge fault mask (drop[a, b, g] kills
     a→b messages of group g).
 
-    Returns ``(states', pack)`` (:func:`_pack`).
+    Returns ``(states', pack)`` (:func:`_pack`).  ``states`` is
+    donated, here and in every program that returns a whole new tuple
+    (the hot and train forms, the campaign): each field's output takes
+    its input's buffer, so a round allocates the pack and nothing else,
+    and the tuple passed in is deleted.  Each leaf has to be a buffer
+    of its own (:meth:`MultiRaft.seed`, :func:`~.batched.init_groups`).
     """
     m = len(states)
     sels = [leader == s for s in range(m)]
@@ -287,7 +299,7 @@ def _fused_round(states, leader, inp, drop, e):
     return states, _pack(rows)
 
 
-@partial(jax.jit, static_argnames=("e", "slot"))
+@partial(jax.jit, static_argnames=("e", "slot"), donate_argnums=0)
 def _fused_round_hot(states, sel, inp, drop, e, slot):
     """The single-addressed-slot round (serving steady state: every
     group routes to one member slot — the bootstrap shape and the
@@ -324,13 +336,13 @@ def _round_train(states, sels, inp, drop, e, k, slots):
         commit=_max_over(states, "commit")))
 
 
-@partial(jax.jit, static_argnames=("e", "k", "slot"))
+@partial(jax.jit, static_argnames=("e", "k", "slot"), donate_argnums=0)
 def _fused_multi_round_hot(states, sel, inp, drop, e, k, slot):
     """``k`` hot-slot rounds in one dispatch (propose_rounds')."""
     return _round_train(states, [sel], inp, drop, e, k, (slot,))
 
 
-@partial(jax.jit, static_argnames=("e", "k"))
+@partial(jax.jit, static_argnames=("e", "k"), donate_argnums=0)
 def _fused_multi_round(states, leader, inp, drop, e, k):
     """``k`` consecutive fused rounds in ONE device dispatch.
 
@@ -348,7 +360,7 @@ def _fused_multi_round(states, leader, inp, drop, e, k):
                         tuple(range(m)))
 
 
-@partial(jax.jit, static_argnames=("slot",))
+@partial(jax.jit, static_argnames=("slot",), donate_argnums=0)
 def _fused_campaign(states, mask, drop, slot):
     """Batched campaign for member ``slot`` (raft.go:358-370), fused.
 
@@ -417,6 +429,19 @@ def _fused_campaign(states, mask, drop, slot):
     return tuple(states), won
 
 
+def _holding_states(method):
+    """Run ``method`` holding the engine's lock.  The round programs
+    donate the member states, so whatever reads or replaces them does
+    so under the lock: a caller on another thread (a snapshot taken
+    off the engine thread) waits for the round instead of meeting an
+    array the round has deleted."""
+    @wraps(method)
+    def run(self, *args, **kwargs):
+        with self._lock:
+            return method(self, *args, **kwargs)
+    return run
+
+
 class MultiRaft:
     """G co-hosted groups, M members each, batched across groups.
 
@@ -431,6 +456,7 @@ class MultiRaft:
                  max_batch_ents: int = 8, seed: int = 0,
                  live: int | None = None):
         self.g, self.m, self.cap = g, m, cap
+        self._lock = threading.RLock()   # see _holding_states
         self.e = max_batch_ents
         rng = np.random.default_rng(seed)
         self.states: list[GroupState] = []
@@ -442,6 +468,11 @@ class MultiRaft:
                 rng.integers(election, 2 * election, size=g), jnp.int32))
             self.states.append(st)
         self.leader = np.full(g, -1, np.int32)  # member slot per group
+        # [G, M] membership, which every member holds alike, kept on
+        # the host too: members_mask() answers from it, so no reader
+        # on another thread holds an array the next round donates
+        live = m if live is None else live
+        self._members = np.tile(np.arange(m) < live, (g, 1))
         # cached single-addressed-slot routing (None = mixed): keyed
         # off self.leader, recomputed only where the routing changes
         # (campaign wins, conf-change removals) — the round dispatch
@@ -506,6 +537,33 @@ class MultiRaft:
         self._sh_drop = NamedSharding(mesh, P(None, None, "g"))
         self._sh_rows = NamedSharding(mesh, P(None, "g"))
 
+    def seed(self, frontier, terms, members=None) -> None:
+        """Re-seed every member with a committed log in compacted form
+        (a restart): ``offset = last = commit = applied = frontier``,
+        slot 0 of the log carries ``terms`` for match checks, and
+        ``members`` ([G, M] bool, where given) is every member's
+        membership mask.  Each field of each member is a put of its
+        own (``jnp.array`` copies: ``asarray`` may alias one host
+        array for several): the round programs donate every leaf of
+        the states, and one buffer can be donated once a call."""
+        fr = np.asarray(frontier, np.int32)
+        tm = np.asarray(terms, np.int32)
+        for slot, st in enumerate(self.states):
+            term = jnp.array(tm)
+            st = st._replace(
+                term=term, offset=jnp.array(fr), last=jnp.array(fr),
+                commit=jnp.array(fr), applied=jnp.array(fr),
+                log_term=jnp.zeros((self.g, self.cap), jnp.int32)
+                .at[:, 0].set(term))
+            if members is not None:
+                st = st._replace(
+                    members=jnp.array(members, bool),
+                    nmembers=jnp.array(np.sum(members, axis=1),
+                                       jnp.int32))
+            self.states[slot] = st
+        if members is not None:
+            self._members = np.array(members, bool)
+
     def _put_g(self, arr, dtype=None):
         """[G] host array → device, g-sharded when the state is."""
         if self._placer is not None:
@@ -546,10 +604,12 @@ class MultiRaft:
 
     def _pack_stands(self) -> bool:
         """Whether every member's ``term`` and ``commit`` are still
-        the arrays the last round returned with its pack (arrays are
-        immutable: the same object is the same value).  Only those
-        two are kept, never a whole state: a compaction's stale
-        generation of logs must not stay alive for this."""
+        the arrays the last round returned with its pack (an array
+        object is never rewritten: the next round donates it and
+        returns new ones, so the same object is the same value; the
+        test is by identity alone and holds for a deleted array).
+        Only those two are kept, never a whole state: a compaction's
+        stale generation of logs must not stay alive for this."""
         return len(self._pack_of) == len(self.states) and all(
             st.term is t and st.commit is c
             for st, (t, c) in zip(self.states, self._pack_of))
@@ -572,6 +632,7 @@ class MultiRaft:
 
     # -- elections (batched, fused, droppable) ---------------------------
 
+    @_holding_states
     def campaign(self, slot: int, mask: np.ndarray | None = None,
                  drop=None) -> np.ndarray:
         """Member ``slot`` campaigns for the masked groups: term+1,
@@ -611,6 +672,7 @@ class MultiRaft:
 
     # -- the replication hot path (one fused device call per round) ------
 
+    @_holding_states
     def propose(self, n_new: np.ndarray,
                 data: Sequence[list[bytes]] | Mapping[int, list[bytes]]
                 | None = None,
@@ -629,6 +691,7 @@ class MultiRaft:
         # program; wait the round's ONE read-back, which blocks until
         # the device has run the round; fetch the payload bookkeeping,
         # host work alone
+        old_log = self.states[0].log_term
         with tracer.stage("mg.round.dispatch", cpu=False), \
                 _ledger.dispatch("multiraft.round"):
             inp = self._round_input(n_new)
@@ -644,6 +707,8 @@ class MultiRaft:
                     dense, e=self.e)
         with tracer.stage("mg.round.wait", cpu=False):
             newly = self._take_pack("multiraft.round", states, pack)
+            if old_log.is_deleted():
+                _M_DONATED.inc()
         # payloads recorded only for groups whose addressed member
         # really IS leader (a deposed member may linger in
         # self.leader), keyed from its pre-append last index; the
@@ -660,6 +725,7 @@ class MultiRaft:
                             int(self.last_base[gi]) + 1 + j] = blob
         return newly
 
+    @_holding_states
     def propose_rounds(self, n_new: np.ndarray, rounds: int,
                        drop=None) -> np.ndarray:
         """``rounds`` consecutive payload-less propose→commit rounds
@@ -704,6 +770,7 @@ class MultiRaft:
 
     # -- membership change (raft.go:376-387,431-435 batched) -------------
 
+    @_holding_states
     def apply_conf_change(self, add: bool, slot: int,
                           mask: np.ndarray | None = None) -> None:
         """Apply a committed ConfChange to the masked groups: every
@@ -729,12 +796,16 @@ class MultiRaft:
             self.states[s] = conf_change_batch(
                 self.states[s], addv, slotv,
                 jnp.full((g,), s, jnp.int32), active=mj)
+        members = self._members.copy()   # swapped whole, never torn
+        members[mask, slot] = add
+        self._members = members
         if not add:
             # deposed-by-removal groups lose their routing entry too
             self.leader = np.where(mask & (self.leader == slot), -1,
                                    self.leader).astype(np.int32)
             self._recompute_hot()
 
+    @_holding_states
     def mark_applied(self, upto: np.ndarray) -> None:
         """The host consumer declares it has applied entries up to
         ``upto[g]`` (clamped to each member's commit).  Compaction
@@ -759,6 +830,7 @@ class MultiRaft:
         self._applied_due = None
         self.states = list(_take_applied(self.states, upto))
 
+    @_holding_states
     def compact(self, upto: np.ndarray | None = None) -> None:
         """Compact every member's log at its applied index (the
         reference couples this to the snapshot trigger,
@@ -792,6 +864,7 @@ class MultiRaft:
                 self.payloads[gi] = {k: v for k, v in p.items()
                                      if k >= c}
 
+    @_holding_states
     def tick(self, drop=None) -> None:
         """Advance every member's timers; campaign where they fire.
         ``drop`` faults apply to the resulting vote traffic too."""
@@ -804,6 +877,7 @@ class MultiRaft:
 
     # -- views -----------------------------------------------------------
 
+    @_holding_states
     def _max_view(self, field: str, last: np.ndarray) -> np.ndarray:
         """Max of ``field`` across members per group: the last
         round's own answer while its states still stand, else taken
@@ -824,11 +898,13 @@ class MultiRaft:
 
     def members_mask(self) -> np.ndarray:
         """[G, M] live-membership mask (every member holds the same:
-        a committed ConfChange flips all of them at once)."""
-        return readback("multiraft.view", self.states[0].members)
+        a committed ConfChange flips all of them at once), from the
+        host's copy: safe on any thread, and no read-back."""
+        return self._members.copy()
 
     def committed_payload(self, group: int, index: int) -> bytes | None:
         return self.payloads[group].get(index)
 
+    @_holding_states
     def log_terms(self, slot: int) -> np.ndarray:
         return np.asarray(self.states[slot].log_term)

@@ -332,13 +332,8 @@ class MultiGroupServer:
             # re-seed consensus: every member holds the committed log in
             # compacted form (offset = last = commit = applied = frontier,
             # slot 0 carries the frontier term for match checks)
-            import jax.numpy as jnp
-
             mr = MultiRaft(g, self.m, cap, max_batch_ents=max_batch_ents,
                            live=self.live)
-            fr = jnp.asarray(frontier, jnp.int32)
-            tm = jnp.asarray(terms, jnp.int32)
-            slot0 = jnp.zeros((g, cap), jnp.int32).at[:, 0].set(tm)
             members = None
             if snap is not None and "members" in blob:
                 msnap = np.asarray(blob["members"], bool)
@@ -355,17 +350,8 @@ class MultiGroupServer:
                             f"restart with spare_member_slots >= "
                             f"{msnap.shape[1] - self.live}")
                     msnap = msnap[:, :self.m]
-                members = jnp.asarray(msnap)
-            for s in range(self.m):
-                st = mr.states[s]
-                st = st._replace(
-                    term=tm, offset=fr, last=fr, commit=fr, applied=fr,
-                    log_term=slot0)
-                if members is not None:
-                    st = st._replace(
-                        members=members,
-                        nmembers=members.sum(axis=1).astype(jnp.int32))
-                mr.states[s] = st
+                members = msnap
+            mr.seed(frontier, terms, members=members)
             self.mr = mr
             # committed ConfChanges in the replayed window re-apply to
             # the fresh engine (the snapshot's members mask carries
