@@ -89,6 +89,19 @@ _DEFS = (
         "requeued and new together (0 on an idle pass): the pack's "
         "cost follows this, not G.", buckets=SIZE_BUCKETS),
     MetricDef(
+        "etcd_wal_frontier_groups", "histogram",
+        "Groups one co-hosted commit-frontier WAL record names (those "
+        "whose commit moved since the last record; 0 when none did): "
+        "the record's size follows this, not G.",
+        buckets=SIZE_BUCKETS),
+    MetricDef(
+        "etcd_round_transfer_bytes", "histogram",
+        "Host<->device bytes of one co-hosted round (MultiRaft."
+        "propose): its [2, G] int32 input put, the leader vector "
+        "where the routing is mixed, and its int32[7, G] pack read "
+        "back.", buckets=(1 << 10, 1 << 12, 1 << 14, 1 << 16, 1 << 18,
+                          1 << 20, 1 << 22, 1 << 24)),
+    MetricDef(
         "etcd_round_donated_total", "counter",
         "Co-hosted rounds (MultiRaft.propose) whose input member "
         "states the program consumed in place: the donated tuple's "

@@ -34,6 +34,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 from ..ops.quorum import commit_index_batch
 
@@ -435,22 +437,39 @@ def maybe_commit(state: GroupState) -> GroupState:
     return state._replace(commit=jnp.where(ok, mci, state.commit))
 
 
+def shift_left(x, shift):
+    """Row ``g`` of ``x`` moved ``shift[g]`` slots to the left with
+    zeros shifted in: ``out[g, j] = x[g, j + shift[g]]`` where that is
+    inside the row, else 0 (``shift`` clamped to ``[0, cap]``).  A
+    barrel shifter: one static shift and one select a bit of the
+    shift, so the pass streams the window log2(cap) + 1 times; a
+    per-element gather of the ``[G, cap]`` window took 2.8 s a member
+    at 100 000 groups on the v5e.  Written in ``lax`` so that tracing
+    it starts no nested trace a step."""
+    g, cap = x.shape
+    zero = np.zeros((), x.dtype)
+    shift = lax.clamp(np.int32(0), shift.astype(np.int32), np.int32(cap))
+    for b in range(cap.bit_length()):
+        step = 1 << b
+        moved = lax.full_like(x, 0) if step >= cap else lax.pad(
+            lax.slice_in_dim(x, step, cap, axis=1), zero,
+            ((0, 0, 0), (0, step, 0)))
+        on = lax.ne(lax.bitwise_and(shift, np.int32(step)), np.int32(0))
+        x = lax.select(lax.broadcast_in_dim(on, (g, cap), (0,)), moved, x)
+    return x
+
+
 @jax.jit
 def compact(state: GroupState, idx, active=None):
     """Batched ``RaftLog.compact`` (log.go:161-169): slide the window
     so slot 0 holds entry ``idx`` (which keeps its term for future
     match checks).  err lanes where idx ∉ [offset, applied]."""
-    g, cap = state.log_term.shape
+    g = state.log_term.shape[0]
     if active is None:
         active = jnp.ones((g,), bool)
     err = active & ((idx < state.offset) | (idx > state.applied))
     do = active & ~err
-    shift = idx - state.offset
-    src = jnp.arange(cap, dtype=jnp.int32)[None, :] + shift[:, None]
-    rolled = jnp.take_along_axis(
-        state.log_term, jnp.clip(src, 0, cap - 1), axis=1)
-    keep = src[:, :] < cap
-    rolled = jnp.where(keep, rolled, 0)
+    rolled = shift_left(state.log_term, idx - state.offset)
     return state._replace(
         log_term=jnp.where(do[:, None], rolled, state.log_term),
         offset=jnp.where(do, idx, state.offset)), err

@@ -66,6 +66,12 @@ from .batched import (
 #: served rounds whose input states the program consumed in place
 #: (donated: the old tuple's log is deleted once the call returns)
 _M_DONATED = _obs.registry.counter("etcd_round_donated_total")
+#: host<->device bytes of each served round: its input put, a mixed
+#: routing's leader vector, its pack read back
+_M_TRANSFER = _obs.registry.histogram("etcd_round_transfer_bytes")
+
+#: ``_payload_low`` of a group that holds no payload
+_NO_PAYLOAD = np.iinfo(np.int64).max
 
 
 def _drop_dense(drop, m: int, g: int) -> np.ndarray:
@@ -480,8 +486,11 @@ class MultiRaft:
         self._route_hot: int | None = None
         self._hot_sel = None  # cached device router mask (see
         # _hot_sel_dev)
-        # host-side payload store: per-group dict index -> bytes
+        # host-side payload store: per-group dict index -> bytes, and
+        # per group the lowest index it holds (_NO_PAYLOAD when none),
+        # so that a compaction visits only the groups it prunes
         self.payloads: list[dict[int, bytes]] = [dict() for _ in range(g)]
+        self._payload_low = np.full(g, _NO_PAYLOAD, np.int64)
         self.errors = {"overflow": np.zeros(g, bool),
                        "conflict": np.zeros(g, bool),
                        "compact_oob": np.zeros(g, bool)}
@@ -663,8 +672,7 @@ class MultiRaft:
                 p = self.payloads[gi]
                 cut = int(winner_last[gi])
                 if p and max(p) > cut:  # skip the common no-op case
-                    self.payloads[gi] = {
-                        k: v for k, v in p.items() if k <= cut}
+                    self._keep_payloads(gi, lambda k: k <= cut)
             # the becoming-leader empty entry (raft.go:329-348)
             self.propose(np.where(won_np, 1, 0).astype(np.int32),
                          drop=drop)
@@ -696,15 +704,19 @@ class MultiRaft:
                 _ledger.dispatch("multiraft.round"):
             inp = self._round_input(n_new)
             _ledger.h2d("multiraft.round", inp)
+            moved = inp.nbytes
             if self._route_hot is not None:
                 hot = self._route_hot
                 states, pack = _fused_round_hot(
                     tuple(self.states), self._hot_sel_dev(hot), inp,
                     dense, e=self.e, slot=hot)
             else:
+                leader = self._put_g(self.leader)
+                moved += leader.nbytes
                 states, pack = _fused_round(
-                    tuple(self.states), self._put_g(self.leader), inp,
-                    dense, e=self.e)
+                    tuple(self.states), leader, inp, dense, e=self.e)
+            # the pack is int32[len(PACK), G]
+            _M_TRANSFER.observe(moved + 4 * len(PACK) * g)
         with tracer.stage("mg.round.wait", cpu=False):
             newly = self._take_pack("multiraft.round", states, pack)
             if old_log.is_deleted():
@@ -719,10 +731,12 @@ class MultiRaft:
                 # only the groups that took proposals, so a mapping
                 # of those answers as a list of G lists does
                 for gi in np.nonzero(self.last_valid & (n_new > 0))[0]:
+                    first = int(self.last_base[gi]) + 1
                     for j, blob in enumerate(
                             data[gi][:int(n_new[gi])]):
-                        self.payloads[gi][
-                            int(self.last_base[gi]) + 1 + j] = blob
+                        self.payloads[gi][first + j] = blob
+                    self._payload_low[gi] = min(
+                        self._payload_low[gi], first)
         return newly
 
     @_holding_states
@@ -857,12 +871,16 @@ class MultiRaft:
         oob, cut = readback("multiraft.compact", jnp.stack(
             [oob.astype(jnp.int32), cut]))
         self.errors["compact_oob"] = oob.astype(bool)
-        for gi in range(self.g):
-            p = self.payloads[gi]
+        for gi in np.nonzero(self._payload_low < cut)[0]:
             c = int(cut[gi])
-            if p and min(p) < c:
-                self.payloads[gi] = {k: v for k, v in p.items()
-                                     if k >= c}
+            self._keep_payloads(gi, lambda k: k >= c)
+
+    def _keep_payloads(self, gi: int, keep) -> None:
+        """Keep the payloads of group ``gi`` whose index ``keep``
+        accepts, and its lowest held index with them."""
+        p = {k: v for k, v in self.payloads[gi].items() if keep(k)}
+        self.payloads[gi] = p
+        self._payload_low[gi] = min(p, default=_NO_PAYLOAD)
 
     @_holding_states
     def tick(self, drop=None) -> None:

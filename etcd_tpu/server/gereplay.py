@@ -12,7 +12,8 @@ the restart bottleneck).  This module keeps the whole pass in arrays:
 2. last-record-wins dedup per (group, gindex) — the replay-overwrite
    semantics of wal.go:171-175 generalized to the group axis — is a
    sort + run-boundary scan,
-3. frontier / ballot selection is a reverse argmax.
+3. frontier / ballot selection is a reverse argmax; the co-hosted
+   tier's sparse frontier records fold over it (:func:`fold_frontier`).
 
 Payload bytes stay in the blob; only the (rare) committed winners
 that actually apply to the store materialize Python objects.
@@ -26,6 +27,48 @@ import numpy as np
 
 from .. import native
 from ..wire import GroupEntry
+
+#: GroupEntry kinds of the co-hosted WAL that carry its commit
+#: frontier (the dist tier's kinds are in distserver.py).  The dense
+#: record holds the whole ``[G]`` commit vector and the ``[G]``
+#: term-at-commit vector; data directories written before the sparse
+#: record hold it, and a restart still reads it.  The sparse record
+#: holds the groups whose commit moved since the last record
+#: (:func:`sparse_frontier`).
+K_FRONTIER = 1
+K_FRONTIER_SPARSE = 3
+
+
+def sparse_frontier(g: int, groups, commit, terms) -> bytes:
+    """Payload of a sparse commit-frontier record: an int32
+    little-endian header ``(G, n)``, then the ``n`` groups, their
+    commit indexes and their terms at commit, each ``n`` int32 LE."""
+    n = len(groups)
+    out = np.empty(2 + 3 * n, "<i4")
+    out[0], out[1] = g, n
+    out[2:2 + n] = groups
+    out[2 + n:2 + 2 * n] = commit
+    out[2 + 2 * n:] = terms
+    return out.tobytes()
+
+
+def read_sparse_frontier(payload: bytes):
+    """``(G, groups, commit, terms)`` of a :func:`sparse_frontier`
+    payload, the arrays int64."""
+    v = np.frombuffer(payload, "<i4")
+    if v.size < 2 or v.size != 2 + 3 * int(v[1]):
+        raise ValueError(f"sparse frontier record of {len(payload)} B "
+                         f"is malformed")
+    n = int(v[1])
+    body = v[2:].astype(np.int64)
+    return int(v[0]), body[:n], body[n:2 * n], body[2 * n:]
+
+
+def _check_g(written: int, g: int) -> None:
+    if written != g:
+        raise RuntimeError(
+            f"data dir was written with --cohosted-groups {written}, "
+            f"not {g}; group routing would silently change")
 
 
 @dataclass(slots=True)
@@ -75,6 +118,44 @@ class GEStream:
         last_in_run = np.ones(k_sorted.size, bool)
         last_in_run[:-1] = k_sorted[1:] != k_sorted[:-1]
         return np.sort(pos[order[last_in_run]])
+
+
+def fold_frontier(stream: GEStream, frontier: np.ndarray,
+                  terms: np.ndarray, g: int):
+    """The co-hosted commit frontier and term-at-commit vectors at
+    the end of ``stream``, over the snapshot's ``frontier``/``terms``
+    (the stream holds only records after the snapshot).  The last
+    dense record replaces both whole; every sparse record after it
+    sets its own groups, the last record of a group winning.  The
+    work follows the records and the groups they name, never G.
+    Raises when a record was written for another G."""
+    frontier = np.array(frontier, np.int64)
+    terms = np.array(terms, np.int64)
+    dpos = stream.last_of_kind(K_FRONTIER)
+    if dpos >= 0:
+        v = np.frombuffer(stream.payload(dpos), np.int32)
+        _check_g(v.size // 2, g)
+        frontier = v[:g].astype(np.int64)
+        terms = v[g:].astype(np.int64)
+    spos = np.nonzero(stream.kind[dpos + 1:] == K_FRONTIER_SPARSE)[0]
+    if spos.size == 0:
+        return frontier, terms
+    parts = [read_sparse_frontier(stream.payload(int(p) + dpos + 1))
+             for p in spos]
+    for written, *_ in parts:
+        _check_g(written, g)
+    groups = np.concatenate([p[1] for p in parts])
+    if groups.size == 0:
+        return frontier, terms
+    if groups.min() < 0 or groups.max() >= g:
+        raise ValueError("sparse frontier record names a group "
+                         f"outside [0, {g})")
+    # the last occurrence of each group: the first in reversed order
+    named, first = np.unique(groups[::-1], return_index=True)
+    last = groups.size - 1 - first
+    frontier[named] = np.concatenate([p[2] for p in parts])[last]
+    terms[named] = np.concatenate([p[3] for p in parts])[last]
+    return frontier, terms
 
 
 def scan(block_or_entries, blob: np.ndarray | None = None) -> GEStream:

@@ -16,7 +16,10 @@ the reference exposes —
 - **Storage seam**: one WAL stream per server (wal/wal.py — same
   record framing, device-replayable as a single batch) multiplexing
   all groups via :class:`~etcd_tpu.wire.GroupEntry` envelopes, plus
-  commit-frontier markers; snapshots via the standard Snapshotter.
+  commit-frontier records that name only the groups whose commit
+  moved (gereplay.sparse_frontier); snapshots via the standard
+  Snapshotter, their frontier, terms and members as packed arrays
+  (:func:`encode_snapshot`).
   Entries are durable BEFORE client acks (the Ready contract,
   node.go:41-60, translated to the co-hosted fate-sharing model).
 - **Store seam**: one shared KV tree; group namespaces are path
@@ -56,6 +59,7 @@ from ..wal import WAL, exist as wal_exist
 from ..wire import Entry, GroupEntry, HardState, Snapshot
 from ..wire.requests import Info, Request
 from .cluster import ClusterStore
+from .gereplay import K_FRONTIER_SPARSE, fold_frontier, sparse_frontier
 from .server import (
     DEFAULT_SNAP_COUNT,
     Response,
@@ -74,6 +78,7 @@ TICK_INTERVAL = 0.1        # reference server.go:182
 _M_APPLY_S = _obs.registry.histogram("etcd_apply_seconds")
 _M_APPLY_N = _obs.registry.histogram("etcd_apply_batch_entries")
 _M_PACK_GROUPS = _obs.registry.histogram("etcd_pack_groups_visited")
+_M_FRONTIER_GROUPS = _obs.registry.histogram("etcd_wal_frontier_groups")
 _M_CAMPAIGNS = _obs.registry.counter("etcd_election_campaigns_total")
 _M_WINS = _obs.registry.counter("etcd_election_wins_total")
 # read serve paths (PR 7): the co-hosted tier is single-copy — every
@@ -92,6 +97,64 @@ def group_of(path: str, g: int) -> int:
     ns = path.strip("/").split("/", 1)[0]
     h = hashlib.sha1(ns.encode()).digest()
     return int.from_bytes(h[:8], "big") % g
+
+
+#: first bytes of a packed snapshot blob; a JSON object (the form of
+#: data directories written before it) never starts with them
+_SNAP_MAGIC = b"\x00mgsnap1\n"
+
+
+def encode_snapshot(store: bytes, frontier, terms, members, seq: int,
+                    applied_total: int) -> bytes:
+    """The co-hosted snapshot blob: the magic, a JSON header line,
+    the store's own serialisation, then the ``[G]`` frontier and
+    ``[G]`` terms as int32 LE and the ``[G, M]`` live-membership mask
+    as one byte a slot.  Its cost follows the store and G's bytes,
+    not a Python object per group."""
+    g, m = members.shape
+    head = json.dumps({"groups": g, "slots": m, "seq": seq,
+                       "applied_total": applied_total,
+                       "store_bytes": len(store)}).encode()
+    return b"".join((
+        _SNAP_MAGIC, head, b"\n", store,
+        np.asarray(frontier).astype("<i4").tobytes(),
+        np.asarray(terms).astype("<i4").tobytes(),
+        np.asarray(members, np.uint8).tobytes()))
+
+
+def decode_snapshot(data: bytes) -> dict:
+    """``store`` (bytes), ``frontier`` and ``terms`` (int64 ``[G]``),
+    ``members`` (bool ``[G, M]``, None where the blob has none),
+    ``seq`` and ``applied_total`` of a snapshot blob in either form:
+    :func:`encode_snapshot`'s, or the JSON object of older data
+    directories."""
+    if not data.startswith(_SNAP_MAGIC):
+        blob = json.loads(data.decode())
+        return {
+            "store": blob["store"].encode(),
+            "frontier": np.asarray(blob["frontier"], np.int64),
+            "terms": np.asarray(blob["terms"], np.int64),
+            "members": (np.asarray(blob["members"], bool)
+                        if "members" in blob else None),
+            "seq": blob["seq"],
+            "applied_total": blob.get("applied_total", 0)}
+    eol = data.index(b"\n", len(_SNAP_MAGIC))
+    head = json.loads(data[len(_SNAP_MAGIC):eol])
+    g, m, n = head["groups"], head["slots"], head["store_bytes"]
+    at = eol + 1 + n
+    if len(data) != at + 8 * g + g * m:
+        raise RuntimeError(f"snapshot blob of {len(data)} B does not "
+                           f"hold what its header names")
+    vec = np.frombuffer(data, "<i4", count=2 * g, offset=at)
+    return {
+        "store": data[eol + 1:at],
+        "frontier": vec[:g].astype(np.int64),
+        "terms": vec[g:].astype(np.int64),
+        "members": np.frombuffer(data, np.uint8, count=g * m,
+                                 offset=at + 8 * g)
+        .reshape(g, m).astype(bool),
+        "seq": head["seq"],
+        "applied_total": head["applied_total"]}
 
 
 @dataclass
@@ -209,12 +272,14 @@ class MultiGroupServer:
                                 live=self.live)
             self.wal = WAL.create(self._waldir,
                                   Info(id=self.id).marshal())
-            # seq-0 zero-frontier marker: WAL replay requires entry
-            # indices contiguous from the open index (wal.go:171-175)
-            zero = np.zeros(g, np.int32).tobytes()
+            # seq-0 frontier marker that names no group (its header
+            # carries G for the restart's guard): WAL replay requires
+            # entry indices contiguous from the open index
+            # (wal.go:171-175)
             self.wal.save(HardState(), [Entry(
                 index=0, term=0,
-                data=GroupEntry(kind=1, payload=zero + zero)
+                data=GroupEntry(kind=K_FRONTIER_SPARSE,
+                                payload=sparse_frontier(g, [], [], []))
                 .marshal())])
         # intra-slice scale-out: the co-hosted batch sharded over a
         # local device mesh (after restart seeding so the replayed
@@ -252,17 +317,17 @@ class MultiGroupServer:
             except NoSnapshotError:
                 snap = None
             if snap is not None:
-                blob = json.loads(snap.data.decode())
+                blob = decode_snapshot(snap.data)
                 if len(blob["frontier"]) != g:
                     raise RuntimeError(
                         f"snapshot was written with "
                         f"--cohosted-groups "
                         f"{len(blob['frontier'])}, not {g}")
-                self.store.recovery(blob["store"].encode())
-                frontier = np.asarray(blob["frontier"], np.int64)
-                terms = np.asarray(blob["terms"], np.int64)
+                self.store.recovery(blob["store"])
+                frontier = blob["frontier"]
+                terms = blob["terms"]
                 snap_index = blob["seq"]
-                applied_total = blob.get("applied_total", 0)
+                applied_total = blob["applied_total"]
                 log.info("multigroup: restart from snapshot seq=%d",
                          snap_index)
         snap_frontier = frontier.copy()
@@ -284,23 +349,14 @@ class MultiGroupServer:
 
         with tracer.stage("restart.apply"):
             # array pass: ONE native envelope sweep + vectorized
-            # last-record-wins dedup and frontier selection — the device
+            # last-record-wins dedup and frontier fold — the device
             # replay hands back struct-of-arrays and the restart stays in
             # that shape instead of walking 1M GroupEntry objects
             # (round-2 weakness #5)
             stream = ge_stream_scan(raw)
             if len(stream):
                 self.seq = max(self.seq, int(stream.seq.max()))
-            fpos = stream.last_of_kind(1)
-            if fpos >= 0:
-                v = np.frombuffer(stream.payload(fpos), np.int32)
-                if v.size != 2 * g:
-                    raise RuntimeError(
-                        f"data dir was written with --cohosted-groups "
-                        f"{v.size // 2}, not {g}; group routing would "
-                        f"silently change")
-                frontier = v[:g].astype(np.int64)
-                terms = v[g:2 * g].astype(np.int64)
+            frontier, terms = fold_frontier(stream, frontier, terms, g)
 
             # committed winners apply in stream order; only the applying
             # slice materializes Python objects (CONFCHANGE entries touch
@@ -335,8 +391,8 @@ class MultiGroupServer:
             mr = MultiRaft(g, self.m, cap, max_batch_ents=max_batch_ents,
                            live=self.live)
             members = None
-            if snap is not None and "members" in blob:
-                msnap = np.asarray(blob["members"], bool)
+            if snap is not None and blob["members"] is not None:
+                msnap = blob["members"]
                 if msnap.shape[1] < self.m:
                     # restart with MORE spare slots: pad the mask (new
                     # slots start empty — the add_member migration path)
@@ -756,15 +812,22 @@ class MultiGroupServer:
         commit = commit.astype(np.int64)
         newly = commit > self.applied
         if to_persist or newly.any():
-            terms = np.zeros(self.g, np.int32)
-            if newly.any():
+            # the frontier record names the groups whose commit moved,
+            # each with its term at commit: its size follows the
+            # round's traffic, not G
+            moved = np.flatnonzero(newly)
+            moved_terms = np.zeros(0, np.int32)
+            if moved.size:
                 terms = terms_now if terms_now is not None \
                     else mr.term_index()
                 self.raft_term = max(self.raft_term,
                                      int(terms.max()))
+                moved_terms = terms[moved]
+            _M_FRONTIER_GROUPS.observe(moved.size)
             frontier = GroupEntry(
-                kind=1, payload=commit.astype(np.int32).tobytes()
-                + terms.tobytes()).marshal()
+                kind=K_FRONTIER_SPARSE,
+                payload=sparse_frontier(self.g, moved, commit[moved],
+                                        moved_terms)).marshal()
             self.seq += 1
             ents = (to_persist or []) + [
                 Entry(index=self.seq, term=self.raft_term,
@@ -889,22 +952,19 @@ class MultiGroupServer:
         """Store snapshot + frontier → snap file; compact the device
         logs; cut the WAL (server.go:562-571 batched)."""
         mr = self.mr
-        terms = mr.term_index()
-        blob = json.dumps({
-            "store": self.store.save().decode(),
-            "frontier": [int(x) for x in self.applied],
-            "terms": [int(x) for x in terms],
-            "seq": self.seq,
-            "applied_total": self.raft_index,
-            # per-group live-membership mask: conf changes below the
-            # snapshot don't need their entries replayed
-            "members": mr.members_mask().astype(int).tolist(),
-        }).encode()
         with tracer.stage("mg.snapshot"):
+            with tracer.stage("mg.snapshot.encode"):
+                # the per-group live-membership mask rides along:
+                # conf changes below the snapshot need no replay
+                blob = encode_snapshot(
+                    self.store.save(), self.applied, mr.term_index(),
+                    mr.members_mask(), self.seq, self.raft_index)
             snap_seq = self.seq
-            self.ss.save_snap(Snapshot(data=blob, index=snap_seq,
-                                       term=self.raft_term))
-            mr.compact()
+            with tracer.stage("mg.snapshot.save"):
+                self.ss.save_snap(Snapshot(data=blob, index=snap_seq,
+                                           term=self.raft_term))
+            with tracer.stage("mg.snapshot.compact"):
+                mr.compact()
             self.wal.cut()
             # snapshot is durable (save_snap fsyncs file+dir): WAL
             # segments wholly behind the OLDEST retained snapshot

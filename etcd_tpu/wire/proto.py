@@ -461,7 +461,11 @@ class GroupEntry:
 
     ``kind``: 0 = a group's log entry (payload = marshaled Request),
     1 = commit-frontier marker (payload = the [G] i32-LE commit vector
-    followed by the [G] i32-LE term-at-commit vector).
+    followed by the [G] i32-LE term-at-commit vector; the co-hosted
+    tier reads it in data directories written before kind 3),
+    2 = the dist tier's ballot (distserver.py), 3 = sparse co-hosted
+    commit-frontier marker (payload = the groups whose commit moved,
+    server/gereplay.py:sparse_frontier).
     """
 
     kind: int = 0
